@@ -17,6 +17,27 @@ def ensure_x64() -> None:
     jax.config.update("jax_enable_x64", True)
 
 
+def ensure_compile_cache() -> None:
+    """Keep compiled programs across processes (JAX's persistent
+    compilation cache). Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    reads it itself and no directory is set in code; otherwise the cache
+    lives at ``<checkout>/.jax_cache`` — a fixed path, because the path is
+    part of how a deployment finds its cache again. Every compile is
+    kept, however short: a cold start is hundreds of sub-second star-tree
+    and index kernels, one per pow2-padded capacity."""
+    import os
+
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+
 from pinot_tpu.engine.errors import QueryError, UnsupportedQueryError
 from pinot_tpu.engine.executor import ServerQueryExecutor
 from pinot_tpu.engine.residency import QueryLease, ResidencyManager

@@ -23,7 +23,8 @@ over a ``(segments, tiles)`` grid:
   ``GroupByResultHolder``. Exactness scheme:
   - **integer sums** split each value into 12-bit limbs (``L`` limbs for a
     plan-time ``max_abs`` bound): every per-tile limb partial is at most
-    ``4095 * PALLAS_TILE < 2^24`` — exactly representable in the f32 matmul.
+    ``4095 * PALLAS_TILE < 2^24`` — exactly representable in the f32 matmul
+    (run at HIGHEST precision: no bf16 pass rounds a limb).
     Limb partials land in per-limb **i32 accumulators with a carry chain**
     (base-2^12 positional rows, normalized every grid step), so provider-
     wide sums are exact up to ~2^62 with no i64 math inside the kernel;
@@ -626,7 +627,9 @@ def build_kernel(spec: PallasSpec):
     nf = len(fsum_row)
     # matmul row plan: [nf float rows][1 count row][per int sum: L limb rows]
     int_sums = sorted(isum_row.items(), key=lambda kv: kv[1][0])
-    # params: [2*n_slots intervals][S num_docs][1 doc_base]
+    # params: [2*n_slots intervals][S num_docs][1 doc_base], held in SMEM as
+    # one [1, n] row — 2-D so that a vmap over params (the launcher's
+    # coalesced form) squeezes a LEADING dim and leaves a legal block
     nd_off = 2 * spec.n_slots
 
     def kernel(params_ref, *refs):
@@ -662,8 +665,8 @@ def build_kernel(spec: PallasSpec):
                        jnp.concatenate(planes, axis=0))  # [RT, 128]
 
         # -- validity + filter expression
-        num_docs = params_ref[nd_off + s]
-        doc_base = params_ref[nd_off + S]
+        num_docs = params_ref[0, nd_off + s]
+        doc_base = params_ref[0, nd_off + S]
         row = jax.lax.broadcasted_iota(jnp.int32, (RT, 128), 0)
         lane = jax.lax.broadcasted_iota(jnp.int32, (RT, 128), 1)
         doc = doc_base + t * T + row * 128 + lane
@@ -692,13 +695,13 @@ def build_kernel(spec: PallasSpec):
                 _, pi, slot0, n_runs = node
                 m = jnp.zeros((RT, 128), dtype=bool)
                 for j in range(n_runs):
-                    lo = params_ref[2 * (slot0 + j)]
-                    hi = params_ref[2 * (slot0 + j) + 1]
+                    lo = params_ref[0, 2 * (slot0 + j)]
+                    hi = params_ref[0, 2 * (slot0 + j) + 1]
                     m = m | ((ids[pi] >= lo) & (ids[pi] <= hi))
                 return m
             _, pi, slot = node                     # "iv"
-            lo = params_ref[2 * slot]
-            hi = params_ref[2 * slot + 1]
+            lo = params_ref[0, 2 * slot]
+            hi = params_ref[0, 2 * slot + 1]
             return (ids[pi] >= lo) & (ids[pi] <= hi)
 
         mask = emit(spec.filter_tree) & valid
@@ -752,10 +755,11 @@ def build_kernel(spec: PallasSpec):
         if spec.group_key_offset:
             keys = keys - jnp.int32(spec.group_key_offset)
 
-        # -- per-segment matched docs (QueryStats parity), exact i32
-        # (dtype pinned: under jax x64 an int32 sum promotes to int64 and
-        # the ref swap rejects the mismatch)
-        out_seg[0, :] += mask.astype(jnp.int32).sum(axis=0, dtype=jnp.int32)
+        # -- per-segment matched docs (QueryStats parity), exact i32: the
+        # tile's four 8-row slabs add into the segment's [8, 128] block
+        # (whole vregs; a (1, 128) block is legal only when S == 1)
+        m_i = mask.astype(jnp.int32)
+        out_seg[0] += sum(m_i[r:r + 8] for r in range(0, RT, 8))
 
         # -- matmul row stack [nf + 1 + sum(L), RT, 128] f32
         rows = []
@@ -787,8 +791,13 @@ def build_kernel(spec: PallasSpec):
             g_iota = g0 + jax.lax.broadcasted_iota(
                 jnp.int32, (RT, 128, _G_CHUNK), 2)
             oh = (keys[:, :, None] == g_iota).astype(jnp.float32)
-            part = jax.lax.dot_general(
-                R, oh, (((1, 2), (0, 1)), ((), ())),
+            # a plain 2-D matmul over the tile's flattened docs: Mosaic has
+            # no dot_general with two contracting dims, and takes the
+            # (RT, 128) -> T flattening as a relayout. HIGHEST keeps every
+            # MXU pass f32 (the limb/f32-exactness argument above)
+            part = jnp.dot(
+                R.reshape(len(rows), T), oh.reshape(T, _G_CHUNK),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)   # [M_mat, 128]
 
             # float sums: Neumaier-compensated accumulation (sum, comp pair)
@@ -854,16 +863,17 @@ def build_kernel(spec: PallasSpec):
         pl.BlockSpec((Mf, G), lambda s, t: (0, 0), memory_space=pltpu.VMEM),
         pl.BlockSpec((Mi, G), lambda s, t: (0, 0), memory_space=pltpu.VMEM),
         pl.BlockSpec((Mm, G), lambda s, t: (0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((1, 128), lambda s, t: (s, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, 8, 128), lambda s, t: (s, 0, 0),
+                     memory_space=pltpu.VMEM),
     )
     out_shape = (
         jax.ShapeDtypeStruct((Mf, G), jnp.float32),
         jax.ShapeDtypeStruct((Mi, G), jnp.int32),
         jax.ShapeDtypeStruct((Mm, G), jnp.float32),
-        jax.ShapeDtypeStruct((S, 128), jnp.int32),
+        jax.ShapeDtypeStruct((S, 8, 128), jnp.int32),
     )
 
-    return pl.pallas_call(
+    fused = pl.pallas_call(
         kernel,
         grid=(S, TPS),
         in_specs=in_specs,
@@ -871,6 +881,19 @@ def build_kernel(spec: PallasSpec):
         out_shape=out_shape,
         interpret=spec.interpret,
     )
+
+    def call(params, *cols):
+        """-> (out_f [Mf, G], out_i [Mi, G], out_mm [Mm, G], out_seg
+        [S, 128]). Kernel body and index maps trace with 32-bit defaults:
+        under jax_enable_x64 every weak Python scalar enters the jaxpr as
+        a 64-bit literal, Mosaic has no 64 -> 32 conversion and wants i32
+        from an index map (every operand is already a 32-bit array)."""
+        with jax.enable_x64(False):
+            out_f, out_i, out_mm, out_seg = fused(params.reshape(1, -1),
+                                                  *cols)
+            return out_f, out_i, out_mm, out_seg.sum(axis=1)
+
+    return call
 
 
 class PallasKernelCache:

@@ -132,7 +132,9 @@ def estimate_segment_bytes(segment, columns: Iterable[str]) -> int:
 def resolve_budget_bytes(budget_bytes: Any = AUTO,
                          config=None) -> Optional[int]:
     """Budget resolution: explicit arg > layered config key > backend device
-    memory. Returns None for uncapped (explicit <= 0, or nothing known)."""
+    memory. Returns None for uncapped: explicit <= 0, or the CPU backend,
+    which reports no device memory. An accelerator that reports none is an
+    error — uncapped staging there ends in an allocation failure mid-query."""
     if budget_bytes is not AUTO:
         if budget_bytes is None:
             return None
@@ -145,15 +147,16 @@ def resolve_budget_bytes(budget_bytes: Any = AUTO,
     if v is not None:
         b = int(v)
         return b if b > 0 else None
-    try:
-        import jax
+    import jax
 
-        stats = jax.devices()[0].memory_stats()
-        limit = (stats or {}).get("bytes_limit")
-        if limit:
-            return int(limit * CommonConstants.DEFAULT_HBM_BUDGET_FRACTION)
-    except Exception:  # backend without memory stats / not initialized
-        pass
+    device = jax.devices()[0]
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return int(limit * CommonConstants.DEFAULT_HBM_BUDGET_FRACTION)
+    if device.platform != "cpu":
+        raise RuntimeError(
+            f"{device.platform} device {device.device_kind!r} reports no "
+            f"bytes_limit: set {CommonConstants.HBM_BUDGET_BYTES_KEY}")
     return None
 
 

@@ -3,11 +3,11 @@
 The reference's hot realtime shape — ``SELECT cols FROM t WHERE ...
 ORDER BY ts DESC LIMIT 10`` (``SelectionOrderByOperator.java``) — runs the
 filter scan AND the order-by selection on device: the boolean mask and a
-lexicographic ``lax.sort`` over the order keys (+ docId as the final key,
-which reproduces the host's stable-sort tie semantics exactly) produce the
-per-segment top-k doc ids; only k ids cross the wire, and the k rows
-materialize from the host-side column files (row materialization is
-O(k · columns), never O(capacity)).
+stable lexicographic ``lax.sort`` over the order keys (docIds ride as the
+payload, so ties keep doc order — the host's stable-sort tie semantics
+exactly) produce the per-segment top-k doc ids; only k ids cross the wire,
+and the k rows materialize from the host-side column files (row
+materialization is O(k · columns), never O(capacity)).
 
 Eligibility (everything else falls back to the numpy host path):
 - every ORDER BY expression is a non-null numeric/dict SV column
@@ -38,6 +38,7 @@ from pinot_tpu.segment.immutable import ImmutableSegment
 MAX_DEVICE_SELECTION_K = 8192
 # LRU bound on compiled top-k kernels (k rides in the cache key)
 _KERNEL_CACHE_CAP = 256
+_I32 = np.iinfo(np.int32)
 
 
 def _order_columns(ctx: QueryContext,
@@ -57,10 +58,18 @@ def _order_columns(ctx: QueryContext,
         if not cm.has_dictionary:
             from pinot_tpu.engine.staging import staged_int_dtype
 
-            if (cm.data_type.is_integral
-                    and staged_int_dtype(cm) != np.dtype(np.int32)):
-                return None  # i64 keys would round through the f64 sort
-            if not cm.data_type.is_integral:
+            if cm.data_type.is_integral:
+                if staged_int_dtype(cm) != np.dtype(np.int32):
+                    return None  # the sort keys are 32-bit
+                # filtered-out rows park at INT32_MAX in the leading key:
+                # a value that transforms onto it (max ascending, min
+                # through ``~`` descending) would tie with them
+                if cm.min_value is None or cm.max_value is None:
+                    return None
+                if (int(cm.max_value) >= _I32.max if ob.ascending
+                        else int(cm.min_value) <= _I32.min):
+                    return None
+            else:
                 # the kernel parks filtered-out rows at +inf: a raw float
                 # column containing ±inf/NaN would collide with (or sort
                 # past) the sentinel — stats must PROVE finiteness
@@ -78,23 +87,33 @@ def _order_columns(ctx: QueryContext,
 def _build_kernel(filter_spec, directions: Tuple[bool, ...], capacity: int,
                   k: int):
     """jitted fn(cols, params, num_docs, keys) -> (docids[k], n_matched).
-    Keys sort lexicographically with docId as the FINAL key — a unique
-    total order identical to the host's stable lexsort."""
+    Keys sort lexicographically in their staged dtype (i32 dictIds / raw
+    ints, f64 raw floats) with the docIds as the payload of a STABLE sort
+    — a total order identical to the host's stable lexsort. Integer keys
+    never widen: a 64-bit comparator is emulated on the TPU, and XLA
+    takes minutes to compile a multi-key sort over it."""
 
     def kernel(cols, params, num_docs, keys):
         pc = _ParamCursor(params)
         mask = _emit_filter(filter_spec, cols, pc, capacity)
         pc.finish()  # selection params are exactly the filter params
-        mask = mask & (jnp.arange(capacity, dtype=jnp.int32) < num_docs)
+        iota = jnp.arange(capacity, dtype=jnp.int32)
+        mask = mask & (iota < num_docs)
         operands = []
         for key, asc in zip(keys, directions):
-            v = key.astype(jnp.float64)
-            if not asc:
-                v = -v
-            operands.append(jnp.where(mask, v, jnp.inf))
-        iota = jnp.arange(capacity, dtype=jnp.int32)
-        sorted_ops = jax.lax.sort(
-            tuple(operands) + (iota,), num_keys=len(operands) + 1)
+            if jnp.issubdtype(key.dtype, jnp.integer):
+                # ~v reverses the order with no overflow at the i32 edges
+                operands.append(key if asc else ~key)
+            else:
+                operands.append(key if asc else -key)
+        # filtered-out rows park past every matched row (_order_columns
+        # proves no matched key reaches the sentinel)
+        lead = operands[0]
+        park = (_I32.max if jnp.issubdtype(lead.dtype, jnp.integer)
+                else jnp.inf)
+        operands[0] = jnp.where(mask, lead, jnp.asarray(park, lead.dtype))
+        sorted_ops = jax.lax.sort(tuple(operands) + (iota,),
+                                  num_keys=len(operands), is_stable=True)
         return sorted_ops[-1][:k], mask.sum(dtype=jnp.int32)
 
     return jax.jit(kernel)

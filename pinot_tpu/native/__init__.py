@@ -3,9 +3,11 @@
 The runtime around the JAX compute path is native where the reference's is
 (SURVEY.md §2 [NATIVE-EQ] items): fixed-bit pack/unpack of dictId arrays,
 refcounted mmap buffers, file CRC, and varint posting lists live in
-``native/pinot_native.cpp``, compiled once with g++ on first use and bound
-through ctypes (no pybind11 in the image). Every entry point has a numpy
-fallback so the framework still runs where no compiler exists.
+``native/pinot_native.cpp``, compiled with g++ on first use and bound
+through ctypes (no pybind11 in the image). The library is always built
+from that tracked source — a ``.so`` without it is not loaded. Every entry
+point has a numpy fallback so the framework still runs where no compiler
+exists; ``available()`` says which one is in use.
 """
 
 from __future__ import annotations
@@ -36,8 +38,11 @@ _load_attempted = False
 
 def _build() -> bool:
     os.makedirs(_LIB_DIR, exist_ok=True)
+    # compile beside the target and rename: processes that race to the
+    # first use (spawned segment builders) never load a half-written file
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", _LIB, _SRC]
+           "-o", tmp, _SRC]
     try:
         r = subprocess.run(cmd, capture_output=True, timeout=120)
     except (OSError, subprocess.TimeoutExpired) as e:
@@ -46,6 +51,7 @@ def _build() -> bool:
     if r.returncode != 0:
         log.warning("native build failed:\n%s", r.stderr.decode()[-2000:])
         return False
+    os.replace(tmp, _LIB)
     return True
 
 
@@ -56,15 +62,13 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _load_attempted:
             return _lib
         _load_attempted = True
-        have_lib = os.path.isfile(_LIB)
-        have_src = os.path.isfile(_SRC)
-        stale = (have_lib and have_src
-                 and os.path.getmtime(_LIB) < os.path.getmtime(_SRC))
-        if not have_lib or stale:
-            if not have_src or not _build():
-                # a pre-built .so without source is still usable
-                if not have_lib:
-                    return None
+        if not os.path.isfile(_SRC):
+            log.warning("native source %s is missing; numpy fallback", _SRC)
+            return None
+        if (not os.path.isfile(_LIB)
+                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
+            if not _build():
+                return None
         try:
             lib = ctypes.CDLL(_LIB)
         except OSError as e:

@@ -82,9 +82,10 @@ def broker_mesh():
     if _MESH is not None or _MESH_FAILED:
         return _MESH
     try:
-        from pinot_tpu.engine import ensure_x64
+        from pinot_tpu.engine import ensure_compile_cache, ensure_x64
 
         ensure_x64()  # i64 keys/sums through the collectives
+        ensure_compile_cache()
         import jax
 
         from jax.sharding import Mesh

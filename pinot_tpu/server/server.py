@@ -35,6 +35,7 @@ from pinot_tpu.ingestion.realtime import (
     SegmentCompletionProtocol,
 )
 from pinot_tpu.ingestion.stream import StreamOffset
+from pinot_tpu.parallel.executor import ShardedQueryExecutor
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.server.data_manager import (
     InstanceDataManager,
@@ -64,7 +65,11 @@ class ServerInstance:
         self.instance_id = instance_id
         self.store = store
         self.completion_protocol = completion_protocol
-        self.executor = executor or ServerQueryExecutor(config=config)
+        # the sharded executor over this process's devices IS the serving
+        # path: one stacked launch per multi-segment scan (a 1x1 mesh on a
+        # one-chip host), star-tree / index / single-segment queries
+        # handed back to the per-segment ladder it subclasses
+        self.executor = executor or ShardedQueryExecutor(config=config)
         # runner pool sized by pinot.server.query.runner.threads (pqr);
         # policy from pinot.server.query.scheduler.policy — default SEWF
         # (shortest-expected-work-first with anti-starvation aging)

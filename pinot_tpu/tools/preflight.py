@@ -10,8 +10,11 @@ the JAX/Pallas references), so this module verifies them ahead of time:
 
 - :class:`LoweringModel` — a pure-Python TPU lowering model: VMEM/SMEM
   budgets, lane/sublane tiling, the supported packed bit-widths, limb
-  bounds. Numbers are deliberately conservative (utilization headroom for
-  compiler scratch and double buffering).
+  bounds — ASSUMPTIONS about one named ``device_kind`` (``TPU_V5E``),
+  taken from published figures and not from the compiler. Numbers are
+  deliberately conservative (utilization headroom for compiler scratch
+  and double buffering). ``model_for`` refuses a device it has no model
+  for.
 - :func:`preflight_spec` — one concrete :class:`PallasSpec` against the
   model: mirrors ``build_kernel``'s exact BlockSpec/accumulator layout
   (via ``_row_layout``) and sizes every VMEM block, the matmul row stack
@@ -84,15 +87,17 @@ RULES: Tuple[_Rule, ...] = (
 
 @dataclass(frozen=True)
 class LoweringModel:
-    """Conservative TPU lowering model (pallas guide: ~16 MB VMEM/core,
-    small SMEM, (8, 128) min tile for 32-bit dtypes, MXU 128x128)."""
+    """Assumed lowering limits of ONE device kind. The device-specific
+    fields carry no default: a model exists only for a device somebody
+    wrote the assumptions down for."""
 
-    vmem_bytes: int = 16 * 2 ** 20
+    device_kind: str                  # jax Device.device_kind it speaks for
+    vmem_bytes: int                   # scoped VMEM a kernel may assume
     # headroom for compiler scratch, double buffering, and spills the
     # model cannot see — the budget the working set must fit
-    vmem_utilization: float = 0.75
+    vmem_utilization: float
     # modeled SMEM capacity in i32 scalar slots for the params vector
-    smem_slots: int = 1024
+    smem_slots: int
     lane: int = _LANE
     sublane_f32: int = 8
     # planar unpack requires word-aligned widths (staging.pack_bits)
@@ -104,6 +109,27 @@ class LoweringModel:
     @property
     def vmem_budget(self) -> int:
         return int(self.vmem_bytes * self.vmem_utilization)
+
+
+# One v5e TensorCore, as the pallas guide describes it: ~16 MB of VMEM a
+# kernel can count on, a small SMEM, (8, 128) min tile for 32-bit dtypes,
+# MXU 128x128. Assumed, not measured: chip_smoke.py prints this model's
+# verdict beside what the compiler did.
+TPU_V5E = LoweringModel(device_kind="TPU v5 lite", vmem_bytes=16 * 2 ** 20,
+                        vmem_utilization=0.75, smem_slots=1024)
+
+_MODELS = {m.device_kind: m for m in (TPU_V5E,)}
+
+
+def model_for(device_kind: str) -> LoweringModel:
+    """The lowering model written for ``device_kind``; an unknown device
+    is an error, never a default."""
+    try:
+        return _MODELS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no lowering model for device kind {device_kind!r} "
+            f"(known: {sorted(_MODELS)})") from None
 
 
 @dataclass
@@ -162,7 +188,7 @@ def _vmem_estimate(spec, model: LoweringModel) -> int:
     total += len(spec.packed_bits) * T * 4
     # output accumulators (whole arrays resident across the grid)
     total += (Mf + Mi + Mm) * G * 4
-    total += model.lane * 4  # out_seg block (1, 128)
+    total += model.sublane_f32 * model.lane * 4  # out_seg block (1, 8, 128)
     # matmul row stack R [M_mat, RT, 128] f32
     n_limb_rows = sum(L for (_s, L) in isum.values())
     m_mat = (Mf // 2) + 1 + n_limb_rows
@@ -177,8 +203,9 @@ def _vmem_estimate(spec, model: LoweringModel) -> int:
 
 def preflight_spec(spec, model: Optional[LoweringModel] = None,
                    shape: str = "", source: str = "fuzz") -> Verdict:
-    """Verify one concrete PallasSpec against the lowering model."""
-    model = model or LoweringModel()
+    """Verify one concrete PallasSpec against the lowering model (the
+    v5e assumptions unless told otherwise)."""
+    model = model or TPU_V5E
     failures: List[Tuple[str, str]] = []
 
     def fail(code: str, detail: str) -> None:
@@ -392,7 +419,7 @@ def preflight_ssb_plans(segs, model: Optional[LoweringModel] = None,
     from pinot_tpu.query import compile_query
     from pinot_tpu.tools import ssb
 
-    model = model or LoweringModel()
+    model = model or TPU_V5E
     staged = StagingCache().stage(segs[0])
     verdicts: List[Verdict] = []
     plan_specs: Dict[str, Tuple] = {}
@@ -427,7 +454,7 @@ def run_preflight(segs=None, model: Optional[LoweringModel] = None,
 
     from pinot_tpu.tools import ssb
 
-    model = model or LoweringModel()
+    model = model or TPU_V5E
     if segs is None:
         with tempfile.TemporaryDirectory() as td:
             segs = ssb.build_segments(0, td, num_segments=2, rows=rows,
@@ -442,6 +469,7 @@ def run_preflight(segs=None, model: Optional[LoweringModel] = None,
                                            source="fuzz"))
     table = {
         "model": {
+            "device_kind": model.device_kind,
             "vmem_bytes": model.vmem_bytes,
             "vmem_utilization": model.vmem_utilization,
             "smem_slots": model.smem_slots,

@@ -158,20 +158,31 @@ def generate_segment_frame(i: int, num_segments: int, n: int,
     return cols
 
 
+def segment_sizes(num_segments: int, rows: int) -> List[int]:
+    """Rows per segment: ``ceil(rows / num_segments)`` each until the rows
+    run out (trailing empty segments are dropped)."""
+    per = -(-rows // num_segments)
+    sizes = []
+    left = rows
+    while left > 0 and len(sizes) < num_segments:
+        sizes.append(min(per, left))
+        left -= sizes[-1]
+    return sizes
+
+
+def generate_segment_frames(num_segments: int, rows: int, seed: int = 42):
+    """Segment by segment, EXACTLY the rows ``build_segments(num_segments,
+    rows, seed)`` indexes — for an oracle that cannot hold the whole table
+    as raw strings."""
+    for i, n in enumerate(segment_sizes(num_segments, rows)):
+        yield generate_segment_frame(i, num_segments, n, seed)
+
+
 def generate_table(num_segments: int, rows: int,
                    seed: int = 42) -> Dict[str, np.ndarray]:
-    """Concatenated per-segment frames — EXACTLY the rows
-    ``build_segments(num_segments, rows, seed)`` indexes (for the pandas
-    oracle / external baseline side of parity checks)."""
-    per = -(-rows // num_segments)
-    frames = []
-    left = rows
-    for i in range(num_segments):
-        take = min(per, left)
-        if take <= 0:
-            break
-        frames.append(generate_segment_frame(i, num_segments, take, seed))
-        left -= take
+    """``generate_segment_frames`` concatenated (for the pandas oracle /
+    external baseline side of parity checks)."""
+    frames = list(generate_segment_frames(num_segments, rows, seed))
     return {k: np.concatenate([f[k] for f in frames]) for k in frames[0]}
 
 
@@ -306,16 +317,8 @@ def build_segments(sf: float, out_dir: str, num_segments: int = 8,
     from pinot_tpu.segment import load_segment
 
     n = rows or int(sf * ROWS_PER_SF)
-    per = -(-n // num_segments)
-    jobs = []
-    left = n
-    for i in range(num_segments):
-        take = min(per, left)
-        if take <= 0:
-            break
-        jobs.append((i, num_segments, take, seed, out_dir, partitioned,
-                     star_tree))
-        left -= take
+    jobs = [(i, num_segments, take, seed, out_dir, partitioned, star_tree)
+            for i, take in enumerate(segment_sizes(num_segments, n))]
 
     if not workers:
         workers = min(len(jobs), os.cpu_count() or 1)

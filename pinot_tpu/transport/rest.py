@@ -145,7 +145,13 @@ class _Api:
             def do_DELETE(self):
                 self._dispatch("DELETE")
 
-        self._httpd = ThreadingHTTPServer(("0.0.0.0", port), Handler)
+        class Server(ThreadingHTTPServer):
+            # socketserver's listen backlog of 5 drops the SYNs of a burst
+            # of more concurrent connects, and each dropped one retries
+            # after TCP's 1 s initial timeout
+            request_queue_size = 128
+
+        self._httpd = Server(("0.0.0.0", port), Handler)
         self.port = self._httpd.server_port
         self._thread: Optional[threading.Thread] = None
 

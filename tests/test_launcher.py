@@ -594,7 +594,8 @@ def test_debug_launches_endpoint(setup):
         d = inst.launch_debug()
         assert d["enabled"] is True
         assert "launches" in d and "queued" in d
-        host_inst = ServerInstance("Server_launch_1", store)
+        host_inst = ServerInstance("Server_launch_1", store,
+                                   executor=ServerQueryExecutor())
         assert host_inst.launch_debug() == {"enabled": False}
     finally:
         pass  # instances were never started; nothing to drain

@@ -1425,11 +1425,8 @@ def test_device_i64_inside_kernel_body(tmp_path):
     """Seeded mutation 3: an i64 op outside the blessed limb-reassembly
     pattern — here, inside the kernel body itself."""
     src = _real_src("engine/pallas_kernels.py")
-    bad = src.replace(
-        "out_seg[0, :] += mask.astype(jnp.int32).sum(axis=0, "
-        "dtype=jnp.int32)",
-        "out_seg[0, :] += mask.astype(jnp.int64).sum(axis=0, "
-        "dtype=jnp.int32)")
+    bad = src.replace("m_i = mask.astype(jnp.int32)",
+                      "m_i = mask.astype(jnp.int64)")
     assert bad != src
     hits = _device_scratch(tmp_path, "pallas_kernels.py", bad)
     assert len(hits) == 1 and "Pallas kernel body" in hits[0].message, \

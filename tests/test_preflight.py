@@ -16,6 +16,8 @@ The contract under ``pytest -m pallas_preflight``:
   ``GET /debug/pallas`` together with the verdict table.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -201,7 +203,7 @@ def test_attach_verdicts_seeds_blocklist_under_pessimal_model(segs):
     with vmem reasons -> the engine declines them loudly."""
     from pinot_tpu.engine import ServerQueryExecutor
 
-    tiny = preflight.LoweringModel(vmem_bytes=1 << 16)
+    tiny = dataclasses.replace(preflight.TPU_V5E, vmem_bytes=1 << 16)
     table = preflight.run_preflight(segs, model=tiny, fuzz=False)
     assert len(table["ssb_failed"]) == 13
     ex = ServerQueryExecutor(use_device=True, use_pallas=True)
@@ -284,3 +286,12 @@ def test_not_extractable_plan_reports_reason(segs):
     spec, eff, reason = preflight.extract_query_spec(plan, staged)
     assert spec is None and eff is None
     assert reason == "pallas_distinct_agg"
+
+
+def test_lowering_model_is_keyed_by_a_named_device_kind():
+    assert preflight.model_for("TPU v5 lite") is preflight.TPU_V5E
+    assert preflight.TPU_V5E.device_kind == "TPU v5 lite"
+    with pytest.raises(ValueError, match="no lowering model"):
+        preflight.model_for("TPU v9 imaginary")
+    with pytest.raises(TypeError):
+        preflight.LoweringModel()       # no anonymous 16 MB default

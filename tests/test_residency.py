@@ -501,3 +501,46 @@ def test_snapshot_is_bytes_accurate(segs):
     assert snap["stagedBytes"] == ent["bytes"]
     assert snap["peakBytes"] >= snap["stagedBytes"]
     assert snap["budgetBytes"] is None
+
+
+# --------------------------------------------------------------------------
+# budget resolution against the backend
+# --------------------------------------------------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform = platform
+        self.device_kind = f"fake {platform}"
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("platform,stats,want", [
+    ("tpu", {"bytes_limit": 1000}, 750),
+    ("cpu", None, None),                 # the CPU answer: uncapped
+    ("cpu", {}, None),
+])
+def test_auto_budget_from_backend(monkeypatch, platform, stats, want):
+    import jax
+
+    from pinot_tpu.engine.residency import resolve_budget_bytes
+
+    monkeypatch.setattr(jax, "devices",
+                        lambda: [_FakeDevice(platform, stats)])
+    assert resolve_budget_bytes() == want
+
+
+def test_auto_budget_raises_on_accelerator_without_bytes_limit(monkeypatch):
+    """A TPU that reports no memory limit is an error, not "uncapped"."""
+    import jax
+
+    from pinot_tpu.engine.residency import resolve_budget_bytes
+
+    monkeypatch.setattr(jax, "devices",
+                        lambda: [_FakeDevice("tpu", None)])
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        resolve_budget_bytes()
+    # an explicit budget never asks the backend
+    assert resolve_budget_bytes(1234) == 1234

@@ -111,6 +111,12 @@ class BrokerRequestHandler:
 
         self.coalesce = coalesce
         self._flights = SingleFlight()
+        # the broker's own request ids (next() on a count is atomic)
+        import itertools
+        import os
+
+        self._request_ids = itertools.count(1)
+        self._request_prefix = f"b{os.getpid():x}"
         self._leading = _threading.local()
         # continuous telemetry: the broker front door records per-table
         # windowed latency/error (the SLO tracker's input) and exposes
@@ -226,6 +232,12 @@ class BrokerRequestHandler:
         self.metrics.meter(BrokerMeter.QUERIES).mark()
         response = BrokerResponse()
         tel_table: List[str] = [""]  # resolved after compile, read by finish
+        # a query that asks for its trace also gets each phase's start and
+        # the broker thread's CPU time in it (the clock of the span tree);
+        # "trace" in the text is a hint that costs an untraced query one
+        # substring test, ctx.trace_enabled decides after the compile
+        clock = _PhaseClock(start) \
+            if isinstance(sql, str) and "trace" in sql else None
 
         def phase(name: str, t0: float) -> float:
             """Record a broker phase (ref: BrokerQueryPhase timers at
@@ -235,6 +247,8 @@ class BrokerRequestHandler:
             response.phase_times_ms[name] = \
                 response.phase_times_ms.get(name, 0.0) + ms
             self.metrics.timer(name).update_ms(ms)
+            if clock is not None:
+                clock.note(name, t0)
             return now
 
         def finish(resp: BrokerResponse) -> BrokerResponse:
@@ -258,6 +272,13 @@ class BrokerRequestHandler:
             response.add_exception(SQL_PARSING_ERROR, str(e))
             return finish(response)
         tel_table[0] = ctx.table_name or ""
+        # every query that came through the broker has a request id: the
+        # client's OPTION(requestId=...) or the broker's own, carried to
+        # the servers in the context and onto both roots of the span tree
+        ctx.options.setdefault(
+            "requestId", f"{self._request_prefix}-{next(self._request_ids)}")
+        if not ctx.trace_enabled:
+            clock = None
         t = phase(BrokerQueryPhase.COMPILATION, start)
 
         if access_control is not None:
@@ -309,6 +330,8 @@ class BrokerRequestHandler:
         # routing). Tickets release in the finally below; rejection is the
         # typed retriable error, surfaced as a 429-coded exception.
         tickets: List[object] = []
+        if clock is not None:
+            clock.note("ADMISSION", time.perf_counter())
         try:
             for table in physical:
                 t_adm = self.admission.admit(table)
@@ -327,14 +350,17 @@ class BrokerRequestHandler:
             return self._scatter_reduce(ctx, physical, gapfill_spec,
                                         response, phase, finish, start,
                                         principal, access_control,
-                                        admit_wait_ms=admit_wait_ms)
+                                        admit_wait_ms=admit_wait_ms,
+                                        clock=clock)
         finally:
             for t_adm in tickets:
                 self.admission.release(t_adm)
 
     def _scatter_reduce(self, ctx, physical, gapfill_spec, response,
                         phase, finish, start, principal, access_control,
-                        admit_wait_ms: float = 0.0) -> BrokerResponse:
+                        admit_wait_ms: float = 0.0,
+                        clock: Optional["_PhaseClock"] = None
+                        ) -> BrokerResponse:
         """Post-admission half of the front door: subquery rewrite ->
         hybrid split -> routing -> scatter/gather -> reduce.
         ``admit_wait_ms`` is the front-door admission-gate queue wait —
@@ -363,6 +389,8 @@ class BrokerRequestHandler:
         # the stragglers' network wait; finish() below runs only the
         # final trim/HAVING/post-agg pass
         acc = self.reduce_service.accumulator(ctx)
+        if clock is not None:
+            acc.trace_origin = start
         for table, sub_ctx in self._split_hybrid(ctx, physical,
                                                  stats=broker_stats):
             t = time.perf_counter()
@@ -415,7 +443,7 @@ class BrokerRequestHandler:
             # the stats travel, not just on the top-level response
             stats.merge(broker_stats)
             response.stats = stats
-            traced_stats = stats if (stats.trace or stats.spans) else None
+            traced_stats = stats if stats.spans else None
             for msg in server_errors:
                 # partial result: the table stands, but the caller sees it
                 response.add_exception(SERVER_NOT_RESPONDING_ERROR, msg)
@@ -427,21 +455,31 @@ class BrokerRequestHandler:
         response.time_used_ms = (time.perf_counter() - start) * 1e3
         if traced_stats is not None:
             # ref: trace JSON attached to response metadata
-            # (ServerQueryExecutorV1Impl.java:221-226). The legacy flat
-            # "entries" view is preserved (emitted from the span tree at
-            # each span close); "spans" is the broker root with the
-            # measured broker phases as children and every server's tree
-            # — instance-tagged at gather, see _tag_trace — re-parented
-            # under ScatterGather. Assembled AFTER the REDUCE phase timer
-            # so the root's children account the full broker wall time.
-            from pinot_tpu.common.tracing import build_broker_root
+            # (ServerQueryExecutorV1Impl.java:221-226). The flat
+            # "entries" view is derived from the servers' trees (one
+            # entry a span, instance-tagged); "spans" is the broker root
+            # with the measured broker phases as children and every
+            # server's tree — instance-tagged at gather, see _tag_trace —
+            # re-parented under ScatterGather. Assembled AFTER the REDUCE
+            # phase timer so the root's children account the full broker
+            # wall time.
+            from pinot_tpu.common.tracing import (
+                build_broker_root,
+                flatten_spans,
+            )
 
+            entries = traced_stats.trace + flatten_spans(traced_stats.spans)
             root = build_broker_root(
                 response.phase_times_ms, traced_stats.spans,
                 response.time_used_ms, admission_wait_ms=admit_wait_ms,
-                reduce_folds=acc.fold_spans)
-            response.trace_info = {"entries": traced_stats.trace,
-                                   "spans": [root]}
+                reduce_folds=acc.fold_spans,
+                phase_start_ms=clock.start_ms if clock else None,
+                phase_cpu_ms=clock.cpu_by_phase() if clock else None,
+                # the root's start on the wall clock: now, less its age
+                start_epoch_ms=time.time() * 1e3
+                - (time.perf_counter() - start) * 1e3,
+                request_id=ctx.request_id)
+            response.trace_info = {"entries": entries, "spans": [root]}
         return finish(response)
 
     # -- table resolution + hybrid split -------------------------------------
@@ -777,6 +815,35 @@ class BrokerRequestHandler:
 
     def shutdown(self) -> None:
         self._pool.stop()
+
+
+class _PhaseClock:
+    """A traced query's broker phases on the span tree's clock: each
+    phase's first start as an offset from the root's, and the broker
+    thread's CPU time in it (``build_broker_root`` reads both)."""
+
+    __slots__ = ("origin", "cpu0", "cpu_at", "start_ms", "cpu_ms")
+
+    def __init__(self, origin: float):
+        self.origin = origin
+        self.cpu0 = self.cpu_at = time.thread_time()
+        self.start_ms: Dict[str, float] = {}
+        self.cpu_ms: Dict[str, float] = {}
+
+    def note(self, name: str, t0: float) -> None:
+        """A phase that began at ``t0`` ends now (the admission wait is
+        noted as it begins: a wait has no CPU time)."""
+        self.start_ms.setdefault(name, (t0 - self.origin) * 1e3)
+        now = time.thread_time()
+        if name != "ADMISSION":
+            self.cpu_ms[name] = self.cpu_ms.get(name, 0.0) \
+                + (now - self.cpu_at) * 1e3
+        self.cpu_at = now
+
+    def cpu_by_phase(self) -> Dict[str, float]:
+        """CPU ms a phase, and ``TOTAL``: all of the root's so far."""
+        return dict(self.cpu_ms,
+                    TOTAL=(time.thread_time() - self.cpu0) * 1e3)
 
 
 def _and(a: Optional[FilterNode], b: FilterNode) -> FilterNode:

@@ -134,6 +134,9 @@ class ReduceAccumulator:
         self.exceptions: List[str] = []
         self.tables: List[DataTable] = []
         self.fold_spans: List[Dict[str, Any]] = []
+        # a traced query's broker sets its root's perf_counter start: the
+        # folds then carry their offset from it and the thread's CPU time
+        self.trace_origin: Optional[float] = None
         self.rtype: Optional[ResponseType] = None
         self._mixed: Optional[MixedResponseTypeError] = None
         self.vectorized = service.vectorized and ctx.options.get(
@@ -168,6 +171,7 @@ class ReduceAccumulator:
     # -- arrival fold --------------------------------------------------------
     def add(self, table: DataTable, instance: Optional[str] = None) -> None:
         t0 = time.perf_counter()
+        c0 = time.thread_time() if self.trace_origin is not None else 0.0
         self.stats.merge(table.stats)
         self.exceptions.extend(table.exceptions)
         if table.exceptions:
@@ -190,6 +194,13 @@ class ReduceAccumulator:
             self._fold(table)
         span = {"name": "Fold", "rows": table.num_rows(),
                 "ms": round((time.perf_counter() - t0) * 1e3, 3)}
+        if self.trace_origin is not None:
+            import threading
+
+            span.update(
+                startMs=round((t0 - self.trace_origin) * 1e3, 3),
+                cpuMs=round((time.thread_time() - c0) * 1e3, 3),
+                thread=threading.current_thread().name)
         if instance is not None:
             span["instance"] = instance
         self.fold_spans.append(span)

@@ -438,19 +438,29 @@ class DataTable:
                 for i in range(self._n_rows or 0)]
 
     # -- framing -------------------------------------------------------------
-    def to_buffers(self) -> List[Any]:
+    def to_buffers(self, payload: Optional[List[Any]] = None) -> List[Any]:
         """The wire form as an ordered list of buffer parts (bytes /
         memoryviews over the live column arrays). Layout:
         magic | u8 type-ordinal | stats json section | exceptions json
         section | per-type payload. Zero-copy: typed column buffers are
         framed directly (``Column.encode_parts``); nothing assembles an
         intermediate bytearray. A transport that can writev/scatter sends
-        the parts as-is; ``to_bytes`` is the single-buffer join."""
+        the parts as-is; ``to_bytes`` is the single-buffer join.
+        ``payload``: ``payload_buffers()`` framed beforehand (a traced
+        table's transport times the framing and notes it in the stats,
+        which are framed here, after)."""
+        if payload is None:
+            payload = self.payload_buffers()
         parts: List[Any] = [MAGIC, bytes([_WIRE_ORDINAL[self.response_type]])]
         _put_section(parts, json.dumps(
             self.stats.to_dict(), separators=(",", ":")).encode("utf-8"))
         _put_section(parts, json.dumps(
             self.exceptions, separators=(",", ":")).encode("utf-8"))
+        return parts + payload
+
+    def payload_buffers(self) -> List[Any]:
+        """The per-type payload's buffer parts (all but the head)."""
+        parts: List[Any] = []
         t = self.response_type
         if t is ResponseType.AGGREGATION:
             states = [decode_value(s) for s in self._payload["states"]] \

@@ -12,14 +12,21 @@ Two data products ride together:
 - **Span trees** (:class:`Span` / :class:`SpanRecorder`): a hierarchical
   record of the full query lifecycle — broker parse/route/scatter ->
   server admission queue -> scheduler queue -> residency lease ->
-  launch-dispatcher queue + vmap batch -> per-segment kernel + D2H ->
-  sharded combine -> broker reduce. Every span carries wall ms, an
-  explicit queue-vs-work split (``queueMs``/``workMs``) where a queue
-  exists, and structured attributes. Server trees ship on the DataTable
-  wire (``QueryStats.spans``) and are re-parented under the broker root
-  at reduce; the legacy flat ``traceInfo["entries"]`` view is EMITTED
-  FROM the tree (each span close appends one flat entry), so pre-span
-  consumers keep working.
+  launch-dispatcher queue + vmap batch -> per-segment walk, plan,
+  dispatch, device wait, D2H, decode -> sharded combine -> broker
+  reduce. Every span carries wall ms, its start as an offset from its
+  root's (``startMs``), the CPU time of the thread that ran it
+  (``cpuMs``; 0 for a pure wait), that thread's name, an explicit
+  queue-vs-work split (``queueMs``/``workMs``) where a queue exists, and
+  structured attributes; each root (``BrokerQuery``, ``ServerQuery``)
+  also its start on the wall clock (``startEpochMs``) and the request's
+  id. A span's self time is its ``ms`` less the union of its children's
+  intervals. Server trees ship on the DataTable wire
+  (``QueryStats.spans``) and are re-parented under the broker root at
+  reduce; the flat ``traceInfo["entries"]`` view is derived from the
+  tree (:func:`flatten_spans`), never kept beside it. Every recorded
+  span is also a ``jax.profiler.TraceAnnotation``: under a profiler
+  session the program's spans lie on the device trace's clock.
 - **The decision ledger**: every point where execution declines a faster
   rung emits a machine-readable ``(decision_point, chosen, declined,
   reason_code)`` record — pallas eligibility, star-tree fit, residency
@@ -45,24 +52,83 @@ from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Tuple
 
 # span-dict keys the serializer owns; attributes must not collide
-_RESERVED = ("name", "ms", "queueMs", "workMs", "children")
+_RESERVED = ("name", "ms", "queueMs", "workMs", "children", "startMs",
+             "cpuMs", "thread", "startEpochMs", "requestId")
+
+_ANNOTATION: Any = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def annotate(name: str, request_id: Optional[str]):
+    """An entered ``jax.profiler.TraceAnnotation`` for one recorded span:
+    with a profiler session open the span lands on its thread's line of
+    ``/host:CPU`` beside the device's ``XLA Ops``; with none it is a
+    TraceMe that records nothing. None where jax is not installed."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except ImportError:  # a jax-less client process
+            _ANNOTATION = False
+    if not _ANNOTATION:
+        return None
+    ann = (_ANNOTATION(name, request=request_id) if request_id
+           else _ANNOTATION(name))
+    ann.__enter__()
+    return ann
+
+
+def _measured_span(name: str, wall_ms: float, start_ms: float,
+                   cpu_ms: float, thread: Optional[str],
+                   queue_ms: Optional[float],
+                   attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """The wire form of a span that was measured, not recorded (a wait
+    that is over, a phase another thread stamped)."""
+    d: Dict[str, Any] = {
+        "name": name, "ms": round(wall_ms, 3),
+        "startMs": round(start_ms, 3), "cpuMs": round(cpu_ms, 3),
+        "thread": thread or threading.current_thread().name}
+    if queue_ms is not None:
+        d["queueMs"] = round(queue_ms, 3)
+        d["workMs"] = round(max(wall_ms - queue_ms, 0.0), 3)
+    for k, v in attrs.items():
+        if k not in _RESERVED:
+            d[k] = v
+    return d
 
 
 class Span:
     """One open span. Closed spans become plain dicts (wire-ready)."""
 
-    __slots__ = ("name", "t0", "wall_ms", "queue_ms", "attrs", "children")
+    __slots__ = ("name", "t0", "c0", "start_ms", "wall_ms", "cpu_ms",
+                 "queue_ms", "thread", "epoch_ms", "request_id", "attrs",
+                 "children", "_ann")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None):
         self.name = name
         self.t0 = time.perf_counter()
+        self.c0 = time.thread_time()
+        self.start_ms = 0.0
         self.wall_ms = 0.0
+        self.cpu_ms = 0.0
         self.queue_ms: Optional[float] = None
+        self.thread = threading.current_thread().name
+        # roots only: the wall clock read beside t0, and the request's id
+        self.epoch_ms: Optional[float] = None
+        self.request_id: Optional[str] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.children: List[Dict[str, Any]] = []
+        self._ann: Any = None
 
     def to_dict(self) -> Dict[str, Any]:
-        d: Dict[str, Any] = {"name": self.name, "ms": round(self.wall_ms, 3)}
+        d: Dict[str, Any] = {"name": self.name, "ms": round(self.wall_ms, 3),
+                             "startMs": round(self.start_ms, 3),
+                             "cpuMs": round(self.cpu_ms, 3),
+                             "thread": self.thread}
+        if self.epoch_ms is not None:
+            d["startEpochMs"] = round(self.epoch_ms, 3)
+            if self.request_id is not None:
+                d["requestId"] = self.request_id
         if self.queue_ms is not None:
             # the explicit queue-vs-work split: queueMs is time spent
             # WAITING at this level, workMs the remainder
@@ -84,17 +150,21 @@ class SpanRecorder:
 
     ``sink`` is the completed-top-level-span list (normally the stats'
     own ``spans`` field, so finished trees land directly on the wire
-    payload); ``legacy`` is the flat entry list (``QueryStats.trace``) —
-    every span close appends one ``{"operator", "ms", ...attrs}`` entry,
-    preserving the pre-span-tree ``traceInfo["entries"]`` contract."""
+    payload). ``origin`` is the ``perf_counter`` reading every span's
+    ``startMs`` is an offset from: the first span opened sets it (and is
+    the ROOT: it alone carries ``startEpochMs`` and ``requestId``); a
+    worker's recorder is handed its query's origin, so its trees come
+    out on the root's clock and are adopted as they are."""
 
-    __slots__ = ("spans", "_stack", "_legacy")
+    __slots__ = ("spans", "_stack", "origin", "request_id")
 
     def __init__(self, sink: Optional[List[Dict[str, Any]]] = None,
-                 legacy: Optional[List[Dict[str, Any]]] = None):
+                 origin: Optional[float] = None,
+                 request_id: Optional[str] = None):
         self.spans: List[Dict[str, Any]] = sink if sink is not None else []
         self._stack: List[Span] = []
-        self._legacy = legacy
+        self.origin = origin
+        self.request_id = request_id
 
     # -- open/close ----------------------------------------------------------
     def span_begin(self, name: str, **attrs: Any) -> Span:
@@ -102,7 +172,14 @@ class SpanRecorder:
         ``span_end`` on every path, exception edges included — the
         graftlint ``spanpair`` obligation gates manual pairs; prefer the
         ``span()`` context manager."""
+        ann = annotate(name, self.request_id)
         sp = Span(name, attrs)
+        sp._ann = ann
+        if self.origin is None:
+            self.origin = sp.t0
+            sp.epoch_ms = time.time() * 1e3
+            sp.request_id = self.request_id
+        sp.start_ms = (sp.t0 - self.origin) * 1e3
         self._stack.append(sp)
         return sp
 
@@ -118,6 +195,12 @@ class SpanRecorder:
             self.span_end(self._stack[-1])
         self._stack.pop()
         span.wall_ms = (time.perf_counter() - span.t0) * 1e3
+        # a sweep from another thread (a worker's tree closed by its
+        # caller on an error path) reads that thread's clock: never < 0
+        span.cpu_ms = max((time.thread_time() - span.c0) * 1e3, 0.0)
+        if span._ann is not None:
+            span._ann.__exit__(None, None, None)
+            span._ann = None
         if queue_ms is not None:
             span.queue_ms = queue_ms
         if attrs:
@@ -125,10 +208,16 @@ class SpanRecorder:
         d = span.to_dict()
         target = self._stack[-1].children if self._stack else self.spans
         target.append(d)
-        if self._legacy is not None:
-            self._legacy.append({"operator": span.name,
-                                 "ms": round(span.wall_ms, 3), **span.attrs})
         return d
+
+    def backdate_root(self, ms: float) -> None:
+        """Move the just-opened root's start back by ``ms``: a wait that
+        ended as the root opened (the admission gate's) is then a child
+        inside the root's interval, at ``startMs`` 0."""
+        root = self._stack[0]
+        root.t0 -= ms / 1e3
+        root.epoch_ms -= ms
+        self.origin = root.t0
 
     @contextmanager
     def span(self, name: str, **attrs: Any):
@@ -151,18 +240,22 @@ class SpanRecorder:
     # -- pre-measured / adopted spans ---------------------------------------
     def add_completed(self, name: str, wall_ms: float,
                       queue_ms: Optional[float] = None,
+                      start: Optional[float] = None, cpu_ms: float = 0.0,
+                      thread: Optional[str] = None,
                       **attrs: Any) -> Dict[str, Any]:
-        """Attach an already-measured span (e.g. a queue wait that ended
-        before the recorder existed) as a child of the current span."""
-        sp = Span(name, attrs)
-        sp.wall_ms = wall_ms
-        sp.queue_ms = queue_ms
-        d = sp.to_dict()
+        """Attach an already-measured span (a queue wait that ended
+        before the recorder existed, a phase another thread stamped) as a
+        child of the current span. ``start`` is its ``perf_counter``
+        start; without one it is taken to have ended now. A pure wait
+        carries ``cpu_ms`` 0. No profiler annotation: it is over."""
+        if start is None:
+            start = time.perf_counter() - wall_ms / 1e3
+        if self.origin is None:
+            self.origin = start
+        d = _measured_span(name, wall_ms, (start - self.origin) * 1e3,
+                           cpu_ms, thread, queue_ms, attrs)
         target = self._stack[-1].children if self._stack else self.spans
         target.append(d)
-        if self._legacy is not None:
-            self._legacy.append({"operator": name, "ms": round(wall_ms, 3),
-                                 **attrs})
         return d
 
     def adopt(self, span_dicts: List[Dict[str, Any]]) -> None:
@@ -182,12 +275,19 @@ def stats_tracer(stats: Any) -> Optional[SpanRecorder]:
     return getattr(stats, "_recorder", None)
 
 
-def start_trace(stats: Any) -> SpanRecorder:
+def start_trace(stats: Any, parent: Optional[SpanRecorder] = None,
+                request_id: Optional[str] = None) -> SpanRecorder:
     """Attach a recorder to ``stats`` (idempotent). Completed roots land
-    in ``stats.spans`` (the wire field); flat entries in ``stats.trace``."""
+    in ``stats.spans`` (the wire field). ``parent`` is the query's own
+    recorder when ``stats`` is a fan-out worker's private stats: the
+    worker records on the parent's clock and under its request id."""
     rec = getattr(stats, "_recorder", None)
     if rec is None:
-        rec = SpanRecorder(sink=stats.spans, legacy=stats.trace)
+        if parent is not None:
+            rec = SpanRecorder(sink=stats.spans, origin=parent.origin,
+                               request_id=parent.request_id)
+        else:
+            rec = SpanRecorder(sink=stats.spans, request_id=request_id)
         stats._recorder = rec
     return rec
 
@@ -215,48 +315,63 @@ def maybe_span(stats: Any, name: str, **attrs: Any):
     return rec.span(name, **attrs)
 
 
+def _shift_starts(spans: List[Dict[str, Any]], by_ms: float) -> None:
+    for d in spans:
+        d["startMs"] = round(d.get("startMs", 0.0) + by_ms, 3)
+        _shift_starts(d.get("children", ()), by_ms)
+
+
 def attach_root_child(stats: Any, name: str, wall_ms: float,
                       queue_ms: Optional[float] = None, front: bool = False,
-                      **attrs: Any) -> None:
+                      cpu_ms: float = 0.0, **attrs: Any) -> None:
     """Retroactively attach a pre-measured child to the stats' FINISHED
     root span (the scheduler-queue wait is measured by the server tier
     after the executor already closed the tree). The root's wall time
     grows to keep the tree self-consistent (children must account inside
-    the root)."""
+    the root): a ``front`` child is laid directly before the root's old
+    start, which moves back by the child's length (``startEpochMs`` with
+    it, every other span's ``startMs`` forward); any other child is laid
+    after the root's old end. A pure wait carries ``cpu_ms`` 0."""
     if not stats.spans:
         return
     root = stats.spans[0]
-    sp = Span(name, attrs)
-    sp.wall_ms = wall_ms
-    sp.queue_ms = queue_ms
-    child = sp.to_dict()
     kids = root.setdefault("children", [])
+    child = _measured_span(name, wall_ms,
+                           0.0 if front else root.get("ms", 0.0), cpu_ms,
+                           None, queue_ms, attrs)
     if front:
+        _shift_starts(kids, wall_ms)
+        if "startEpochMs" in root:
+            root["startEpochMs"] = round(root["startEpochMs"] - wall_ms, 3)
         kids.insert(0, child)
     else:
         kids.append(child)
     root["ms"] = round(root.get("ms", 0.0) + wall_ms, 3)
-    stats.trace.append({"operator": name, "ms": round(wall_ms, 3), **attrs})
 
 
 def flatten_spans(span_dicts: List[Dict[str, Any]]
                   ) -> List[Dict[str, Any]]:
-    """Span trees -> legacy flat entries (pre-order), for consumers that
-    want the old shape derived from the tree rather than the emitted
-    legacy list."""
+    """Span trees -> the flat ``traceInfo["entries"]`` view (pre-order):
+    one ``{"operator", "ms", ...attributes}`` entry a span. The one
+    source of the flat view: nothing keeps a second list beside the
+    tree. A root's ``instance`` tag (set at gather, before re-parenting)
+    is handed down to every entry of its tree."""
     out: List[Dict[str, Any]] = []
 
-    def walk(d: Dict[str, Any]) -> None:
+    def walk(d: Dict[str, Any], instance: Any) -> None:
         e = {"operator": d["name"], "ms": d["ms"]}
         for k, v in d.items():
             if k not in ("name", "ms", "children"):
                 e[k] = v
+        instance = d.get("instance", instance)
+        if instance is not None:
+            e["instance"] = instance
         out.append(e)
         for c in d.get("children", ()):
-            walk(c)
+            walk(c, instance)
 
     for d in span_dicts:
-        walk(d)
+        walk(d, None)
     return out
 
 
@@ -264,43 +379,76 @@ def build_broker_root(phase_ms: Dict[str, float],
                       server_spans: List[Dict[str, Any]],
                       total_ms: float,
                       admission_wait_ms: float = 0.0,
-                      reduce_folds: Optional[List[Dict[str, Any]]] = None
+                      reduce_folds: Optional[List[Dict[str, Any]]] = None,
+                      phase_start_ms: Optional[Dict[str, float]] = None,
+                      phase_cpu_ms: Optional[Dict[str, float]] = None,
+                      start_epoch_ms: Optional[float] = None,
+                      request_id: Optional[str] = None
                       ) -> Dict[str, Any]:
     """Assemble the broker root span from the measured broker phases
     (COMPILATION/ROUTING/SCATTER_GATHER/REDUCE), re-parenting the
     per-server trees under the ScatterGather child — the reduce-side half
     of the reference's per-server ``traceInfo`` keying.
 
+    ``phase_start_ms`` gives each phase's (first) start as an offset from
+    the root's (``ADMISSION`` for the front-door wait), ``phase_cpu_ms``
+    the broker thread's CPU time in it (``TOTAL``: in the whole root;
+    absent where it was not taken: a query only a server sampled),
+    ``start_epoch_ms`` the root's start on the wall clock. A server's root keeps its own ``startEpochMs`` and
+    gains the ``startMs`` that puts it on the broker's clock.
+
     ``reduce_folds`` is the reduce-as-arrivals split: one Fold child per
-    folded DataTable (its work overlapped the gather wait, so the folds'
-    wall time lives INSIDE ScatterGather; the Reduce child keeps the
-    final merge/trim/HAVING pass and carries a foldMs rollup)."""
+    folded DataTable. Its work overlapped the gather wait, so the folds
+    are children of ScatterGather, where their time lies; the Reduce
+    child keeps the final merge/trim/HAVING pass and a foldMs rollup."""
+    starts = phase_start_ms or {}
+    cpus = phase_cpu_ms or {}
+    thread = threading.current_thread().name
+
+    def span(name: str, phase: str, ms: float) -> Dict[str, Any]:
+        d: Dict[str, Any] = {"name": name, "ms": round(ms, 3),
+                             "startMs": round(starts.get(phase, 0.0), 3),
+                             "thread": thread}
+        if phase in cpus:
+            d["cpuMs"] = round(cpus[phase], 3)
+        return d
+
     children: List[Dict[str, Any]] = []
     if admission_wait_ms > 0:
-        children.append({"name": "Admission",
-                         "ms": round(admission_wait_ms, 3),
-                         "queueMs": round(admission_wait_ms, 3),
-                         "workMs": 0.0})
+        adm = span("Admission", "ADMISSION", admission_wait_ms)
+        adm.update(cpuMs=0.0, queueMs=round(admission_wait_ms, 3),
+                   workMs=0.0)
+        children.append(adm)
     for phase, name in (("COMPILATION", "Compile"), ("ROUTING", "Routing")):
         if phase in phase_ms:
-            children.append({"name": name,
-                             "ms": round(phase_ms[phase], 3)})
-    sg: Dict[str, Any] = {
-        "name": "ScatterGather",
-        "ms": round(phase_ms.get("SCATTER_GATHER", 0.0), 3)}
-    if server_spans:
-        sg["children"] = list(server_spans)
+            children.append(span(name, phase, phase_ms[phase]))
+    sg = span("ScatterGather", "SCATTER_GATHER",
+              phase_ms.get("SCATTER_GATHER", 0.0))
+    gathered = list(server_spans)
+    if start_epoch_ms is not None:
+        for s in gathered:
+            if "startEpochMs" in s:
+                s["startMs"] = round(s["startEpochMs"] - start_epoch_ms, 3)
+    gathered.extend(reduce_folds or ())
+    if gathered:
+        sg["children"] = gathered
     children.append(sg)
     if "REDUCE" in phase_ms:
-        reduce_span: Dict[str, Any] = {"name": "Reduce",
-                                       "ms": round(phase_ms["REDUCE"], 3)}
+        reduce_span = span("Reduce", "REDUCE", phase_ms["REDUCE"])
         if reduce_folds:
             reduce_span["foldMs"] = round(
                 sum(f.get("ms", 0.0) for f in reduce_folds), 3)
-            reduce_span["children"] = list(reduce_folds)
         children.append(reduce_span)
-    return {"name": "BrokerQuery", "ms": round(total_ms, 3),
-            "children": children}
+    root: Dict[str, Any] = {"name": "BrokerQuery", "ms": round(total_ms, 3),
+                            "startMs": 0.0, "thread": thread}
+    if cpus:
+        root["cpuMs"] = round(cpus.get("TOTAL", sum(cpus.values())), 3)
+    if start_epoch_ms is not None:
+        root["startEpochMs"] = round(start_epoch_ms, 3)
+    if request_id is not None:
+        root["requestId"] = request_id
+    root["children"] = children
+    return root
 
 
 # --------------------------------------------------------------------------

@@ -279,12 +279,15 @@ class ServerQueryExecutor:
 
             requested = random.random() < self.trace_sample
         if requested or self.queries.force_trace:
-            rec = start_trace(stats)
+            rec = start_trace(stats, request_id=ctx.request_id)
             stats._trace_requested = requested
             root = rec.span_begin("ServerQuery", table=ctx.table_name)
             stats._root_span = root  # closed by _close_query's close_all
+            # the admission wait ended as the root opened: the root
+            # starts where the wait did, so the wait lies inside it
+            rec.backdate_root(admit_wait_ms)
             rec.add_completed("Admission", wall_ms=admit_wait_ms,
-                              queue_ms=admit_wait_ms)
+                              queue_ms=admit_wait_ms, start=root.t0)
         token = self.queries.begin(ctx, stats)
         stats._registry_token = token  # phase updates from inner layers
         return stats, token
@@ -303,7 +306,6 @@ class ServerQueryExecutor:
             # forced recording: the slow log copied what it needed; the
             # response must look exactly like an untraced one
             stats.spans.clear()
-            stats.trace.clear()
             stats._recorder = None
 
     def _execute_instance_admitted(self, ctx: QueryContext,
@@ -388,15 +390,21 @@ class ServerQueryExecutor:
             aggs = [resolve_agg(f) for f in ctx.aggregations]
             if ctx.is_group_by:
                 merged = self._execute_group_by(ctx, aggs, segments, stats)
-                if merged.trim(self.num_groups_limit):
-                    stats.num_groups_limit_reached = True
-                return DataTable.for_group_by(merged.groups,
-                                              self._schema_types(segments[0]),
-                                              stats)
+                # the result to its wire form (every key and state encoded)
+                with maybe_span(stats, "Serialize",
+                                rows=len(merged.groups)):
+                    if merged.trim(self.num_groups_limit):
+                        stats.num_groups_limit_reached = True
+                    return DataTable.for_group_by(
+                        merged.groups, self._schema_types(segments[0]),
+                        stats)
             merged_agg = self._execute_aggregation(ctx, aggs, segments, stats)
-            return DataTable.for_aggregation(merged_agg.states, stats)
+            with maybe_span(stats, "Serialize", rows=1):
+                return DataTable.for_aggregation(merged_agg.states, stats)
         finally:
-            self.residency.end_query(lease, stats)
+            # unpin, re-measure the residents, re-enforce the budget
+            with maybe_span(stats, "Release"):
+                self.residency.end_query(lease, stats)
 
     def execute(self, ctx: QueryContext,
                 segments: List[ImmutableSegment]) -> Tuple[ResultTable, QueryStats]:
@@ -479,7 +487,9 @@ class ServerQueryExecutor:
             merged_agg = self._execute_aggregation(ctx, aggs, segments, stats)
             return reduce_aggregation(ctx, aggs, merged_agg), stats
         finally:
-            self.residency.end_query(lease, stats)
+            # unpin, re-measure the residents, re-enforce the budget
+            with maybe_span(stats, "Release"):
+                self.residency.end_query(lease, stats)
 
     def _begin_lease(self, ctx: QueryContext,
                      segments: List[ImmutableSegment], stats: QueryStats):
@@ -563,11 +573,12 @@ class ServerQueryExecutor:
             lambda seg, st: self._segment_aggregation(ctx, aggs, seg, st),
             segments, stats)
         merged: Optional[AggResult] = None
-        for part in parts:
-            if merged is None:
-                merged = part
-            else:
-                merged.merge(part, aggs)
+        with maybe_span(stats, "CombineSegments", segments=len(parts)):
+            for part in parts:
+                if merged is None:
+                    merged = part
+                else:
+                    merged.merge(part, aggs)
         return merged
 
     def _map_segments(self, fn, segments: List[ImmutableSegment],
@@ -599,16 +610,32 @@ class ServerQueryExecutor:
         if self.worker_threads <= 1 or len(segments) <= 1:
             return [fn(seg, stats) for seg in segments]
         pool = self._worker_pool()
-        traced = stats_tracer(stats) is not None
+        parent = stats_tracer(stats)
         locals_ = [QueryStats() for _ in segments]
         for st in locals_:  # the pin set must ride into worker threads
             st._staging_lease = lease
             st._tel_table = getattr(stats, "_tel_table", "")
-            if traced:
+            if parent is not None:
                 # recorders are thread-confined: each worker records into
-                # its private stats; merge() below re-parents the
-                # finished spans under the caller's open span
-                start_trace(st)
+                # its private stats, on the query's clock; merge() below
+                # re-parents the finished spans under the caller's open
+                # span
+                start_trace(st, parent=parent)
+        if parent is not None:
+            import time as _time
+
+            t_submit = _time.perf_counter()
+            segment_fn = fn
+
+            def picked_up(seg, st):
+                # submit until a worker picks the task up: a pure wait
+                waited = (_time.perf_counter() - t_submit) * 1e3
+                stats_tracer(st).add_completed(
+                    "SegmentQueue", wall_ms=waited, queue_ms=waited,
+                    start=t_submit, segment=seg.segment_name)
+                return segment_fn(seg, st)
+
+            fn = picked_up
         parts = pool.map(fn, segments, locals_)
         for st in locals_:
             rec = stats_tracer(st)
@@ -667,7 +694,7 @@ class ServerQueryExecutor:
                 if ix is not None:
                     return done(ix, "index")
                 try:
-                    plan = self._plan_for(ctx, seg)
+                    plan = self._plan_for(ctx, seg, stats)
                     return done(self._run_device_scalar(plan, seg, stats),
                                 "device")
                 except PlanError as e:
@@ -758,14 +785,22 @@ class ServerQueryExecutor:
         def declined(reason: str) -> None:
             record_decision(stats, "startree", "scan", "startree", reason)
 
-        pick = self._star_tree_pick(ctx, aggs, seg, on_decline=declined)
-        if pick is None:
-            return None
-        tree, tree_index, preds = pick
-        matches = startree_exec.resolve_matches(seg, preds,
-                                                on_decline=declined)
-        if matches is None:
-            return None  # predicate not dictId-translatable -> scan path
+        if not getattr(seg, "star_trees", None):
+            return None  # no trees: nothing to walk, and not a decline
+        with maybe_span(stats, "StarTreeWalk",
+                        segment=seg.segment_name) as sp:
+            pick = self._star_tree_pick(ctx, aggs, seg, on_decline=declined)
+            if pick is None:
+                return None
+            tree, tree_index, preds = pick
+            matches = startree_exec.resolve_matches(seg, preds,
+                                                    on_decline=declined)
+            if matches is None:
+                return None  # predicate not dictId-translatable -> scan
+            idx = tree.select_records(matches,
+                                      [e.name for e in ctx.group_by])
+            if sp is not None:
+                sp.attrs.update(tree=tree_index, records=int(idx.shape[0]))
 
         def chose(rung: str) -> None:
             # the CHOSEN tree rides the ledger and QueryStats: with
@@ -780,7 +815,7 @@ class ServerQueryExecutor:
             try:
                 res = startree_device.execute_star_tree_device(
                     self, ctx, aggs, seg, tree, matches, stats,
-                    tree_index=tree_index)
+                    tree_index=tree_index, idx=idx)
                 if res is not None:
                     chose("startree_device")
                     return res, "startree_device"
@@ -789,7 +824,7 @@ class ServerQueryExecutor:
                 record_decision(stats, "startree", "startree_host",
                                 "startree_device", e.reason_code)
         res = startree_exec.execute_with_matches(ctx, aggs, seg, tree,
-                                                 matches, stats)
+                                                 matches, stats, idx=idx)
         if res is None:
             # the host walker refused a tree the pick accepted (defensive:
             # the fit re-check inside execute_with_matches disagreed) —
@@ -839,20 +874,23 @@ class ServerQueryExecutor:
                            stats: QueryStats) -> AggResult:
         served = self._try_pallas(plan, seg, stats)
         if served is not None:
-            out, eff = served
-            return decode_scalar_result(eff, seg, out)
-        out = self._run_kernel(plan, seg, stats)
-        return decode_scalar_result(plan, seg, out)
+            out, plan = served
+        else:
+            out = self._run_kernel(plan, seg, stats)
+        with maybe_span(stats, "Decode"):
+            return decode_scalar_result(plan, seg, out)
 
     # -- group-by ----------------------------------------------------------
     def _execute_group_by(self, ctx: QueryContext, aggs: List[AggDef],
                           segments: List[ImmutableSegment],
                           stats: QueryStats) -> GroupByResult:
         merged = GroupByResult()
-        for part in self._map_segments(
-                lambda seg, st: self._segment_group_by(ctx, aggs, seg, st),
-                segments, stats):
-            merged.merge(part, aggs)
+        parts = self._map_segments(
+            lambda seg, st: self._segment_group_by(ctx, aggs, seg, st),
+            segments, stats)
+        with maybe_span(stats, "CombineSegments", segments=len(parts)):
+            for part in parts:
+                merged.merge(part, aggs)
         return merged
 
     def _segment_group_by(self, ctx: QueryContext, aggs: List[AggDef],
@@ -883,7 +921,7 @@ class ServerQueryExecutor:
                     stats.group_by_rung = "index"
                     return done(ix, "index")
                 try:
-                    plan = self._plan_for(ctx, seg)
+                    plan = self._plan_for(ctx, seg, stats)
                     return done(self._run_device_grouped(plan, seg, stats),
                                 "device")
                 except PlanError as e:
@@ -894,11 +932,20 @@ class ServerQueryExecutor:
             return done(host_engine.host_group_by_segment(ctx, aggs, seg,
                                                           stats), "host")
 
-    def _plan_for(self, ctx: QueryContext, seg: ImmutableSegment):
+    def _plan_for(self, ctx: QueryContext, seg: ImmutableSegment,
+                  stats: Optional[QueryStats] = None):
         """plan_segment with an LRU keyed on (sql, segment); a reloaded
         segment (new object, same name) misses via the identity check."""
+        with maybe_span(stats, "Plan", cacheHit=False) as sp:
+            plan, hit = self._plan_cached(ctx, seg)
+            if sp is not None:
+                sp.attrs["cacheHit"] = hit
+        return plan
+
+    def _plan_cached(self, ctx: QueryContext, seg: ImmutableSegment):
+        """-> (plan, whether the cache held it)."""
         if ctx.sql is None:
-            return plan_segment(ctx, seg)
+            return plan_segment(ctx, seg), False
         import weakref
 
         # the key carries: a filter FINGERPRINT (the hybrid split and the
@@ -914,13 +961,13 @@ class ServerQueryExecutor:
             hit = self._plan_cache.get(key)
             if hit is not None and hit[0]() is seg:
                 self._plan_cache.move_to_end(key)
-                return hit[1]
+                return hit[1], True
         plan = plan_segment(ctx, seg)
         with self._plan_cache_lock:
             self._plan_cache[key] = (weakref.ref(seg), plan)
             if len(self._plan_cache) > self._plan_cache_cap:
                 self._plan_cache.popitem(last=False)
-        return plan
+        return plan, False
 
     def _run_device_grouped(self, plan: SegmentPlan, seg: ImmutableSegment,
                             stats: QueryStats) -> GroupByResult:
@@ -928,12 +975,11 @@ class ServerQueryExecutor:
         if served is not None:
             # decode against the EFFECTIVE plan: the probe-narrowed shape
             # (large sparse key spaces) carries its own strides/bases
-            out, eff = served
-            result = decode_grouped_result(eff, seg, out)
-            stats.group_by_rung = grouped_rung(eff.spec, out)
-            return result
-        out = self._run_kernel(plan, seg, stats)
-        result = decode_grouped_result(plan, seg, out)
+            out, plan = served
+        else:
+            out = self._run_kernel(plan, seg, stats)
+        with maybe_span(stats, "Decode"):
+            result = decode_grouped_result(plan, seg, out)
         stats.group_by_rung = grouped_rung(plan.spec, out)
         return result
 
@@ -945,7 +991,7 @@ class ServerQueryExecutor:
         EFFECTIVE plan it decodes against (the original, or the
         probe-narrowed plan for large-group shapes), or None."""
         from pinot_tpu.engine import pallas_kernels
-        from pinot_tpu.engine.kernels import unpack_outputs
+        from pinot_tpu.engine.kernels import fetch_outputs, unpack_outputs
 
         interpret = self._pallas_mode()
         if interpret is None:
@@ -973,13 +1019,15 @@ class ServerQueryExecutor:
                             reason)
 
         def launch():
-            served = pallas_kernels.run_segment(
-                plan, staged, self.pallas_kernels, interpret,
-                on_decline=declined, lut_run_cap=self._pallas_lut_runs)
+            with maybe_span(stats, "Dispatch"):
+                served = pallas_kernels.run_segment(
+                    plan, staged, self.pallas_kernels, interpret,
+                    on_decline=declined, lut_run_cap=self._pallas_lut_runs)
             if served is None:
                 return None
             packed, eff = served
-            return unpack_outputs(packed, eff.spec), eff
+            return unpack_outputs(fetch_outputs(stats, packed),
+                                  eff.spec), eff
 
         try:
             # per-segment coalescing contract: concurrent identical queries
@@ -1020,7 +1068,7 @@ class ServerQueryExecutor:
     # -- shared ------------------------------------------------------------
     def _run_kernel(self, plan: SegmentPlan, seg: ImmutableSegment,
                     stats: QueryStats) -> Dict[str, Any]:
-        from pinot_tpu.engine.kernels import unpack_outputs
+        from pinot_tpu.engine.kernels import fetch_outputs, unpack_outputs
 
         with maybe_span(stats, "Stage", segment=seg.segment_name):
             staged = self.residency.stage(seg, lease=self._lease_of(stats))
@@ -1028,18 +1076,19 @@ class ServerQueryExecutor:
             and plan.spec[0][1][0] == ("validdocs",)
 
         def launch():
-            cols = {name: staged.column(name).tree()
-                    for name in plan.columns}
-            kernel = self.kernels.get(plan.spec)
-            params = tuple(plan.params)
-            if has_validdocs:
-                # fill the planner's placeholder (staging owns the snapshot
-                # build + version-keyed device cache)
-                params = (staged.valid_mask(),) + params[1:]
-            packed = kernel(cols, params, np.int32(seg.num_docs))
+            with maybe_span(stats, "Dispatch"):
+                cols = {name: staged.column(name).tree()
+                        for name in plan.columns}
+                kernel = self.kernels.get(plan.spec)
+                params = tuple(plan.params)
+                if has_validdocs:
+                    # fill the planner's placeholder (staging owns the
+                    # snapshot build + version-keyed device cache)
+                    params = (staged.valid_mask(),) + params[1:]
+                packed = kernel(cols, params, np.int32(seg.num_docs))
             # one D2H fetch for the whole output tree (each transfer is a
             # host<->device round trip; see kernels.output_layout)
-            return unpack_outputs(packed, plan.spec)
+            return unpack_outputs(fetch_outputs(stats, packed), plan.spec)
 
         # per-segment coalescing: identical concurrent queries (same cached
         # plan object + same staged resident) share one launch + D2H.

@@ -85,14 +85,14 @@ def build_gather_kernel(spec):
 
     body = build_kernel_body(spec, sparse_k=sparse_mode(spec))
 
-    def kernel(cols, idx, params, num_docs):
+    def index_gather_agg(cols, idx, params, num_docs):
         gathered = {name: {k: (v if k == "dictvals" else v[idx])
                            for k, v in tree.items()}
                     for name, tree in cols.items()}
         return pack_outputs(body(gathered, params, num_docs, jnp.int32(0)),
                             spec)
 
-    return jax.jit(kernel)
+    return jax.jit(index_gather_agg)
 
 
 def _decline(stats: Optional[QueryStats], reason: str) -> None:

@@ -579,10 +579,12 @@ def build_kernel(spec: Tuple):
     output_layout)."""
     body = build_kernel_body(spec, sparse_k=sparse_mode(spec))
 
-    def kernel(cols, params, num_docs):
+    def scan_segment(cols, params, num_docs):
         return pack_outputs(body(cols, params, num_docs, jnp.int32(0)), spec)
 
-    return jax.jit(kernel)
+    # a jitted entry is named for its kernel family: the name is the
+    # program's on a profiler trace (``jit_scan_segment``)
+    return jax.jit(scan_segment)
 
 
 # --------------------------------------------------------------------------
@@ -721,6 +723,29 @@ def pack_outputs(out: Dict[str, Any], spec: Tuple) -> jnp.ndarray:
                 leaf = jnp.asarray(leaf)[gat]
         parts.append(jnp.asarray(leaf, dtype=jnp.float64).reshape(-1))
     return jnp.concatenate(parts) if len(parts) > 1 else parts[0]
+
+
+def fetch_outputs(stats, packed, wait: bool = True):
+    """The packed output vector as a host array: the one D2H fetch of a
+    launch. A traced query (``stats`` carries a recorder) splits it into
+    a ``DeviceWait`` span (``block_until_ready``: the only span in which
+    the device is owed work; ``wait=False`` where the launcher's
+    dispatcher already waited) and a ``D2H`` span (the copy); an untraced
+    one just copies, which blocks all the same."""
+    import numpy as np
+
+    rec = getattr(stats, "_recorder", None)
+    if rec is None:
+        return np.asarray(packed)
+    if wait:
+        import jax
+
+        with rec.span("DeviceWait"):
+            jax.block_until_ready(packed)
+    with rec.span("D2H") as sp:
+        host = np.asarray(packed)
+        sp.attrs["bytes"] = int(host.nbytes)
+    return host
 
 
 def unpack_outputs(packed, spec: Tuple, num_seg: int = 0) -> Dict[str, Any]:

@@ -882,7 +882,7 @@ def build_kernel(spec: PallasSpec):
         interpret=spec.interpret,
     )
 
-    def call(params, *cols):
+    def pallas_scan(params, *cols):
         """-> (out_f [Mf, G], out_i [Mi, G], out_mm [Mm, G], out_seg
         [S, 128]). Kernel body and index maps trace with 32-bit defaults:
         under jax_enable_x64 every weak Python scalar enters the jaxpr as
@@ -893,7 +893,7 @@ def build_kernel(spec: PallasSpec):
                                                   *cols)
             return out_f, out_i, out_mm, out_seg.sum(axis=1)
 
-    return call
+    return pallas_scan
 
 
 class PallasKernelCache:

@@ -93,7 +93,7 @@ def _build_kernel(filter_spec, directions: Tuple[bool, ...], capacity: int,
     never widen: a 64-bit comparator is emulated on the TPU, and XLA
     takes minutes to compile a multi-key sort over it."""
 
-    def kernel(cols, params, num_docs, keys):
+    def selection_topk(cols, params, num_docs, keys):
         pc = _ParamCursor(params)
         mask = _emit_filter(filter_spec, cols, pc, capacity)
         pc.finish()  # selection params are exactly the filter params
@@ -116,7 +116,7 @@ def _build_kernel(filter_spec, directions: Tuple[bool, ...], capacity: int,
                                   num_keys=len(operands), is_stable=True)
         return sorted_ops[-1][:k], mask.sum(dtype=jnp.int32)
 
-    return jax.jit(kernel)
+    return jax.jit(selection_topk)
 
 
 def device_selection(ctx: QueryContext, segments: List[ImmutableSegment],

@@ -33,6 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from pinot_tpu.common.tracing import maybe_span
 from pinot_tpu.engine.aggregates import AggDef
 from pinot_tpu.engine.plan import PlanError, StarTreePlan, plan_star_tree
 from pinot_tpu.engine.results import AggResult, GroupByResult, QueryStats
@@ -58,13 +59,13 @@ def build_startree_kernel(spec: Tuple):
 
     body = build_kernel_body(spec, sparse_k=sparse_mode(spec))
 
-    def kernel(cols, idx, params, num_docs):
+    def startree_agg(cols, idx, params, num_docs):
         gathered = {name: {k: v[idx] for k, v in tree.items()}
                     for name, tree in cols.items()}
         return pack_outputs(body(gathered, params, num_docs, jnp.int32(0)),
                             spec)
 
-    return jax.jit(kernel)
+    return jax.jit(startree_agg)
 
 
 def _empty_states(aggs: List[AggDef]) -> List[Any]:
@@ -136,24 +137,27 @@ def execute_star_tree_device(executor, ctx: QueryContext,
                              aggs: List[AggDef], segment, tree,
                              matches: Dict[str, Any],
                              stats: QueryStats,
-                             tree_index: Optional[int] = None
+                             tree_index: Optional[int] = None,
+                             idx: Optional[np.ndarray] = None
                              ) -> Optional[Any]:
     """-> AggResult / GroupByResult served from device-resident node
     arrays, or raises PlanError (host walker serves). ``executor`` provides
     the residency manager (staging + lease pinning) and the star-tree
     kernel cache. ``tree_index`` is the pick's index into
-    ``segment.star_trees`` (derived by identity when omitted)."""
+    ``segment.star_trees`` (derived by identity when omitted); ``idx`` the
+    records the caller's walk selected (walked here when omitted)."""
     import jax.numpy as jnp
 
-    from pinot_tpu.engine.kernels import unpack_outputs
+    from pinot_tpu.engine.kernels import fetch_outputs, unpack_outputs
 
     if tree_index is None:
         tree_index = segment.star_trees.index(tree)
-    group_cols = [e.name for e in ctx.group_by]
-    idx = tree.select_records(matches, group_cols)
+    if idx is None:
+        idx = tree.select_records(matches, [e.name for e in ctx.group_by])
     n = int(idx.shape[0])
 
-    plan = plan_star_tree(ctx, segment, tree, matches, n)
+    with maybe_span(stats, "Plan", cacheHit=False):
+        plan = plan_star_tree(ctx, segment, tree, matches, n)
 
     if n == 0:
         # nothing selected: skip the launch, emit the scan path's empty
@@ -167,26 +171,27 @@ def execute_star_tree_device(executor, ctx: QueryContext,
     # stage the node arrays through the residency manager: the segment
     # resident is pinned by this query's lease, so the arrays cannot be
     # evicted out from under the launch
-    staged = executor.residency.stage(segment,
-                                      lease=executor._lease_of(stats))
+    with maybe_span(stats, "Stage", segment=segment.segment_name):
+        staged = executor.residency.stage(segment,
+                                          lease=executor._lease_of(stats))
 
     def launch():
-        nodes = staged.startree_nodes(tree_index)
-        cols = {key: {"fwd": nodes[key]} for key in plan.columns}
-        capacity = plan.spec[-1]
-        padded = np.zeros(capacity, dtype=np.int32)
-        padded[:n] = idx.astype(np.int32)
-        kernel = executor._startree_kernel(plan.spec)
-        packed = kernel(cols, jnp.asarray(padded), tuple(plan.params),
-                        np.int32(n))
-        return unpack_outputs(packed, plan.spec)  # may raise PlanError
+        with maybe_span(stats, "Dispatch"):
+            nodes = staged.startree_nodes(tree_index)
+            cols = {key: {"fwd": nodes[key]} for key in plan.columns}
+            capacity = plan.spec[-1]
+            padded = np.zeros(capacity, dtype=np.int32)
+            padded[:n] = idx.astype(np.int32)
+            kernel = executor._startree_kernel(plan.spec)
+            packed = kernel(cols, jnp.asarray(padded), tuple(plan.params),
+                            np.int32(n))
+        # may raise PlanError
+        return unpack_outputs(fetch_outputs(stats, packed), plan.spec)
 
     # per-segment coalescing contract (engine/executor._kernel_flight):
     # concurrent identical dashboard queries — the SAME compiled ctx object
     # over the same staged tree — share one node-slice launch + D2H. The
     # walk/plan above stays per-caller (host work, query-private stats).
-    from pinot_tpu.common.tracing import maybe_span
-
     with maybe_span(stats, "Kernel", kernel="startree_device",
                     segment=segment.segment_name, records=n):
         out, _ = executor._kernel_flight.do(
@@ -198,6 +203,7 @@ def execute_star_tree_device(executor, ctx: QueryContext,
     stats.total_docs += segment.num_docs
     stats.num_docs_scanned += n
     stats.num_segments_matched += 1
-    if not ctx.is_group_by:
-        return _decode_scalar(plan, out)
-    return _decode_grouped(plan, segment, out)
+    with maybe_span(stats, "Decode"):
+        if not ctx.is_group_by:
+            return _decode_scalar(plan, out)
+        return _decode_grouped(plan, segment, out)

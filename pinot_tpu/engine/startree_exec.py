@@ -319,10 +319,13 @@ def execute_star_tree(ctx: QueryContext, aggs: List[AggDef], segment,
 
 def execute_with_matches(ctx: QueryContext, aggs: List[AggDef], segment,
                          tree: StarTree, matches: Dict[str, Any],
-                         stats: Optional[QueryStats] = None):
-    """Host (numpy) aggregation over the tree-walk-selected records."""
+                         stats: Optional[QueryStats] = None,
+                         idx: Optional[np.ndarray] = None):
+    """Host (numpy) aggregation over the tree-walk-selected records
+    (``idx``: the caller's walk; walked here when omitted)."""
     group_cols = [e.name for e in ctx.group_by]
-    idx = tree.select_records(matches, group_cols)
+    if idx is None:
+        idx = tree.select_records(matches, group_cols)
 
     if stats is not None:
         stats.num_segments_processed += 1

@@ -214,7 +214,7 @@ def build_sharded_kernel(spec: Tuple, mesh: Mesh,
     cols_axes = {name: {k: kind_axis[k] for k in keys}
                  for name, keys in col_layouts}
 
-    def per_device(cols, params, num_docs):
+    def scan_sharded(cols, params, num_docs):
         doc_off = (jax.lax.axis_index(DOC_AXIS) * local_cap).astype(jnp.int32)
 
         def one_segment(seg_cols, nd):
@@ -267,7 +267,7 @@ def build_sharded_kernel(spec: Tuple, mesh: Mesh,
         return pack_outputs(out, spec)
 
     sharded = _shard_map(
-        per_device, mesh=mesh,
+        scan_sharded, mesh=mesh,
         in_specs=(cols_spec, P(), P(SEG_AXIS)),
         out_specs=P())
     return jax.jit(sharded)
@@ -307,7 +307,8 @@ def build_sharded_pallas_kernel(spec, plan_spec: Tuple, mesh: Mesh):
     _, _, mm_row, _, _, _ = _row_layout(spec)
     axes = (SEG_AXIS, DOC_AXIS)
 
-    def per_device(static_params, packed_cols, value_cols, num_docs):
+    def pallas_scan_sharded(static_params, packed_cols, value_cols,
+                            num_docs):
         doc_base = (jax.lax.axis_index(DOC_AXIS)
                     * (T_l * PALLAS_TILE)).astype(jnp.int32)
         params = jnp.concatenate([
@@ -338,7 +339,7 @@ def build_sharded_pallas_kernel(spec, plan_spec: Tuple, mesh: Mesh):
     n_value_refs = sum(l if l else 1 for l in
                        (spec.value_limbs or (0,) * len(spec.value_is_int)))
     sharded = _shard_map(
-        per_device, mesh=mesh,
+        pallas_scan_sharded, mesh=mesh,
         in_specs=(P(),
                   [pk_spec] * len(spec.packed_bits),
                   [pk_spec] * n_value_refs,
@@ -365,7 +366,7 @@ def build_sharded_pallas_probe(spec, mesh: Mesh):
     _, _, mm_row, _, _, _ = _row_layout(spec)
     axes = (SEG_AXIS, DOC_AXIS)
 
-    def per_device(static_params, packed_cols, num_docs):
+    def pallas_probe_sharded(static_params, packed_cols, num_docs):
         doc_base = (jax.lax.axis_index(DOC_AXIS)
                     * (T_l * PALLAS_TILE)).astype(jnp.int32)
         params = jnp.concatenate([
@@ -379,7 +380,7 @@ def build_sharded_pallas_probe(spec, mesh: Mesh):
 
     pk_spec = P(SEG_AXIS, DOC_AXIS, None, None)
     sharded = _shard_map(
-        per_device, mesh=mesh,
+        pallas_probe_sharded, mesh=mesh,
         in_specs=(P(), [pk_spec] * len(spec.packed_bits), P(SEG_AXIS)),
         out_specs=P())
     return jax.jit(sharded)

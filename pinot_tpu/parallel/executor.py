@@ -144,21 +144,40 @@ class ShardedQueryExecutor(ServerQueryExecutor):
 
         return index_exec.batch_index_eligible(self, ctx, segments)
 
+    def _route(self, ctx, aggs, segments, stats) -> str:
+        """The path this query takes, chosen before any segment runs:
+        'startree' | 'index' (both the per-segment ladder) | 'sliced' |
+        'sharded' | 'per_segment'."""
+        with maybe_span(stats, "Route", path="per_segment") as sp:
+            if self._any_star_tree_fit(ctx, aggs, segments):
+                path = "startree"
+            elif not self.use_device:
+                path = "per_segment"
+            elif self._index_rung_fit(ctx, segments):
+                path = "index"
+            elif self._sliced_lease(stats) is not None:
+                path = "sliced"
+            elif len(segments) > 1 and self._device_admitted(stats):
+                path = "sharded"
+            else:
+                path = "per_segment"
+            if sp is not None:
+                sp.attrs["path"] = path
+        return path
+
     def _execute_aggregation(self, ctx, aggs, segments, stats):
-        if self._any_star_tree_fit(ctx, aggs, segments):
+        path = self._route(ctx, aggs, segments, stats)
+        if path in ("startree", "index"):
             return ServerQueryExecutor._execute_aggregation(
                 self, ctx, aggs, segments, stats)
-        if self.use_device and self._index_rung_fit(ctx, segments):
-            return ServerQueryExecutor._execute_aggregation(
-                self, ctx, aggs, segments, stats)
-        if self.use_device and self._sliced_lease(stats) is not None:
+        if path == "sliced":
             return self._execute_sliced(ctx, aggs, segments, stats,
                                         grouped=False)
-        if self.use_device and len(segments) > 1 \
-                and self._device_admitted(stats):
+        if path == "sharded":
             try:
                 batch, out, plan = self._run_sharded(ctx, segments, stats)
-                return decode_scalar_result(plan, batch, out)
+                with maybe_span(stats, "Decode"):
+                    return decode_scalar_result(plan, batch, out)
             except (PlanError, ValueError) as e:
                 # ValueError: segments not batchable (mixed layouts/schemas,
                 # batch.py) — the per-segment path still serves them
@@ -170,20 +189,18 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         return super()._execute_aggregation(ctx, aggs, segments, stats)
 
     def _execute_group_by(self, ctx, aggs, segments, stats):
-        if self._any_star_tree_fit(ctx, aggs, segments):
+        path = self._route(ctx, aggs, segments, stats)
+        if path in ("startree", "index"):
             return ServerQueryExecutor._execute_group_by(
                 self, ctx, aggs, segments, stats)
-        if self.use_device and self._index_rung_fit(ctx, segments):
-            return ServerQueryExecutor._execute_group_by(
-                self, ctx, aggs, segments, stats)
-        if self.use_device and self._sliced_lease(stats) is not None:
+        if path == "sliced":
             return self._execute_sliced(ctx, aggs, segments, stats,
                                         grouped=True)
-        if self.use_device and len(segments) > 1 \
-                and self._device_admitted(stats):
+        if path == "sharded":
             try:
                 batch, out, plan = self._run_sharded(ctx, segments, stats)
-                return decode_grouped_result(plan, batch, out)
+                with maybe_span(stats, "Decode"):
+                    return decode_grouped_result(plan, batch, out)
             except (PlanError, ValueError) as e:
                 record_decision(
                     stats, "sharded_combine", "per_segment",
@@ -222,9 +239,10 @@ class ShardedQueryExecutor(ServerQueryExecutor):
                     try:
                         batch, out, plan = self._run_sharded(ctx, chunk,
                                                              stats)
-                        part = (decode_grouped_result(plan, batch, out)
-                                if grouped
-                                else decode_scalar_result(plan, batch, out))
+                        with maybe_span(stats, "Decode"):
+                            part = (decode_grouped_result(plan, batch, out)
+                                    if grouped else
+                                    decode_scalar_result(plan, batch, out))
                     except (PlanError, ValueError):
                         part = None  # per-segment path serves this slice
                 if part is None:
@@ -335,14 +353,16 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         from pinot_tpu.engine.kernels import unpack_outputs
 
         lease = self._lease_of(stats)
-        batch = self.batch_for(segments, lease)
-        # the batch's device arrays are a resident like any staged segment:
-        # byte-accounted, LRU-ordered, and PINNED through this query's lease
-        # so another thread's budget enforcement cannot free arrays a
-        # launched combine program is reading
-        bkey = batch.metadata.segment_name
-        self.residency.register(bkey, lambda: _BatchResident(self, batch),
-                                same=lambda r: r.batch is batch, lease=lease)
+        with maybe_span(stats, "Stage", segments=len(segments)):
+            batch = self.batch_for(segments, lease)
+            # the batch's device arrays are a resident like any staged
+            # segment: byte-accounted, LRU-ordered, and PINNED through this
+            # query's lease so another thread's budget enforcement cannot
+            # free arrays a launched combine program is reading
+            bkey = batch.metadata.segment_name
+            self.residency.register(
+                bkey, lambda: _BatchResident(self, batch),
+                same=lambda r: r.batch is batch, lease=lease)
         S = pad_segments(batch.num_segments, self.mesh.shape[SEG_AXIS])
 
         # the filter fingerprint distinguishes same-SQL contexts whose
@@ -350,26 +370,30 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         # idset refresh) — without it a stale compiled plan would serve
         pkey = (ctx.sql if ctx.sql is not None else repr(ctx),
                 filter_fingerprint(ctx), batch.metadata.segment_name, S)
-        with self._cache_lock:
-            cached = self._param_cache.get(pkey)
-            if cached is not None:
-                self._param_cache.move_to_end(pkey)
-                plan, launch_key, params = cached
-                kernel = self._launch_cache.get(launch_key)
-                if kernel is not None:
-                    self._launch_cache.move_to_end(launch_key)
-        if cached is None:
-            plan = plan_segment(ctx, batch)
-            kernel, params, plan = self._bind_launch(plan, batch, S, stats)
-            self._remember(pkey, plan, kernel, params)
-        elif kernel is None:
-            # launch tier evicted under this param entry: rebind (the plan
-            # is in hand, so this costs a kernel-cache lookup, not a
-            # replan; a probe-narrowed plan re-extracts directly without
-            # re-probing — its num_groups is already inside the bound)
-            kernel, params, plan = self._bind_launch(plan, batch, S, stats)
-            self._remember(pkey, plan, kernel, params)
-        num_docs = self._device_num_docs(batch, S)
+        with maybe_span(stats, "Plan", cacheHit=True) as plan_sp:
+            with self._cache_lock:
+                cached = self._param_cache.get(pkey)
+                if cached is not None:
+                    self._param_cache.move_to_end(pkey)
+                    plan, launch_key, params = cached
+                    kernel = self._launch_cache.get(launch_key)
+                    if kernel is not None:
+                        self._launch_cache.move_to_end(launch_key)
+            if cached is None:
+                plan = plan_segment(ctx, batch)
+            if cached is None or kernel is None:
+                # a miss of the param tier plans and binds; a launch tier
+                # evicted under a param entry only rebinds (the plan is
+                # in hand, so this costs a kernel-cache lookup, not a
+                # replan; a probe-narrowed plan re-extracts directly
+                # without re-probing — its num_groups is already inside
+                # the bound)
+                kernel, params, plan = self._bind_launch(plan, batch, S,
+                                                         stats)
+                self._remember(pkey, plan, kernel, params)
+                if plan_sp is not None:
+                    plan_sp.attrs["cacheHit"] = False
+            num_docs = self._device_num_docs(batch, S)
 
         # span covers dispatcher queue + launch + D2H; the queue-vs-work
         # split comes from the launch request's measured queue wait
@@ -423,10 +447,13 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         repair path; returns the unpacked output tree and appends the
         final launch request to ``req_out`` (the span above reads its
         queue wait)."""
-        from pinot_tpu.engine.kernels import unpack_outputs
+        from pinot_tpu.engine.kernels import fetch_outputs, unpack_outputs
 
+        rec = stats_tracer(stats)
+        traced = rec is not None
         try:
-            req = self.launcher.submit(kernel, params, num_docs)
+            req = self.launcher.submit(kernel, params, num_docs, traced,
+                                       rec.request_id if traced else None)
             req_out.append(req)
             packed = req.result()
         except (PlanError, ValueError):
@@ -467,9 +494,12 @@ class ShardedQueryExecutor(ServerQueryExecutor):
                             "pallas_combine", "pallas_exec_failed")
             kernel, params, plan = self._bind_jnp(plan, batch, S)
             self._remember(pkey, plan, kernel, params)
-            req = self.launcher.submit(kernel, params, num_docs)
+            req = self.launcher.submit(kernel, params, num_docs, traced,
+                                       rec.request_id if traced else None)
             req_out.append(req)
             packed = req.result()
+        if traced:
+            req.add_spans(rec)  # the dispatcher's Dispatch + DeviceWait
         # coalescing outcome -> per-query stats (merged across shards and
         # servers; see QueryStats.merge for the sum-vs-max key split).
         # Accumulate instead of overwrite: a sliced combine calls this once
@@ -489,8 +519,10 @@ class ShardedQueryExecutor(ServerQueryExecutor):
                     stats.launch[k] = stats.launch.get(k, 0) + v
         else:
             stats.launch = cur
-        # ONE D2H fetch decodes the entire query result
-        return unpack_outputs(packed, plan.spec, num_seg=S)
+        # ONE D2H fetch decodes the entire query result (the dispatcher
+        # already waited for the device: the request carries that span)
+        return unpack_outputs(fetch_outputs(stats, packed, wait=False),
+                              plan.spec, num_seg=S)
 
     def _remember(self, pkey: Tuple, plan: SegmentPlan, kernel, params
                   ) -> None:

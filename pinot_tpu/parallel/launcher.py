@@ -48,6 +48,8 @@ from collections import OrderedDict, deque
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Tuple
 
+from pinot_tpu.common import tracing
+
 log = logging.getLogger(__name__)
 
 # stats keys whose QueryStats.launch merge takes MAX (the rest sum); shared
@@ -120,9 +122,12 @@ class _LaunchRequest:
     executor copies into ``QueryStats.launch``)."""
 
     __slots__ = ("kernel", "params", "num_docs", "future", "t_submit",
-                 "batch_size", "queue_wait_ms", "launches_saved", "deduped")
+                 "batch_size", "queue_wait_ms", "launches_saved", "deduped",
+                 "traced", "request_id", "t_dispatch", "t_launched",
+                 "t_ready", "dispatch_cpu_ms", "thread")
 
-    def __init__(self, kernel: LaunchKernel, params, num_docs):
+    def __init__(self, kernel: LaunchKernel, params, num_docs,
+                 traced: bool = False, request_id: Optional[str] = None):
         self.kernel = kernel
         self.params = params
         self.num_docs = num_docs
@@ -132,9 +137,32 @@ class _LaunchRequest:
         self.queue_wait_ms = 0.0
         self.launches_saved = 0
         self.deduped = False
+        # a traced query's launch: the dispatcher stamps its group's
+        # phases here (beside t_submit), the query's thread attaches them
+        self.traced = traced
+        self.request_id = request_id
+        self.t_dispatch = self.t_launched = self.t_ready = 0.0
+        self.dispatch_cpu_ms = 0.0
+        self.thread = ""
 
     def result(self, timeout: Optional[float] = None):
         return self.future.result(timeout)
+
+    def add_spans(self, rec) -> None:
+        """The dispatcher thread's two phases of this launch as children
+        of the recorder's open span: ``Dispatch`` (host side: group,
+        stack, the jit call until it returns) and ``DeviceWait``
+        (``block_until_ready``). A coalesced group's requests all carry
+        the group's one pair."""
+        if not self.t_ready:
+            return  # never launched (the submit or the group failed)
+        rec.add_completed(
+            "Dispatch", wall_ms=(self.t_launched - self.t_dispatch) * 1e3,
+            start=self.t_dispatch, cpu_ms=self.dispatch_cpu_ms,
+            thread=self.thread)
+        rec.add_completed(
+            "DeviceWait", wall_ms=(self.t_ready - self.t_launched) * 1e3,
+            start=self.t_launched, thread=self.thread)
 
 
 class LaunchScheduler:
@@ -179,8 +207,10 @@ class LaunchScheduler:
         self._registries: List[Any] = []  # guarded-by-writes: _stats_lock
 
     # -- submission ----------------------------------------------------------
-    def submit(self, kernel: LaunchKernel, params, num_docs) -> _LaunchRequest:
-        req = _LaunchRequest(kernel, params, num_docs)
+    def submit(self, kernel: LaunchKernel, params, num_docs,
+               traced: bool = False, request_id: Optional[str] = None
+               ) -> _LaunchRequest:
+        req = _LaunchRequest(kernel, params, num_docs, traced, request_id)
         with self._cond:
             if self._closed:
                 raise RuntimeError(f"launch scheduler {self._name} is closed")
@@ -301,6 +331,12 @@ class LaunchScheduler:
         now = time.perf_counter()
         for r in reqs:
             r.queue_wait_ms = (now - r.t_submit) * 1e3
+        traced = [r for r in reqs if r.traced]
+        cpu0 = time.thread_time() if traced else 0.0
+        # a traced group's two phases also go onto a profiler trace, on
+        # this thread's line (under the first traced request's id)
+        ann = (tracing.annotate("Dispatch", traced[0].request_id)
+               if traced else None)
         # dedup exact repeats: the executor's param cache hands identical
         # queries the SAME device param objects, so identity is the test
         uniq: List[Any] = []
@@ -360,10 +396,22 @@ class LaunchScheduler:
         # stays totally ordered (the no-interleaved-collectives invariant)
         # and the queue keeps filling while this program runs — which is
         # exactly what makes the next drain coalesce
+        launched = time.perf_counter()
+        cpu_ms = (time.thread_time() - cpu0) * 1e3 if traced else 0.0
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            ann = tracing.annotate("DeviceWait", traced[0].request_id)
         try:
             jax.block_until_ready([o for o in outs if o is not None])
         except BaseException:  # noqa: BLE001 — surface at the fetch instead
             pass
+        ready = time.perf_counter()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        for r in traced:
+            r.t_dispatch, r.t_launched, r.t_ready = now, launched, ready
+            r.dispatch_cpu_ms = cpu_ms
+            r.thread = self._name
 
         n = len(reqs)
         for r, slot in zip(reqs, req_slot):

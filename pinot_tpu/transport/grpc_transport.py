@@ -51,6 +51,48 @@ def _decode_request(raw: bytes):
     return context_from_dict(d["context"]), d["table"], d.get("segments")
 
 
+def _to_wire(dt: DataTable) -> bytes:
+    """``to_bytes``; a traced table's framing is timed and lands in its
+    tree as the root's last child, ``Serialize`` (the stats section is
+    framed after the payload, so the span rides the bytes it measured)."""
+    if not dt.stats.spans:
+        return dt.to_bytes()
+    import time
+
+    from pinot_tpu.common.tracing import attach_root_child
+
+    t0, c0 = time.perf_counter(), time.thread_time()
+    payload = dt.payload_buffers()
+    nbytes = sum(memoryview(p).nbytes for p in payload)
+    attach_root_child(dt.stats, "Serialize",
+                      wall_ms=(time.perf_counter() - t0) * 1e3,
+                      cpu_ms=(time.thread_time() - c0) * 1e3, bytes=nbytes)
+    return b"".join(dt.to_buffers(payload))
+
+
+def _from_wire(raw: bytes, traced: bool) -> DataTable:
+    """``from_bytes``; for a query that asked for its trace the decode is
+    timed and rides beside the server's tree as a ``Deserialize`` span on
+    the wall clock (the broker lays both under ScatterGather)."""
+    if not traced:
+        return DataTable.from_bytes(raw)
+    import threading
+    import time
+
+    epoch_ms, t0, c0 = time.time() * 1e3, time.perf_counter(), \
+        time.thread_time()
+    dt = DataTable.from_bytes(raw)
+    if dt.stats.spans:
+        dt.stats.spans.append({
+            "name": "Deserialize",
+            "ms": round((time.perf_counter() - t0) * 1e3, 3),
+            "startMs": 0.0, "startEpochMs": round(epoch_ms, 3),
+            "cpuMs": round((time.thread_time() - c0) * 1e3, 3),
+            "thread": threading.current_thread().name,
+            "bytes": len(raw)})
+    return dt
+
+
 class GrpcQueryServer:
     """Network front of one ServerInstance
     (ref: GrpcQueryServer.java:45 submit:84). ``Execute`` is the unary
@@ -83,7 +125,7 @@ class GrpcQueryServer:
         except Exception as e:  # errors travel in the DataTable
             log.debug("grpc execute failed", exc_info=True)
             table_result = DataTable.for_exception(repr(e))
-        return table_result.to_bytes()
+        return _to_wire(table_result)
 
     def _execute_streaming(self, request: bytes, context):
         """Yield one DataTable per block: selection queries stream a block
@@ -94,8 +136,8 @@ class GrpcQueryServer:
         try:
             ctx, table, segments = _decode_request(request)
             if not ctx.is_selection:
-                yield self._instance.execute_query(
-                    ctx, table, segments).to_bytes()
+                yield _to_wire(self._instance.execute_query(
+                    ctx, table, segments))
                 return
             for block in self._instance.execute_query_streaming(
                     ctx, table, segments):
@@ -133,7 +175,7 @@ class GrpcServerStub:
         try:
             raw = self._call(_encode_request(ctx, table, segments),
                              timeout=self.timeout_s)
-            return DataTable.from_bytes(raw)
+            return _from_wire(raw, ctx.trace_enabled)
         except grpc.RpcError as e:
             return DataTable.for_exception(
                 f"rpc to {self.address} failed: {e.code().name}")

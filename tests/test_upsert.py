@@ -6,6 +6,7 @@ import numpy as np
 import pandas as pd
 import pytest
 
+from pinot_tpu.common.tracing import flatten_spans
 from pinot_tpu.engine import ServerQueryExecutor
 from pinot_tpu.ingestion import MemoryStream
 from pinot_tpu.query import compile_query
@@ -172,8 +173,9 @@ class TestUpsertDevicePath:
             assert drt.rows == hrt.rows, sql
             # the DEVICE kernels must have served (a silent PlanError
             # fallback to host would make this parity vacuous)
-            paths = {t.get("path") for t in dstats.trace}
-            assert "device" in paths, (sql, dstats.trace)
+            flat = flatten_spans(dstats.spans)
+            paths = {t.get("path") for t in flat}
+            assert "device" in paths, (sql, flat)
         # only the live doc per key is visible
         t, _ = dev.execute(compile_query("SELECT count(*) FROM users"),
                            [seg])

@@ -114,8 +114,8 @@ def _matching_dict_ids(ds: DataSource, pred: Predicate) -> np.ndarray:
         reader = getattr(ds, "fst_index", None)
         if reader is not None:
             return reader.matching_ids(str(pred.value))
-        return np.array([i for i in range(card)
-                         if rx.search(str(d.get_value(i)))], dtype=np.int64)
+        return np.array([i for i, v in enumerate(d.get_values(range(card)))
+                         if rx.search(str(v))], dtype=np.int64)
     if t is PredicateType.TEXT_MATCH:
         from pinot_tpu.segment.textindex import (
             match_text_value,
@@ -133,9 +133,8 @@ def _matching_dict_ids(ds: DataSource, pred: Predicate) -> np.ndarray:
             ast = parse_text_query(str(pred.value))
         except ValueError as e:
             raise QueryError(f"bad TEXT_MATCH query: {e}")
-        return np.array([i for i in range(card)
-                         if match_text_value(d.get_value(i), ast)],
-                        dtype=np.int64)
+        return np.array([i for i, v in enumerate(d.get_values(range(card)))
+                         if match_text_value(v, ast)], dtype=np.int64)
     raise UnsupportedQueryError(f"predicate {t} not supported on "
                                 f"dictionary column {ds.name!r}")
 
@@ -233,8 +232,8 @@ def _eval_json_match(ds: DataSource, pred: Predicate, n: int) -> np.ndarray:
     if cm.has_dictionary:
         d = ds.dictionary
         lut = np.fromiter(
-            (match_json_value(d.get_value(i), ast)
-             for i in range(cm.cardinality)), dtype=bool,
+            (match_json_value(v, ast)
+             for v in d.get_values(range(cm.cardinality))), dtype=bool,
             count=cm.cardinality)
         return lut[np.asarray(ds.forward_index[:n])]
     vals = ds.forward_index[:n]

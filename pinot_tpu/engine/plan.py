@@ -968,8 +968,8 @@ def _build_lut(ds: DataSource, pred: Predicate) -> np.ndarray:
             # trie-resolved dictId interval (ref: FSTBasedRegexpPredicateEvaluator)
             lut[reader.matching_ids(str(pred.value))] = True
             return lut
-        for i in range(card):
-            if rx.search(str(d.get_value(i))):
+        for i, v in enumerate(d.get_values(range(card))):
+            if rx.search(str(v)):
                 lut[i] = True
         return lut
     if t is PredicateType.JSON_MATCH:
@@ -984,8 +984,8 @@ def _build_lut(ds: DataSource, pred: Predicate) -> np.ndarray:
             ast = parse_match_filter(str(pred.value))
         except ValueError as e:
             raise QueryError(f"bad JSON_MATCH filter: {e}")
-        for i in range(card):
-            if match_json_value(d.get_value(i), ast):
+        for i, v in enumerate(d.get_values(range(card))):
+            if match_json_value(v, ast):
                 lut[i] = True
         return lut
     # TEXT_MATCH: tokenized index when present (dictId postings -> LUT,
@@ -1001,8 +1001,8 @@ def _build_lut(ds: DataSource, pred: Predicate) -> np.ndarray:
         ast = parse_text_query(str(pred.value))
     except ValueError as e:
         raise QueryError(f"bad TEXT_MATCH query: {e}")
-    for i in range(card):
-        if match_text_value(d.get_value(i), ast):
+    for i, v in enumerate(d.get_values(range(card))):
+        if match_text_value(v, ast):
             lut[i] = True
     return lut
 

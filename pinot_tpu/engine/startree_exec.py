@@ -348,9 +348,10 @@ def execute_with_matches(ctx: QueryContext, aggs: List[AggDef], segment,
     uniq, gid, decode_codes = compose_group_keys(key_ids, cards)
 
     # decode dictIds through the segment dictionaries
-    keys = [tuple(segment.data_source(c).dictionary.get_value(int(i))
-                  for c, i in zip(group_cols, decode_codes(int(u))))
-            for u in uniq]
+    codes = [decode_codes(int(u)) for u in uniq]
+    keys = list(zip(*(
+        segment.data_source(c).dictionary.get_values([k[j] for k in codes])
+        for j, c in enumerate(group_cols))))
     n = len(uniq)
     states_per_agg = [
         _grouped_states(tree, agg, fn, idx, gid, n)

@@ -8,11 +8,14 @@ columns a *dictId interval* — the property the TPU filter kernels exploit
 
 Numeric dictionaries are plain sorted numpy arrays (device-stageable
 directly). String/bytes dictionaries use an offsets+blob layout (mmap
-friendly); the device only ever sees their dictIds.
+friendly) on disk and are read from process memory where their size
+allows; the device only ever sees their dictIds.
 """
 
 from __future__ import annotations
 
+import sys
+from bisect import bisect_left
 from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -142,11 +145,67 @@ class NumericDictionary(Dictionary):
         return self._values
 
 
+# A string dictionary whose values would take at most this much process
+# memory (``_host_bytes_estimate``) is read from memory; a larger one stays on
+# its mapped blob. A server holds segments x string columns of these, and only
+# those a query has read: SSB's largest (p_brand1, 1,000 values, 9 KB of blob)
+# estimates to 114 KB, and 1,000 segments of 20 such columns to 2.3 GB.
+MATERIALISE_MAX_HOST_BYTES = 1 << 20
+
+
+def _host_bytes_estimate(cardinality: int, blob_bytes: int) -> int:
+    """Process memory of ``cardinality`` entries held as ``bytes`` and as
+    ``str``: two object headers (33 + 49 B) and two pointers (list, object
+    array) an entry, and the blob's bytes twice."""
+    return 98 * cardinality + 2 * blob_bytes
+
+
+class _BlobEntries:
+    """The raw entries of an offsets + blob pair as a sequence of ``bytes``,
+    read through plain views of the two arrays: a mapped file is then read by
+    buffer slices, and no ``np.memmap`` method runs for a value."""
+
+    def __init__(self, offsets: np.ndarray, blob: np.ndarray):
+        self._offsets = offsets.view(np.ndarray)
+        self._blob = memoryview(blob.view(np.ndarray))
+
+    def __len__(self) -> int:
+        return int(self._offsets.shape[0]) - 1
+
+    def __getitem__(self, dict_id: int) -> bytes:
+        lo, hi = self._offsets[dict_id:dict_id + 2].tolist()
+        return bytes(self._blob[lo:hi])
+
+    def take(self, dict_ids: np.ndarray) -> List[bytes]:
+        blob = self._blob
+        return [bytes(blob[lo:hi])
+                for lo, hi in zip(self._offsets[dict_ids].tolist(),
+                                  self._offsets[dict_ids + 1].tolist())]
+
+
+class _Materialised:
+    """A dictionary's entries in process memory: ``raw`` in the file's
+    (bytewise) order for the search, ``values`` decoded for the reads."""
+
+    def __init__(self, raw: List[bytes], is_bytes: bool):
+        self.raw = raw
+        self.values = np.empty(len(raw), dtype=object)
+        self.values[:] = raw if is_bytes else [r.decode("utf-8") for r in raw]
+        held = [raw] if is_bytes else [raw, self.values]
+        self.host_bytes = (sys.getsizeof(raw) + self.values.nbytes
+                           + sum(sys.getsizeof(v) for vs in held for v in vs))
+
+
 class StringDictionary(Dictionary):
     """Sorted UTF-8 strings as offsets[card+1] + byte blob.
 
     Bytes dictionaries reuse this with raw bytes (sorted bytewise, which
     matches the reference's ByteArray comparison order).
+
+    Reads go a batch at a time over one of two storage forms, chosen by the
+    size known at load: up to ``MATERIALISE_MAX_HOST_BYTES`` the entries are
+    materialised in process memory on first use; above it they stay on the
+    (mapped) blob and are read through ``_BlobEntries``.
     """
 
     def __init__(self, offsets: np.ndarray, blob: np.ndarray, data_type: DataType):
@@ -154,6 +213,12 @@ class StringDictionary(Dictionary):
         self._blob = blob
         self.data_type = data_type
         self._is_bytes = data_type is DataType.BYTES
+        self._entries = _BlobEntries(offsets, blob)
+        self.blob_backed = _host_bytes_estimate(
+            len(self._entries), int(blob.shape[0])) > MATERIALISE_MAX_HOST_BYTES
+        # built aside and assigned once: workers that race here build equal
+        # values and the last assignment wins
+        self._materialised: Optional[_Materialised] = None
 
     @classmethod
     def from_values(cls, sorted_values: List[Any], data_type: DataType) -> "StringDictionary":
@@ -166,31 +231,50 @@ class StringDictionary(Dictionary):
         return cls(offsets, blob, data_type)
 
     def __len__(self) -> int:
-        return int(self._offsets.shape[0]) - 1
+        return len(self._entries)
 
-    def _raw(self, dict_id: int) -> bytes:
-        lo, hi = int(self._offsets[dict_id]), int(self._offsets[dict_id + 1])
-        return self._blob[lo:hi].tobytes()
+    def _in_memory(self) -> Optional[_Materialised]:
+        m = self._materialised
+        if m is None and not self.blob_backed:
+            m = _Materialised(
+                self._entries.take(np.arange(len(self), dtype=np.intp)),
+                self._is_bytes)
+            self._materialised = m
+        return m
+
+    @property
+    def host_bytes(self) -> int:
+        """Process memory held by the materialised entries (0 before the
+        first read, and always for a blob-backed dictionary)."""
+        m = self._materialised
+        return 0 if m is None else m.host_bytes
 
     def get_value(self, dict_id: int) -> Any:
-        raw = self._raw(dict_id)
+        m = self._in_memory()
+        if m is not None:
+            return m.values[dict_id]
+        raw = self._entries[dict_id]
         return raw if self._is_bytes else raw.decode("utf-8")
+
+    def get_values(self, dict_ids: Sequence[int]) -> List[Any]:
+        ids = np.asarray(dict_ids, dtype=np.intp)
+        m = self._in_memory()
+        if m is not None:
+            return m.values[ids].tolist()
+        raws = self._entries.take(ids)
+        return raws if self._is_bytes else [r.decode("utf-8") for r in raws]
 
     def _encode(self, value: Any) -> bytes:
         return value if isinstance(value, bytes) else str(value).encode("utf-8")
 
     def insertion_index_of(self, value: Any) -> int:
         target = self._encode(value)
-        lo, hi = 0, len(self)
-        while lo < hi:
-            mid = (lo + hi) // 2
-            if self._raw(mid) < target:
-                lo = mid + 1
-            else:
-                hi = mid
-        if lo < len(self) and self._raw(lo) == target:
-            return lo
-        return -(lo + 1)
+        m = self._in_memory()
+        raws = self._entries if m is None else m.raw
+        i = bisect_left(raws, target)
+        if i < len(raws) and raws[i] == target:
+            return i
+        return -(i + 1)
 
     def index_of(self, value: Any) -> int:
         i = self.insertion_index_of(value)

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import os
 from functools import cached_property
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -268,6 +268,13 @@ class ImmutableSegment:
             ds = DataSource(self, column)
             self._data_sources[column] = ds
         return ds
+
+    def loaded_string_dictionaries(self) -> List[StringDictionary]:
+        """The STRING/BYTES dictionaries some reader has opened so far;
+        opens none itself (``/debug/memory``)."""
+        found = (ds.__dict__.get("dictionary")
+                 for ds in list(self._data_sources.values()))
+        return [d for d in found if isinstance(d, StringDictionary)]
 
     @cached_property
     def star_trees(self):

@@ -675,7 +675,11 @@ class ServerInstance:
         """Bytes-accurate HBM residency + native mmap accounting
         (ref: MmapDebugResource). Per resident: device bytes, pin count,
         staged column/packed/value array counts; plus the budget, fleet
-        total/peak, and the hit/miss/eviction/spill counters."""
+        total/peak, and the hit/miss/eviction/spill counters.
+        ``dictionaries``: of the hosted immutable segments' string
+        dictionaries that a reader has opened, how many hold their values
+        in process memory (``materialised``, with ``hostBytes``) and how
+        many are too large for that and stay on the mapped blob."""
         from pinot_tpu import native
 
         out: Dict[str, Any] = {"stagedSegments": {}}
@@ -683,4 +687,24 @@ class ServerInstance:
         if residency is not None:
             out.update(residency.snapshot())
         out["nativeMmapBuffers"] = native.mmap_buffer_count()
+        out["dictionaries"] = self._dictionary_debug()
+        return out
+
+    def _dictionary_debug(self) -> Dict[str, int]:
+        out = {"materialised": 0, "blobBacked": 0, "hostBytes": 0}
+        for table in self.data_manager.table_names():
+            tdm = self.data_manager.get(table)
+            if tdm is None:
+                continue  # dropped since table_names()
+            acquired = tdm.acquire_segments()
+            try:
+                for sdm in acquired:
+                    loaded = getattr(sdm.segment,
+                                     "loaded_string_dictionaries", None)
+                    for d in loaded() if loaded else ():
+                        out["materialised"] += d.host_bytes > 0
+                        out["blobBacked"] += d.blob_backed
+                        out["hostBytes"] += d.host_bytes
+            finally:
+                tdm.release_segments(acquired)
         return out

@@ -320,3 +320,32 @@ def test_server_admin_size_and_memory(cluster, tmp_path):
         assert out["demoted"] is False
     finally:
         api.stop()
+
+
+def test_debug_memory_counts_string_dictionaries(cluster, tmp_path):
+    """A freshly loaded segment holds no dictionary values in process
+    memory; a group-by on a string column materialises that column's."""
+    _create_and_load(cluster, tmp_path)
+    apis = [ServerAdminApi(s, port=0) for s in cluster.servers.values()]
+    for api in apis:
+        api.start()
+
+    def dictionaries():
+        found = [_http("GET", f"http://localhost:{api.port}/debug/memory")
+                 ["dictionaries"] for api in apis]
+        return {k: sum(d[k] for d in found)
+                for k in ("materialised", "blobBacked", "hostBytes")}
+
+    try:
+        assert dictionaries() == {"materialised": 0, "blobBacked": 0,
+                                  "hostBytes": 0}
+        rows = cluster.query_rows(
+            "SELECT region, sum(qty) FROM tx_sales GROUP BY region")
+        assert sorted(r[0] for r in rows) == ["east", "north", "west"]
+        after = dictionaries()
+        assert after["materialised"] == 2  # one `region` a segment
+        assert after["blobBacked"] == 0
+        assert after["hostBytes"] > 0
+    finally:
+        for api in apis:
+            api.stop()

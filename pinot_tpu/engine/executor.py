@@ -510,9 +510,9 @@ class ServerQueryExecutor:
             return None
         sliceable = not ctx.distinct and not ctx.is_selection
         with maybe_span(stats, "Lease", segments=len(segments)) as sp:
-            lease = self.residency.begin_query(segments,
-                                               ctx.referenced_columns(),
-                                               sliceable=sliceable)
+            lease = self.residency.begin_query(
+                segments, ctx.referenced_columns(), sliceable=sliceable,
+                devices=self._stage_devices(ctx, segments))
             if sp is not None:
                 sp.attrs.update(sliced=lease.sliced, spilled=lease.spilled,
                                 reason=lease.admit_reason)
@@ -524,6 +524,11 @@ class ServerQueryExecutor:
                             "resident_device", lease.admit_reason)
         stats._staging_lease = lease
         return lease
+
+    def _stage_devices(self, ctx: QueryContext,
+                       segments: List[ImmutableSegment]) -> int:
+        """Devices this executor spreads what a query stages over: one."""
+        return 1
 
     @staticmethod
     def _lease_of(stats: QueryStats):

@@ -6,13 +6,20 @@ gracefully instead of OOMing. This module subsumes the old unbounded
 ``StagingCache`` and the sharded executor's ad-hoc device-column caches
 behind one byte-accounted, lock-correct manager:
 
-- **Accounting**: every resident (a per-segment :class:`StagedSegment` or a
-  sharded-batch device-column set) reports ``nbytes()``; the manager rolls
-  bytes up per resident and tracks the fleet total + peak.
-- **Budget**: ``pinot.server.query.hbm.budget.bytes`` (spi/config.py layered
-  keys; <= 0 means uncapped). When unset, the budget auto-derives from the
-  backend's reported device memory (``bytes_limit`` fraction) — on hosts
-  whose backend reports nothing (CPU), staging is uncapped.
+- **Accounting, a device**: HBM is a chip's, so bytes are reckoned by
+  device. Every resident (a per-segment :class:`StagedSegment` or a
+  sharded-batch device-column set) reports ``device_nbytes()`` — a sharded
+  array's shard on each device, a replicated one whole on each, a
+  per-segment array on the device it was put on; one that only reports
+  ``nbytes()`` lies on the default device. The manager rolls bytes up per
+  resident and per device and tracks the fleet total + peak.
+- **Budget, a device**: ``pinot.server.query.hbm.budget.bytes`` (spi/config.py
+  layered keys; <= 0 means uncapped) is bytes a device. When unset, it
+  auto-derives from the backend's reported device memory (the least
+  ``bytes_limit`` of the devices, times the fraction) — on hosts whose
+  backend reports nothing (CPU), staging is uncapped. Admission, slicing,
+  enforcement and the drift estimate compare the FULLEST device with it:
+  a table spread over four chips is over budget when one chip is.
 - **Host-RAM spill tier**: eviction DEMOTES a resident's device arrays to
   host numpy copies instead of dropping them (per the ISCA'23 HBM/ICI cost
   model a D2H demote + H2D restage is ~10x cheaper than rebuilding device
@@ -59,7 +66,11 @@ import threading
 from collections import OrderedDict
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-from pinot_tpu.engine.staging import StagedSegment, staged_int_dtype
+from pinot_tpu.engine.staging import (
+    DEFAULT_DEVICE,
+    StagedSegment,
+    staged_int_dtype,
+)
 from pinot_tpu.spi.config import CommonConstants
 
 log = logging.getLogger(__name__)
@@ -129,12 +140,33 @@ def estimate_segment_bytes(segment, columns: Iterable[str]) -> int:
     return total
 
 
+def _device_bytes_of(resident) -> Dict[int, int]:
+    """A resident's bytes by device id; one that does not say where they
+    lie (no ``device_nbytes()``) holds them on the default device."""
+    by_device = getattr(resident, "device_nbytes", None)
+    if by_device is not None:
+        return by_device()
+    return {DEFAULT_DEVICE: int(resident.nbytes())}
+
+
+def _add_bytes(into: Dict[int, int], by_device: Dict[int, int]) -> None:
+    for d, n in by_device.items():
+        into[d] = into.get(d, 0) + n
+
+
+def _fullest(by_device: Dict[int, int]) -> int:
+    return max(by_device.values(), default=0)
+
+
 def resolve_budget_bytes(budget_bytes: Any = AUTO,
                          config=None) -> Optional[int]:
-    """Budget resolution: explicit arg > layered config key > backend device
-    memory. Returns None for uncapped: explicit <= 0, or the CPU backend,
-    which reports no device memory. An accelerator that reports none is an
-    error — uncapped staging there ends in an allocation failure mid-query."""
+    """Budget resolution, in bytes a device: explicit arg > layered config
+    key > backend device memory (every device's own ``bytes_limit`` times
+    the fraction; the least of them, which on the alike chips of one host
+    is each one's). Returns None for uncapped: explicit <= 0, or the CPU
+    backend, which reports no device memory. An accelerator that reports
+    none is an error — uncapped staging there ends in an allocation failure
+    mid-query."""
     if budget_bytes is not AUTO:
         if budget_bytes is None:
             return None
@@ -149,15 +181,18 @@ def resolve_budget_bytes(budget_bytes: Any = AUTO,
         return b if b > 0 else None
     import jax
 
-    device = jax.devices()[0]
-    limit = (device.memory_stats() or {}).get("bytes_limit")
-    if limit:
-        return int(limit * CommonConstants.DEFAULT_HBM_BUDGET_FRACTION)
-    if device.platform != "cpu":
-        raise RuntimeError(
-            f"{device.platform} device {device.device_kind!r} reports no "
-            f"bytes_limit: set {CommonConstants.HBM_BUDGET_BYTES_KEY}")
-    return None
+    budgets = []
+    for device in jax.devices():
+        limit = (device.memory_stats() or {}).get("bytes_limit")
+        if limit:
+            budgets.append(
+                int(limit * CommonConstants.DEFAULT_HBM_BUDGET_FRACTION))
+        elif device.platform != "cpu":
+            raise RuntimeError(
+                f"{device.platform} device {device.device_kind!r} reports "
+                f"no bytes_limit: set "
+                f"{CommonConstants.HBM_BUDGET_BYTES_KEY}")
+    return min(budgets, default=None)
 
 
 def resolve_host_budget_bytes(budget_bytes: Any = AUTO,
@@ -200,9 +235,9 @@ class QueryLease:
 
     __slots__ = ("device_allowed", "sliced", "spilled", "hits", "misses",
                  "evictions", "pin_blocked", "promotions", "demotions",
-                 "slices", "admit_reason", "_pinned", "_est")
+                 "slices", "admit_reason", "devices", "_pinned", "_est")
 
-    def __init__(self, device_allowed: bool = True):
+    def __init__(self, device_allowed: bool = True, devices: int = 1):
         self.device_allowed = device_allowed
         self.sliced = False
         self.spilled = not device_allowed
@@ -218,6 +253,10 @@ class QueryLease:
         self.promotions = 0
         self.demotions = 0
         self.slices = 0
+        # devices the caller spreads this query's working set over (the
+        # sharded combine's mesh; 1 on the per-segment path): admission,
+        # slicing and the drift estimate reckon its estimates a device
+        self.devices = devices
         self._pinned: set = set()
         # raw (unscaled) admission estimates per missing segment, for the
         # post-stage drift observation in end_query
@@ -242,12 +281,13 @@ class QueryLease:
 
 
 class _Entry:
-    __slots__ = ("resident", "pins", "nbytes", "touch")
+    __slots__ = ("resident", "pins", "nbytes", "by_device", "touch")
 
     def __init__(self, resident):
         self.resident = resident
         self.pins = 0
-        self.nbytes = 0
+        self.nbytes = 0     # all devices together
+        self.by_device: Dict[int, int] = {}
         self.touch = 0
 
 
@@ -278,6 +318,7 @@ class ResidencyManager:
         # image (numpy copies); LRU-dropped under the host budget
         self._host_entries: "OrderedDict[str, _Entry]" = OrderedDict()  # guarded-by: _lock
         self._staged_bytes = 0  # guarded-by: _lock
+        self._staged_by_device: Dict[int, int] = {}  # guarded-by: _lock
         self._peak_bytes = 0  # guarded-by: _lock
         self._host_bytes = 0  # guarded-by: _lock
         self._host_peak_bytes = 0  # guarded-by: _lock
@@ -325,8 +366,9 @@ class ResidencyManager:
     # -- budget --------------------------------------------------------------
     @property
     def budget_bytes(self) -> Optional[int]:
-        """Lazy: resolving the auto default may initialize the jax backend,
-        which must not happen at executor construction."""
+        """Bytes a device may hold staged. Lazy: resolving the auto default
+        may initialize the jax backend, which must not happen at executor
+        construction."""
         if not self._budget_resolved:
             with self._lock:
                 if not self._budget_resolved:
@@ -571,6 +613,7 @@ class ResidencyManager:
             self._entries.clear()
             self._host_entries.clear()
             self._staged_bytes = 0
+            self._staged_by_device = {}
             self._host_bytes = 0
         self._release_all(doomed + host_doomed)
 
@@ -717,9 +760,13 @@ class ResidencyManager:
 
     # -- query protocol ------------------------------------------------------
     def begin_query(self, segments: List[Any], columns: Iterable[str],
-                    sliceable: bool = False) -> QueryLease:
+                    sliceable: bool = False, devices: int = 1
+                    ) -> QueryLease:
         """Admission: fit the query's estimated working set against what
-        COULD be freed (budget minus other queries' pinned bytes).
+        COULD be freed (budget minus other queries' pinned bytes), on the
+        fullest device. ``devices`` is how many the caller will spread
+        what is not yet staged over (the sharded combine's mesh): each
+        takes that share of the estimate, on top of what the fullest holds.
 
         Three outcomes instead of the old fit-or-fail two:
         - fits -> normal device lease;
@@ -731,16 +778,17 @@ class ResidencyManager:
           (graceful degradation, never a device OOM).
 
         Estimates are scaled by the measured-vs-estimated drift EWMA."""
+        devices = max(1, int(devices))
         budget = self.budget_bytes
         if budget is None:
-            return QueryLease(device_allowed=True)
+            return QueryLease(device_allowed=True, devices=devices)
         cols = list(columns)
         with self._lock:
             self._refresh_locked()
             scale = min(max(self._est_scale, _EST_SCALE_MIN),
                         _EST_SCALE_MAX)
             names = {getattr(s, "segment_name", None) for s in segments}
-            reusable = 0
+            held: Dict[int, int] = {}   # reusable + pinned elsewhere
             missing_est = 0
             max_single = 0
             ests: Dict[str, int] = {}
@@ -748,7 +796,7 @@ class ResidencyManager:
                 e = self._entries.get(s.segment_name)
                 if e is not None and isinstance(e.resident, StagedSegment) \
                         and e.resident.segment is s:
-                    reusable += e.nbytes
+                    _add_bytes(held, e.by_device)
                     max_single = max(max_single, e.nbytes)
                 else:
                     raw = estimate_segment_bytes(s, cols)
@@ -756,10 +804,11 @@ class ResidencyManager:
                     est = int(raw * scale)
                     missing_est += est
                     max_single = max(max_single, est)
-            other_pinned = sum(e.nbytes for n, e in self._entries.items()
-                               if e.pins > 0 and n not in names)
-            if missing_est + reusable + other_pinned <= budget:
-                lease = QueryLease(device_allowed=True)
+            pinned = self._pinned_elsewhere_locked(names)
+            _add_bytes(held, pinned)
+            other_pinned = _fullest(pinned)
+            if -(-missing_est // devices) + _fullest(held) <= budget:
+                lease = QueryLease(device_allowed=True, devices=devices)
                 lease._est = ests
                 return lease
             if sliceable and self._slicing_on \
@@ -767,11 +816,12 @@ class ResidencyManager:
                 self.sliced_queries += 1
                 self._mark("STAGING_SLICED")
                 log.info(
-                    "HBM admission: working set ~%d B over budget %d B "
+                    "HBM admission: working set ~%d B over %d device(s), "
+                    "%d B held on the fullest, over budget %d B a device "
                     "(%d B pinned elsewhere) — serving in budget-sized "
-                    "slices on the device path", missing_est + reusable,
-                    budget, other_pinned)
-                lease = QueryLease(device_allowed=True)
+                    "slices on the device path", missing_est, devices,
+                    _fullest(held), budget, other_pinned)
+                lease = QueryLease(device_allowed=True, devices=devices)
                 lease.sliced = True
                 lease.admit_reason = "working_set_over_budget_sliceable"
                 lease._est = ests
@@ -779,16 +829,26 @@ class ResidencyManager:
             self.spills += 1
             self._mark("STAGING_SPILLS")
             log.info(
-                "HBM admission: working set ~%d B (+%d B reusable) over "
-                "budget %d B (%d B pinned elsewhere) and not sliceable; "
-                "spilling query to host engine", missing_est, reusable,
-                budget, other_pinned)
+                "HBM admission: working set ~%d B over %d device(s), %d B "
+                "held on the fullest, over budget %d B a device (%d B "
+                "pinned elsewhere) and not sliceable; spilling query to "
+                "host engine", missing_est, devices, _fullest(held), budget,
+                other_pinned)
             lease = QueryLease(device_allowed=False)
             lease.admit_reason = (
                 "single_segment_over_budget"
                 if max_single + other_pinned > budget
                 else "working_set_over_budget_not_sliceable")
             return lease
+
+    def _pinned_elsewhere_locked(self, names: set) -> Dict[int, int]:
+        """Bytes by device that other queries' leases pin: what no
+        eviction on this query's behalf can free."""
+        pinned: Dict[int, int] = {}
+        for n, e in self._entries.items():
+            if e.pins > 0 and n not in names:
+                _add_bytes(pinned, e.by_device)
+        return pinned
 
     def plan_slices(self, segments: List[Any], columns: Iterable[str],
                     lease: Optional[QueryLease] = None,
@@ -801,7 +861,9 @@ class ResidencyManager:
         repeat queries pick k from (approximately) real bytes. Returns
         None when even one padded segment exceeds the free budget — the
         caller degrades to the per-segment sliced path, whose footprint
-        truly scales one segment at a time."""
+        truly scales one segment at a time. Costs are a device's: the
+        lease says over how many the batch's rows are spread, and the
+        budget is what the fullest has left."""
         budget = self.budget_bytes
         if budget is None:
             return [list(segments)]
@@ -809,19 +871,19 @@ class ResidencyManager:
             return [list(segments)]
         cols = list(columns)
         known = lease._est if lease is not None else {}
+        devices = lease.devices if lease is not None else 1
         with self._lock:
             self._refresh_locked()
             scale = min(max(self._est_scale, _EST_SCALE_MIN),
                         _EST_SCALE_MAX)
             names = {getattr(s, "segment_name", None) for s in segments}
-            other_pinned = sum(e.nbytes for n, e in self._entries.items()
-                               if e.pins > 0 and n not in names)
+            other_pinned = _fullest(self._pinned_elsewhere_locked(names))
             ests = []
             for s in segments:
                 raw = known.get(s.segment_name)
                 if raw is None:
                     raw = estimate_segment_bytes(s, cols)
-                ests.append(max(1, int(raw * scale)))
+                ests.append(max(1, int(raw * scale / devices)))
         avail = (budget - other_pinned) * _SLICE_FILL
         mean = sum(ests) / len(ests)
         if mean * pad_to > avail:
@@ -878,7 +940,8 @@ class ResidencyManager:
                 est = lease._est.get(name, 0)
                 if est > 0 and e is not None \
                         and isinstance(e.resident, StagedSegment):
-                    self._observe_estimate_locked(est, e.nbytes)
+                    self._observe_estimate_locked(est,
+                                                  _fullest(e.by_device))
             lease._pinned.clear()
             doomed = self._enforce_locked(lease)
             staged = self._staged_bytes
@@ -916,13 +979,16 @@ class ResidencyManager:
 
     # -- eviction engine -----------------------------------------------------
     def _refresh_locked(self) -> None:
-        total = 0
+        by_device: Dict[int, int] = {}
         for e in self._entries.values():
             try:
-                e.nbytes = int(e.resident.nbytes())
+                e.by_device = _device_bytes_of(e.resident)
             except Exception:
-                e.nbytes = 0
-            total += e.nbytes
+                e.by_device = {}
+            e.nbytes = sum(e.by_device.values())
+            _add_bytes(by_device, e.by_device)
+        total = sum(by_device.values())
+        self._staged_by_device = by_device
         self._staged_bytes = total
         if total > self._peak_bytes:
             self._peak_bytes = total
@@ -954,21 +1020,23 @@ class ResidencyManager:
 
     def _enforce_locked(self, lease: Optional[QueryLease] = None
                         ) -> List[Tuple[Optional[str], Any]]:
-        """Evict unpinned residents until the budget fits, ranked by
-        ``bytes * staleness / rebuild_cost`` (descending): big, cold,
-        cheap-to-restage residents go first, so the budget preferentially
-        keeps what is slow to get back. With equal bytes and equal costs
-        this is exact LRU. Returns ``(name, resident)`` pairs — the CALLER
-        demotes/releases them after dropping ``_lock`` (see
-        ``_demote_or_release_all``); their bytes are already out of the
-        accounting here."""
+        """Evict unpinned residents until every device fits its budget,
+        ranked by ``bytes * staleness / rebuild_cost`` (descending): big,
+        cold, cheap-to-restage residents go first, so the budget
+        preferentially keeps what is slow to get back. With equal bytes and
+        equal costs this is exact LRU. A resident with nothing on a device
+        that is over is left alone: dropping it frees nothing there.
+        Returns ``(name, resident)`` pairs — the CALLER demotes/releases
+        them after dropping ``_lock`` (see ``_demote_or_release_all``);
+        their bytes are already out of the accounting here."""
         self._refresh_locked()
         budget = self.budget_bytes
         if budget is None:
             return []
         doomed: List[Tuple[Optional[str], Any]] = []
-        total = self._staged_bytes
-        if total <= budget:
+        staged = dict(self._staged_by_device)
+        over = {d for d, n in staged.items() if n > budget}
+        if not over:
             return doomed
         seq = self._touch_seq + 1
         scores: Dict[str, float] = {}
@@ -976,9 +1044,11 @@ class ResidencyManager:
             scores[name] = (e.nbytes * (seq - e.touch)
                             / self._rebuild_cost_locked(name, e))
         for name in sorted(scores, key=scores.get, reverse=True):
-            if total <= budget:
+            if not over:
                 break
             e = self._entries[name]
+            if e.nbytes and over.isdisjoint(e.by_device):
+                continue
             if e.pins > 0:
                 # an in-flight query owns these arrays: eviction is blocked
                 # (counted — a high rate means the budget is too small for
@@ -989,13 +1059,16 @@ class ResidencyManager:
                 self._mark("STAGING_PIN_BLOCKED")
                 continue
             del self._entries[name]
-            total -= e.nbytes
+            for d, n in e.by_device.items():
+                staged[d] -= n
+            over = {d for d in over if staged[d] > budget}
             doomed.append((name, e.resident))
             self.evictions += 1
             if lease is not None:
                 lease.evictions += 1
             self._mark("STAGING_EVICTIONS")
-        self._staged_bytes = total
+        self._staged_by_device = staged
+        self._staged_bytes = sum(staged.values())
         return doomed
 
     def enforce(self) -> None:
@@ -1070,7 +1143,7 @@ class ResidencyManager:
             if budget is not None:
                 with self._lock:
                     self._refresh_locked()
-                    if self._staged_bytes >= budget:
+                    if _fullest(self._staged_by_device) >= budget:
                         return  # best-effort: never evict for a prefetch
             try:
                 staged.column(cname)
@@ -1084,7 +1157,7 @@ class ResidencyManager:
             if budget is not None:
                 with self._lock:
                     self._refresh_locked()
-                    if self._staged_bytes >= budget:
+                    if _fullest(self._staged_by_device) >= budget:
                         return
             try:
                 staged.startree_nodes(ti)
@@ -1204,6 +1277,13 @@ class ResidencyManager:
             e = self._entries.get(name)
             return 0 if e is None else e.nbytes
 
+    def resident_device_nbytes(self, name: str) -> Dict[int, int]:
+        """One resident's bytes by device as last measured ({} when
+        absent); no re-measure, so cheap enough for a span's attributes."""
+        with self._lock:
+            e = self._entries.get(name)
+            return {} if e is None else dict(e.by_device)
+
     def resident_names(self) -> List[str]:
         with self._lock:
             return list(self._entries)
@@ -1244,9 +1324,23 @@ class ResidencyManager:
             }
 
     def snapshot(self) -> Dict[str, Any]:
-        """Bytes-accurate two-tier residency state for ``/debug/memory``."""
+        """Bytes-accurate two-tier residency state for ``/debug/memory``.
+        ``stagedBytes`` is all devices together; ``devices`` has one entry
+        a device of this process: what it may hold staged, what is staged
+        there, and the allocator's own numbers (None on a backend that
+        reports none)."""
+        import jax
+
+        budget = self.budget_bytes
+        allocator = [(d.id, d.memory_stats() or {})
+                     for d in jax.local_devices()]
         with self._lock:
             self._refresh_locked()
+            devices = [{"id": i, "budgetBytes": budget,
+                        "stagedBytes": self._staged_by_device.get(i, 0),
+                        "bytesInUse": m.get("bytes_in_use"),
+                        "peakBytes": m.get("peak_bytes_in_use")}
+                       for i, m in allocator]
             residents = {}
             for name, e in self._entries.items():
                 d: Dict[str, Any] = {"bytes": e.nbytes, "pins": e.pins}
@@ -1266,9 +1360,10 @@ class ResidencyManager:
                            "kind": type(e.resident).__name__}
                     for name, e in self._host_entries.items()}
             return {
-                "budgetBytes": self.budget_bytes,
+                "budgetBytes": budget,
                 "stagedBytes": self._staged_bytes,
                 "peakBytes": self._peak_bytes,
+                "devices": devices,
                 "counters": {
                     "hits": self.hits, "misses": self.misses,
                     "evictions": self.evictions,

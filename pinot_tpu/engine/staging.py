@@ -16,10 +16,11 @@ Staged layout per column:
 
 from __future__ import annotations
 
+import functools
 import threading
 
 from collections import OrderedDict
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -44,6 +45,37 @@ def staged_int_dtype(cm) -> np.dtype:
             and int(cm.max_value) <= _I32_MAX):
         return np.dtype(np.int32)
     return np.dtype(np.int64)
+
+
+# the device a resident's bytes lie on when it does not say (a plain
+# ``jnp.asarray`` lands there)
+DEFAULT_DEVICE = 0
+
+
+@functools.lru_cache(maxsize=4096)
+def _shard_layout(sharding, shape: Tuple[int, ...],
+                  itemsize: int) -> Tuple[Tuple[int, int], ...]:
+    """((device id, bytes), ...) of an array of ``shape`` under
+    ``sharding``: every device of the sharding holds one shard, so a
+    replicated array costs a whole copy on each."""
+    n = itemsize
+    for d in sharding.shard_shape(shape):
+        n *= d
+    return tuple((d.id, n) for d in sharding.device_set)
+
+
+def add_device_bytes(arr, into: Dict[int, int]) -> None:
+    """Add ``arr``'s device bytes to ``into`` by device id (HBM is a
+    device's, so the residency budget is reckoned a device). Reads only
+    the array's sharding and shape: never a sync, never a copy."""
+    sharding = getattr(arr, "sharding", None)
+    if sharding is None:
+        n = int(getattr(arr, "nbytes", 0) or 0)
+        if n:
+            into[DEFAULT_DEVICE] = into.get(DEFAULT_DEVICE, 0) + n
+        return
+    for d, n in _shard_layout(sharding, arr.shape, arr.dtype.itemsize):
+        into[d] = into.get(d, 0) + n
 
 
 class StagedColumn:
@@ -563,27 +595,33 @@ class StagedSegment:
             self._valid_cache = (ver, arr)
         return arr
 
-    def nbytes(self) -> int:
-        """Device bytes this segment holds resident (HBM accounting for the
-        residency manager). Walks the staged arrays — list() snapshots the
-        dicts against concurrent stagers."""
-        total = 0
+    def device_nbytes(self) -> Dict[int, int]:
+        """Device bytes this segment holds resident, by the device they
+        lie on (HBM accounting for the residency manager): its own arrays
+        on the device they were put on, a column borrowed from a sharded
+        batch wherever that slice lives. Walks the staged arrays — list()
+        snapshots the dicts against concurrent stagers."""
+        into: Dict[int, int] = {}
         for col in list(self._columns.values()):
             for arr in col.tree().values():
-                total += int(getattr(arr, "nbytes", 0))
+                add_device_bytes(arr, into)
         for pc in list(self._packed.values()):
-            total += int(pc.words.nbytes)
+            add_device_bytes(pc.words, into)
         for v in list(self._values.values()):
-            total += int(v.nbytes)
+            add_device_bytes(v, into)
         for t in list(self._startree.values()):
             for arr in t.values():
-                total += int(getattr(arr, "nbytes", 0))
+                add_device_bytes(arr, into)
         for a in list(self._index_slices.values()):
-            total += int(getattr(a, "nbytes", 0))
+            add_device_bytes(a, into)
         vc = self._valid_cache
         if vc is not None:
-            total += int(getattr(vc[1], "nbytes", 0))
-        return total
+            add_device_bytes(vc[1], into)
+        return into
+
+    def nbytes(self) -> int:
+        """All of ``device_nbytes()``: on one device what it always was."""
+        return sum(self.device_nbytes().values())
 
     def demote(self) -> Optional[SegmentHostImage]:
         """D2H snapshot for the residency host-RAM tier, then release the

@@ -31,6 +31,7 @@ from pinot_tpu.engine.executor import (
 )
 from pinot_tpu.engine.plan import PlanError, SegmentPlan, plan_segment
 from pinot_tpu.engine.results import AggResult, GroupByResult, QueryStats
+from pinot_tpu.engine.staging import add_device_bytes
 from pinot_tpu.parallel.batch import SegmentBatch
 from pinot_tpu.parallel.combine import (
     DOC_AXIS,
@@ -112,6 +113,18 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         self._borrows = 0
 
     # -- combine overrides --------------------------------------------------
+    def _stage_devices(self, ctx, segments) -> int:
+        """An aggregation over several segments of a table without trees
+        goes through the sharded combine, whose batch arrays are split
+        over every device of the mesh; anything else (a single segment, a
+        selection, a table with star-trees: the per-segment ladder) is
+        staged on one."""
+        if (len(segments) > 1 and not ctx.distinct and not ctx.is_selection
+                and not any(getattr(s, "star_trees", None)
+                            for s in segments)):
+            return self.mesh.size
+        return 1
+
     def _sliced_lease(self, stats):
         """The sliced lease when admission granted budget-sliced execution
         (working set over the HBM budget, largest segment fits), else
@@ -353,7 +366,7 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         from pinot_tpu.engine.kernels import unpack_outputs
 
         lease = self._lease_of(stats)
-        with maybe_span(stats, "Stage", segments=len(segments)):
+        with maybe_span(stats, "Stage", segments=len(segments)) as stage_sp:
             batch = self.batch_for(segments, lease)
             # the batch's device arrays are a resident like any staged
             # segment: byte-accounted, LRU-ordered, and PINNED through this
@@ -363,6 +376,13 @@ class ShardedQueryExecutor(ServerQueryExecutor):
             self.residency.register(
                 bkey, lambda: _BatchResident(self, batch),
                 same=lambda r: r.batch is batch, lease=lease)
+            if stage_sp is not None:
+                # what the batch holds as the stage ends (a column's first
+                # use stages it later, under Plan: that query reads less)
+                held = self.residency.resident_device_nbytes(bkey)
+                stage_sp.attrs.update(
+                    devices=len(held),
+                    fullestDeviceBytes=max(held.values(), default=0))
         S = pad_segments(batch.num_segments, self.mesh.shape[SEG_AXIS])
 
         # the filter fingerprint distinguishes same-SQL contexts whose
@@ -421,12 +441,16 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         # budget now rather than waiting for end_query
         self.residency.account(bkey, lease)
         # estimate-drift feedback for the batch path: the admission/slice
-        # estimates were per-segment sums; the measured batch bytes (incl.
-        # the mesh seg-axis pad) are the truth slicing should pick k from
-        # on the next pass
+        # estimates were per-segment sums, spread over the lease's devices;
+        # the batch's measured bytes on its fullest device (incl. the mesh
+        # seg-axis pad and the replicated dictionaries) are the truth
+        # slicing should pick k from on the next pass
         if lease is not None and lease._est:
-            est = sum(lease._est.get(s.segment_name, 0) for s in segments)
-            measured = self.residency.resident_nbytes(bkey)
+            est = sum(lease._est.get(s.segment_name, 0)
+                      for s in segments) // lease.devices
+            measured = max(
+                self.residency.resident_device_nbytes(bkey).values(),
+                default=0)
             if est > 0 and measured > 0:
                 self.residency.observe_estimate(est, measured)
 
@@ -962,12 +986,20 @@ class _BatchResident:
         self.executor = executor
         self.batch = batch
 
-    def nbytes(self) -> int:
+    def device_nbytes(self) -> Dict[int, int]:
+        """Bytes by device: a sharded column's shard on each device of
+        the mesh, the replicated dictionaries whole on every one."""
         name = self.batch.metadata.segment_name
         with self.executor._device_cols_lock:
             staged = [v for k, v in self.executor._device_cols.items()
                       if k[0] == name]
-        return sum(_tree_nbytes(v) for v in staged)
+        into: Dict[int, int] = {}
+        for v in staged:
+            _tree_device_bytes(v, into)
+        return into
+
+    def nbytes(self) -> int:
+        return sum(self.device_nbytes().values())
 
     def release(self) -> None:
         self.executor._evict_batch(self.batch)
@@ -983,13 +1015,14 @@ class _BatchResident:
         return image if image.nbytes() > 0 else None
 
 
-def _tree_nbytes(obj) -> int:
-    """Device bytes of a staged-column value: dict trees of arrays, the
-    (words, bits) packed tuples, or bare arrays."""
-    if obj is None:
-        return 0
+def _tree_device_bytes(obj, into: Dict[int, int]) -> None:
+    """Device bytes of a staged-column value, added to ``into`` by device:
+    dict trees of arrays, the (words, bits) packed tuples, or bare
+    arrays."""
     if isinstance(obj, dict):
-        return sum(_tree_nbytes(v) for v in obj.values())
+        obj = list(obj.values())
     if isinstance(obj, (tuple, list)):
-        return sum(_tree_nbytes(v) for v in obj)
-    return int(getattr(obj, "nbytes", 0) or 0)
+        for v in obj:
+            _tree_device_bytes(v, into)
+    else:
+        add_device_bytes(obj, into)

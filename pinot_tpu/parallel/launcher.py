@@ -36,6 +36,13 @@ A kernel whose vmapped form fails to build/run (e.g. a batching rule a
 backend can't lower) is marked non-batchable and its group falls back to
 serial launches on the dispatcher thread — coalescing degrades to the old
 serialized behavior, never to a wrong answer.
+
+The dispatcher never BUILDS a vmapped form: its first call traces and
+compiles a batched program, seconds on the chip, and every rider of the
+group (and every query queued behind it) would wait for that inside its
+own launch. A group whose variant this kernel has not built launches its
+members one by one (``unbuiltGroups`` on ``/debug/launches`` counts them);
+``LaunchKernel.run_many`` called off the serving path builds it.
 """
 
 from __future__ import annotations
@@ -68,9 +75,11 @@ class LaunchKernel:
     query's runtime arrays; everything else — staged columns, mesh, output
     layout — is closed over). ``key`` is the literal-normalized identity two
     requests must share to ride one launch: same compiled kernel, same
-    staged arrays, same num_docs source. The vmapped form is built lazily
-    per padded batch size and maps ONLY over params (``in_axes=(0, None)``),
-    so staged columns are broadcast, not copied per batch element.
+    staged arrays, same num_docs source. The vmapped form is built per
+    padded batch size by the first ``run_many`` of that size — never by the
+    dispatcher, which asks ``has_batched`` first — and maps ONLY over params
+    (``in_axes=(0, None)``), so staged columns are broadcast, not copied per
+    batch element.
     """
 
     __slots__ = ("key", "call", "is_pallas", "max_batch", "batchable",
@@ -91,6 +100,13 @@ class LaunchKernel:
     def run_one(self, params, num_docs):
         return self.call(params, num_docs)
 
+    def has_batched(self, n: int) -> bool:
+        """Whether the batched variant a group of ``n`` would ride has been
+        built (a ``run_many`` of its padded size has returned)."""
+        size = min(_next_pow2(n), _next_pow2(self.max_batch))
+        with self._lock:
+            return size in self._vmapped
+
     def run_many(self, params_list: List[Any], num_docs) -> List[Any]:
         """One vmapped launch over ``len(params_list)`` stacked param sets;
         returns one output row per param set (device-sliced, D2H deferred
@@ -106,14 +122,15 @@ class LaunchKernel:
         stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *padded)
         with self._lock:
             fn = self._vmapped.get(size)
-            if fn is None:
-                # vmap of the jitted solo call: pjit's batching rule traces
-                # the inner program with a leading batch dim and caches the
-                # compile in the inner jit's own cache (no outer jit — that
-                # would bake the closed-over staged columns in as constants)
-                fn = jax.vmap(self.call, in_axes=(0, None))
-                self._vmapped[size] = fn
+        if fn is None:
+            # vmap of the jitted solo call: pjit's batching rule traces
+            # the inner program with a leading batch dim and caches the
+            # compile in the inner jit's own cache (no outer jit — that
+            # would bake the closed-over staged columns in as constants)
+            fn = jax.vmap(self.call, in_axes=(0, None))
         out = fn(stacked, num_docs)
+        with self._lock:    # built: its trace and compile are done
+            self._vmapped.setdefault(size, fn)
         return [out[j] for j in range(n)]
 
 
@@ -198,6 +215,7 @@ class LaunchScheduler:
         self.deduped_requests = 0  # guarded-by-writes: _stats_lock
         self.batched_requests = 0  # guarded-by-writes: _stats_lock
         self.failures = 0  # guarded-by-writes: _stats_lock
+        self.unbuilt_groups = 0  # guarded-by-writes: _stats_lock
         self.max_batch_size = 0  # guarded-by-writes: _stats_lock
         self.queue_wait_ms_total = 0.0  # guarded-by-writes: _stats_lock
         self.queue_wait_ms_max = 0.0  # guarded-by-writes: _stats_lock
@@ -363,7 +381,14 @@ class LaunchScheduler:
             start = 0
             while start < len(uniq):
                 chunk = uniq[start:start + kernel.max_batch]
-                if kernel.batchable and len(chunk) > 1:
+                batched = kernel.batchable and len(chunk) > 1
+                if batched and not kernel.has_batched(len(chunk)):
+                    # building it here would be a compile inside these
+                    # queries' launch: its members go one by one instead
+                    batched = False
+                    with self._stats_lock:
+                        self.unbuilt_groups += 1
+                if batched:
                     try:
                         rows = kernel.run_many(chunk, num_docs)
                         outs[start:start + len(chunk)] = rows
@@ -489,6 +514,7 @@ class LaunchScheduler:
                 "dedupedRequests": self.deduped_requests,
                 "batchedRequests": self.batched_requests,
                 "failures": self.failures,
+                "unbuiltGroups": self.unbuilt_groups,
                 "maxBatchSize": self.max_batch_size,
                 "queueWaitMsTotal": round(self.queue_wait_ms_total, 3),
                 "queueWaitMsMax": round(self.queue_wait_ms_max, 3),

@@ -38,7 +38,8 @@ caught:
   release.
 - **cache-field parity** (classes defining both ``nbytes()`` and
   ``release()``): every field such a class populates outside ``__init__``
-  must be read by ``nbytes()`` AND cleared by ``release()`` — a staged
+  must be read by ``nbytes()`` (or the ``device_nbytes()`` it sums) AND
+  cleared by ``release()`` — a staged
   cache that accounting cannot see, or that eviction cannot drop, is the
   tiered-storage follow-up's landmine.
 - **idxacct** (every function, package-wide): a ``.index_slice(...)``
@@ -842,7 +843,7 @@ def _check_cache_parity(mod: Module, node: ast.ClassDef,
     release_fn = methods["release"]
     fields: Dict[str, Tuple[str, int]] = {}
     for mname, fn in methods.items():
-        if mname in ("__init__", "release", "nbytes"):
+        if mname in ("__init__", "release", "nbytes", "device_nbytes"):
             continue
         for n in walk_no_nested(fn):
             targets: List[ast.expr] = []
@@ -868,7 +869,11 @@ def _check_cache_parity(mod: Module, node: ast.ClassDef,
                     base = base.value
                 if isinstance(base, ast.Attribute) and is_self_attr(base):
                     fields.setdefault(base.attr, (mname, n.lineno))
-    read_in_nbytes = {n.attr for n in ast.walk(nbytes_fn)
+    # a resident that reckons its bytes by device counts in
+    # ``device_nbytes()``, of which ``nbytes()`` is the sum
+    counting = [nbytes_fn] + [methods[m] for m in ("device_nbytes",)
+                              if m in methods]
+    read_in_nbytes = {n.attr for fn in counting for n in ast.walk(fn)
                       if isinstance(n, ast.Attribute) and is_self_attr(n)}
     cleared: Set[str] = set()
     for n in ast.walk(release_fn):

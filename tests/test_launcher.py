@@ -127,8 +127,12 @@ def test_vmapped_batch_distinct_params():
         return params * num_docs
 
     kern = LaunchKernel(("k2",), call, max_batch=8)
-    b = sched.submit(blocker, 0, 0)
     nd = jnp.int32(3)
+    # the dispatcher rides a batched variant, it never builds one: built
+    # here, off the serving path (three pad to the variant of four)
+    kern.run_many([jnp.float32(v) for v in (0.0, 7.0, 9.0)], nd)
+    launches.clear()
+    b = sched.submit(blocker, 0, 0)
     reqs = [sched.submit(kern, jnp.float32(v), nd) for v in (1.0, 2.0, 5.0)]
     gate.set()
     b.result(30)
@@ -139,6 +143,41 @@ def test_vmapped_batch_distinct_params():
     assert len(launches) == 1
     assert all(r.batch_size == 3 for r in reqs)
     assert sched.stats_snapshot()["launchesSaved"] >= 2
+    assert sched.stats_snapshot()["unbuiltGroups"] == 0
+
+
+def test_unbuilt_batched_variant_is_never_built_by_the_dispatcher():
+    """A group whose batched variant the kernel has not built launches its
+    members one by one and is counted: tracing and compiling a batched
+    program inside a live query's launch makes its riders wait seconds on
+    the chip. Built off the serving path, the next group rides it."""
+    import jax.numpy as jnp
+
+    sched = LaunchScheduler(name="t-unbuilt")
+    launches = []
+
+    def call(params, num_docs):
+        launches.append(1)
+        return params * num_docs
+
+    kern = LaunchKernel(("ku",), call, max_batch=8)
+    nd = jnp.int32(3)
+    for round_, want_launches in ((0, 2), (1, 1)):
+        blocker, gate = _blocker()
+        b = sched.submit(blocker, 0, 0)
+        reqs = [sched.submit(kern, jnp.float32(v), nd) for v in (1.0, 2.0)]
+        gate.set()
+        b.result(30)
+        assert [float(np.asarray(r.result(30))) for r in reqs] == [3.0, 6.0]
+        assert len(launches) == want_launches, round_
+        assert all(r.batch_size == 2 for r in reqs)
+        assert reqs[0].launches_saved == 2 - want_launches
+        assert sched.snapshot()["unbuiltGroups"] == 1
+        if round_ == 0:
+            assert not kern.has_batched(2)
+            kern.run_many([jnp.float32(0.0), jnp.float32(4.0)], nd)
+            assert kern.has_batched(2) and not kern.has_batched(3)
+            launches.clear()
 
 
 def test_unbatchable_kernel_falls_back_serial():
@@ -152,7 +191,11 @@ def test_unbatchable_kernel_falls_back_serial():
 
     import jax.numpy as jnp
 
+    import jax
+
     kern = LaunchKernel(("k3",), call, max_batch=8)
+    # a variant that passes for built and fails when it runs
+    kern._vmapped[2] = jax.vmap(call, in_axes=(0, None))
     b = sched.submit(blocker, 0, 0)
     reqs = [sched.submit(kern, jnp.float32(v), 0) for v in (1.0, 4.0)]
     gate.set()
@@ -233,6 +276,21 @@ THREADS = 8
 ITERS = 6
 
 
+def _build_batched(dev, segs, sizes=(2, 4, 8)) -> None:
+    """Every launch kernel's batched variants, built off the serving path
+    (the dispatcher rides them and never builds one)."""
+    from pinot_tpu.parallel.combine import SEG_AXIS, pad_segments
+
+    batch = dev.batch_for(segs)
+    S = pad_segments(batch.num_segments, dev.mesh.shape[SEG_AXIS])
+    num_docs = dev._device_num_docs(batch, S)
+    with dev._cache_lock:
+        entries = list(dev._param_cache.values())
+    for _, lkey, params in entries:
+        for size in sizes:
+            dev._launch_cache[lkey].run_many([params] * size, num_docs)
+
+
 def test_concurrency_hammer(setup):
     _, segs = setup
     dev = ShardedQueryExecutor()  # the suite-wide virtual 8-device mesh
@@ -242,6 +300,7 @@ def test_concurrency_hammer(setup):
     for ctx in ctxs:
         rt, _ = dev.execute(ctx, segs)
         serial.append(rt.rows)
+    _build_batched(dev, segs)
     mark = dev.launcher.stats_snapshot()
 
     errors = []
@@ -437,6 +496,8 @@ def test_window_gathers_stragglers_into_one_batch():
         return params * num_docs
 
     kern = LaunchKernel(("kw",), call, max_batch=8)
+    kern.run_many([jnp.float32(0.0), jnp.float32(1.0)], jnp.int32(3))
+    launches.clear()
     r1 = sched.submit(kern, jnp.float32(2.0), jnp.int32(3))
     time.sleep(0.02)  # arrives mid-window: must join r1's drain
     r2 = sched.submit(kern, jnp.float32(5.0), jnp.int32(3))

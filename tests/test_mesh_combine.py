@@ -147,3 +147,49 @@ def test_sliced_combine_on_8dev_mesh_matches_uncapped(ssb_segs,
     assert got.rows == want.rows
     assert stats.staging.get("spills", 0) == 0, \
         "capped run spilled to host instead of slicing on the mesh"
+
+
+def test_four_device_mesh_holds_a_table_one_device_could_not(
+        ssb_segs, exec_1dev, forced_mesh_devices):
+    """The deployment of ``ssb_scan_x4`` at toy size: mesh 4x1, a budget
+    (bytes a device) under the table's bytes and over a device's share.
+    Every flight is served sharded and exact, nothing is evicted, sliced
+    or spilled, and the spans say where the bytes lie."""
+    from pinot_tpu.common.tracing import flatten_spans
+
+    def executor(**kw):
+        return ShardedQueryExecutor(
+            mesh=make_combine_mesh(devices=forced_mesh_devices[:4]), **kw)
+
+    sqls = [ssb.QUERIES[q] + " LIMIT 100000" for q in QIDS]
+    probe = executor()
+    for sql in sqls:
+        probe.execute(compile_query(sql), ssb_segs)
+    snap = probe.residency.snapshot()
+    total = snap["stagedBytes"]
+    fullest = max(d["stagedBytes"] for d in snap["devices"])
+    budget = int(fullest * 1.5)
+    assert fullest < budget < total
+
+    ex = executor(hbm_budget_bytes=budget)
+    for sql in sqls:    # stage every column (the first bind of each does)
+        ex.execute(compile_query(sql), ssb_segs)
+    paths = []
+    for sql in sqls:
+        ctx = compile_query(sql + " OPTION(trace=true)")
+        got, stats = ex.execute(ctx, ssb_segs)
+        want, _ = exec_1dev.execute(compile_query(sql), ssb_segs)
+        assert got.rows == want.rows
+        spans = {e["operator"]: e for e in flatten_spans(stats.spans)}
+        paths.append(spans["Route"]["path"])
+        if paths[-1] == "per_segment":   # pruned to one segment: device 0
+            continue
+        assert spans["ShardedCombine"]["mesh"] == "4x1"
+        assert spans["Stage"]["devices"] == 4
+        assert 0 < spans["Stage"]["fullestDeviceBytes"] <= budget
+    assert paths.count("sharded") >= 10 and set(paths) <= {"sharded",
+                                                           "per_segment"}
+    counters = ex.residency.snapshot()["counters"]
+    assert (counters["evictions"], counters["slicedQueries"],
+            counters["spills"]) == (0, 0, 0)
+    assert ex.residency.staged_bytes() == total

@@ -69,6 +69,10 @@ def _stage_full(rm: ResidencyManager, seg, lease=None):
     return st
 
 
+def _fullest_device(rm: ResidencyManager) -> int:
+    return max(d["stagedBytes"] for d in rm.snapshot()["devices"])
+
+
 def _host_rows(segs, sql):
     host = ServerQueryExecutor(use_device=False)
     rt, _ = host.execute(compile_query(sql), segs)
@@ -253,13 +257,14 @@ def test_sharded_spill_matches_host_oracle(segs):
 
 
 def test_sharded_capped_budget_churns_but_stays_correct(segs):
-    """Budget fits ONE batch resident: alternating working sets (the full
-    segment list vs a subset batch) evict each other — LRU churn — while
-    every answer stays host-identical and nothing device-OOMs."""
+    """Budget (bytes a device) fits ONE batch resident's share of the
+    fullest device: alternating working sets (the full segment list vs a
+    subset batch) evict each other — LRU churn — while every answer stays
+    host-identical and nothing device-OOMs."""
     probe = ShardedQueryExecutor()
     ctx_all = compile_query(GROUP_SQL)
     probe.execute(ctx_all, segs)
-    one_batch = probe.residency.staged_bytes()
+    one_batch = _fullest_device(probe.residency)
     assert one_batch > 0
 
     dev = ShardedQueryExecutor(hbm_budget_bytes=int(one_batch * 1.5))
@@ -274,7 +279,7 @@ def test_sharded_capped_budget_churns_but_stays_correct(segs):
         assert rt.rows == want_sub
     snap = dev.residency.stats_snapshot()
     assert snap["evictions"] >= 1, "capped budget never churned"
-    assert snap["stagedBytes"] <= int(one_batch * 1.5)
+    assert _fullest_device(dev.residency) <= int(one_batch * 1.5)
 
 
 def test_warm_hit_rate_is_total(segs):
@@ -544,3 +549,117 @@ def test_auto_budget_raises_on_accelerator_without_bytes_limit(monkeypatch):
         resolve_budget_bytes()
     # an explicit budget never asks the backend
     assert resolve_budget_bytes(1234) == 1234
+
+
+# --------------------------------------------------------------------------
+# bytes are reckoned a device (HBM is a chip's, so is the budget)
+# --------------------------------------------------------------------------
+
+def _mesh_executor(n_devices: int, **kw) -> ShardedQueryExecutor:
+    import jax
+
+    from pinot_tpu.parallel import make_combine_mesh
+
+    return ShardedQueryExecutor(
+        mesh=make_combine_mesh(jax.devices()[:n_devices]), **kw)
+
+
+def _table_bytes(segs, n_devices: int):
+    """(all devices together, the fullest device) of the whole table
+    resident behind GROUP_SQL and AGG_SQL on an ``n_devices`` mesh."""
+    probe = _mesh_executor(n_devices)
+    for sql in (GROUP_SQL, AGG_SQL):
+        probe.execute(compile_query(sql), segs)
+    return probe.residency.staged_bytes(), _fullest_device(probe.residency)
+
+
+def test_table_over_one_devices_budget_is_resident_on_four(segs):
+    """All bytes together exceed a device's budget, each device's share
+    fits: the table sits resident on the four devices and is served
+    sharded, nothing evicted, sliced or spilled, answers the host's."""
+    total, fullest = _table_bytes(segs, 4)
+    budget = int(fullest * 1.6)
+    assert fullest < budget < total
+    dev = _mesh_executor(4, hbm_budget_bytes=budget)
+    for _ in range(2):
+        for sql in (GROUP_SQL, AGG_SQL):
+            rt, stats = dev.execute(compile_query(sql), segs)
+            assert rt.rows == _host_rows(segs, sql)
+            assert stats.staging["slices"] == 0
+    snap = dev.residency.snapshot()
+    counters = snap["counters"]
+    assert (counters["evictions"], counters["slicedQueries"],
+            counters["spills"]) == (0, 0, 0)
+    assert snap["stagedBytes"] == total > snap["budgetBytes"]
+    held = [d for d in snap["devices"] if d["stagedBytes"]]
+    assert len(held) == 4
+    assert all(d["stagedBytes"] <= d["budgetBytes"] == budget for d in held)
+
+
+def test_table_over_every_devices_share_is_sliced_as_ever(segs):
+    """The same table under a budget that a device's share of it does not
+    fit streams through in slices, exact, never spilled."""
+    _, fullest = _table_bytes(segs, 2)
+    dev = _mesh_executor(2, hbm_budget_bytes=int(fullest * 0.6))
+    rt, stats = dev.execute(compile_query(GROUP_SQL), segs)
+    assert rt.rows == _host_rows(segs, GROUP_SQL)
+    assert stats.staging["spills"] == 0
+    assert stats.staging["slices"] >= 2
+    assert dev.residency.stats_snapshot()["slicedQueries"] == 1
+
+
+def test_one_device_counts_what_it_always_did(segs):
+    """On one device every byte count is the sum of the arrays' ``nbytes``
+    (the only reckoning there was): the sharded batch, a staged segment,
+    the total, and the one entry of ``devices`` that holds anything."""
+    from pinot_tpu.engine.staging import DEFAULT_DEVICE
+
+    dev = _mesh_executor(1)
+    dev.execute(compile_query(GROUP_SQL), segs)
+    _stage_full(dev.residency, segs[0])
+
+    def plain(obj) -> int:
+        if isinstance(obj, dict):
+            obj = list(obj.values())
+        if isinstance(obj, (tuple, list)):
+            return sum(plain(v) for v in obj)
+        return int(getattr(obj, "nbytes", 0))
+
+    with dev._device_cols_lock:
+        batch_bytes = plain(list(dev._device_cols.values()))
+    st = dev.residency.stage(segs[0])
+    seg_bytes = sum(plain(c.tree()) for c in st._columns.values())
+    snap = dev.residency.snapshot()
+    assert st.nbytes() == seg_bytes > 0
+    assert snap["stagedBytes"] == batch_bytes + seg_bytes
+    assert [d["stagedBytes"] for d in snap["devices"] if d["stagedBytes"]] \
+        == [snap["stagedBytes"]]
+    assert snap["devices"][0]["id"] == DEFAULT_DEVICE
+
+
+def test_debug_memory_devices_add_up_with_replicas_on_each(segs):
+    """``devices[].stagedBytes`` sum to ``stagedBytes``; a replicated
+    array (the unified dictionary values) is counted on every device that
+    holds a copy, a sharded one shard by shard."""
+    dev = _mesh_executor(4)
+    dev.execute(compile_query(GROUP_SQL), segs)
+    snap = dev.residency.snapshot()
+    assert len(snap["devices"]) == 8   # every device of the process
+    assert {"id", "budgetBytes", "stagedBytes", "bytesInUse",
+            "peakBytes"} == set(snap["devices"][0])
+    assert sum(d["stagedBytes"] for d in snap["devices"]) \
+        == snap["stagedBytes"]
+    with dev._device_cols_lock:
+        trees = [v for v in dev._device_cols.values()
+                 if isinstance(v, dict)]
+    sharded = sum(int(a.nbytes) for t in trees for k, a in t.items()
+                  if k != "dictvals")
+    replicated = sum(int(a.nbytes) for t in trees for k, a in t.items()
+                     if k == "dictvals")
+    assert replicated > 0
+    with dev._device_cols_lock:
+        rest = sum(int(v.nbytes) for v in dev._device_cols.values()
+                   if not isinstance(v, dict))     # the doc counts
+    by_device = sorted(d["stagedBytes"] for d in snap["devices"])
+    assert by_device[:4] == [0] * 4
+    assert by_device[4:] == [(sharded + rest) // 4 + replicated] * 4

@@ -216,12 +216,16 @@ def test_sliced_combine_parity_at_fraction_of_working_set(segs, oracle,
 
 def test_sliced_combine_on_padded_mesh_degrades_to_per_segment(segs,
                                                                oracle):
-    """On the default (8-virtual-device) mesh every batch pads to 8
-    segments, so a small budget can fit no multi-segment slice —
-    plan_slices returns None and the per-segment sliced path serves,
-    still on device, still exact."""
+    """On a 2-wide mesh every batch pads to 2 segments, one a device. A
+    budget (bytes a device) that holds one segment and not the two a
+    device's share of the four comes to fits no padded slice under the
+    planner's fill — plan_slices returns None and the per-segment sliced
+    path serves, still on device, still exact."""
+    import jax
+
     est = residency_mod.estimate_segment_bytes(segs[0], COLUMNS)
-    dev = ShardedQueryExecutor(hbm_budget_bytes=int(est * 2.5))
+    dev = ShardedQueryExecutor(mesh=make_combine_mesh(jax.devices()[:2]),
+                               hbm_budget_bytes=int(est * 1.1))
     rt, stats = dev.execute(compile_query(GROUP_SQL), segs)
     assert rt.rows == oracle["rows"]["group"]
     assert stats.staging["spills"] == 0

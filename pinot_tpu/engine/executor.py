@@ -178,6 +178,10 @@ class ServerQueryExecutor:
         # last kernel-preflight verdict table run against this executor
         # (tools/preflight.attach_verdicts); surfaced on GET /debug/pallas
         self.preflight_verdicts: Optional[dict] = None
+        # fused-scan launches by accumulate form (GET /debug/pallas
+        # ``launches``)
+        self._pallas_launches = {"single": 0, "two_level": 0}  # guarded-by: _pallas_launches_lock
+        self._pallas_launches_lock = threading.Lock()
         self._segment_pool = None
         self._segment_pool_lock = threading.Lock()
         # request-tier admission: bounded concurrency + bounded queue in
@@ -246,6 +250,28 @@ class ServerQueryExecutor:
         if backend in ("gpu", "cuda", "rocm"):
             return None  # pltpu memory spaces cannot lower on GPU
         return backend == "cpu"  # interpret on CPU
+
+    def _note_pallas_launch(self, plan_spec: Tuple,
+                            count: bool = True) -> Dict[str, Any]:
+        """The span attributes that say which accumulate the fused scan of
+        ``plan_spec`` takes (``groups`` is the kernel's
+        ``num_groups_padded``); counts the launch under that name unless
+        the caller shared another query's."""
+        from pinot_tpu.engine.pallas_kernels import (
+            accumulate_kind,
+            padded_groups,
+        )
+
+        groups = padded_groups(plan_spec)
+        kind = accumulate_kind(groups)
+        if count:
+            with self._pallas_launches_lock:
+                self._pallas_launches[kind] += 1
+        return {"groups": groups, "accumulate": kind}
+
+    def pallas_launches(self) -> Dict[str, int]:
+        with self._pallas_launches_lock:
+            return dict(self._pallas_launches)
 
     # -- public ------------------------------------------------------------
     def execute_instance(self, ctx: QueryContext,
@@ -1047,10 +1073,13 @@ class ServerQueryExecutor:
             t0 = _time.perf_counter()
             with maybe_span(stats, "Kernel", kernel="pallas",
                             segment=seg.segment_name) as sp:
-                served, _ = self._kernel_flight.do(
+                served, shared = self._kernel_flight.do(
                     ("pallas", id(plan), id(staged)), launch)
+                took = (self._note_pallas_launch(served[1].spec,
+                                                 count=not shared)
+                        if served is not None else {})
                 if sp is not None:
-                    sp.attrs["served"] = served is not None
+                    sp.attrs.update(took, served=served is not None)
             observe_ms(getattr(stats, "_tel_table", ""), "kernel",
                        (_time.perf_counter() - t0) * 1e3)
         except Exception:  # lowering/compile failure -> jnp kernels

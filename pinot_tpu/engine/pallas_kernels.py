@@ -20,7 +20,12 @@ over a ``(segments, tiles)`` grid:
 - sums/counts/avg are a **one-hot matmul on the MXU**: rows
   ``[value rows..., mask] @ one_hot(keys)`` accumulate ``[aggs, groups]``
   partials — the fixed-shape scatter-add replacement for
-  ``GroupByResultHolder``. Exactness scheme:
+  ``GroupByResultHolder``. Above 128 groups the key splits in **two
+  levels**, ``hi = key >> 7`` and ``lo = key & 127``: a tile builds ONE
+  ``[T, 128]`` one-hot of ``lo`` and expands each row into ``H = G / 128``
+  rows by ``hi`` (row ``m * H + h`` keeps the docs whose ``hi == h``), so
+  one full-height matmul gives every group's partial and a tile's work
+  does not multiply rows by 128-group chunks. Exactness scheme:
   - **integer sums** split each value into 12-bit limbs (``L`` limbs for a
     plan-time ``max_abs`` bound): every per-tile limb partial is at most
     ``4095 * PALLAS_TILE < 2^24`` — exactly representable in the f32 matmul
@@ -59,7 +64,11 @@ from pinot_tpu.engine.staging import LIMB_BITS, PALLAS_TILE, StagedSegment
 
 # one-hot chunk width along the group dimension (lane count)
 _G_CHUNK = 128
-# max padded group count the pallas path handles (VMEM + unroll bound);
+# most LHS rows of one accumulate matmul (two MXU heights): a plan whose
+# expanded row stack is taller runs it in blocks of this many rows
+_EXPAND_ROWS = 256
+# max padded group count the pallas path handles (VMEM bound: the
+# accumulators and one expanded row block grow with it);
 # 8192 covers every SSB flight except the Q3.2+/Q4.3 city/brand key spaces
 # (those ride the jnp sparse-group ladder, engine/kernels.py)
 MAX_PALLAS_GROUPS = 8192
@@ -304,9 +313,7 @@ def extract_plan(plan, provider, on_decline=None,
             # column's dictId range; fold them into one static key offset
             bases = [int(b) for b in np.asarray(pc.take())]
             key_offset = sum(b * s for b, s in zip(bases, strides))
-            G = -(-num_groups // _G_CHUNK) * _G_CHUNK
-        else:
-            G = _G_CHUNK  # single group at key 0
+        G = padded_groups(plan.spec)
 
         # -- aggregation value expressions (ref: the reference evaluates
         # transform expressions inside the aggregation operator,
@@ -601,6 +608,33 @@ def _expr_is_int(vexpr: Tuple, value_is_int: Tuple[bool, ...]) -> bool:
             and _expr_is_int(vexpr[2], value_is_int))
 
 
+def accumulate_rows(num_groups_padded: int) -> Tuple[int, int, int]:
+    """(H, Hp, rows_per_dot) of the two-level accumulate: the composed key
+    splits into ``hi = key >> 7`` in [0, H) and ``lo = key & 127``; a
+    sum/count accumulator row holds its groups as Hp sublane rows of 128
+    lanes — 1 when H == 1, else H rounded up to whole (8, 128) vregs; one
+    matmul takes ``rows_per_dot`` matmul rows, expanded to at most
+    _EXPAND_ROWS LHS rows (the working set tools/preflight.py budgets)."""
+    H = num_groups_padded // _G_CHUNK
+    Hp = 1 if H == 1 else -(-H // 8) * 8
+    return H, Hp, max(1, _EXPAND_ROWS // Hp)
+
+
+def padded_groups(plan_spec: Tuple) -> int:
+    """``num_groups_padded`` of the kernel that serves a plan spec: its
+    group count rounded up to whole 128-lane chunks; a scalar aggregation
+    is a single group at key 0."""
+    _, _, group_specs, num_groups, _ = plan_spec
+    if not group_specs:
+        return _G_CHUNK
+    return -(-num_groups // _G_CHUNK) * _G_CHUNK
+
+
+def accumulate_kind(num_groups_padded: int) -> str:
+    """What a span or counter calls the accumulate a spec takes."""
+    return "single" if num_groups_padded <= _G_CHUNK else "two_level"
+
+
 def build_kernel(spec: PallasSpec):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -608,7 +642,7 @@ def build_kernel(spec: PallasSpec):
     T = PALLAS_TILE
     RT = T // 128
     G = spec.num_groups_padded
-    n_chunks = G // _G_CHUNK
+    H, Hp, rows_per_dot = accumulate_rows(G)
     n_packed = len(spec.packed_bits)
     n_values = len(spec.value_is_int)
     # per value input: how many refs it occupies (1 plain array, or L
@@ -624,9 +658,19 @@ def build_kernel(spec: PallasSpec):
     TPS = spec.tiles_per_seg
 
     fsum_row, isum_row, mm_row, Mf, Mi, Mm = _row_layout(spec)
-    nf = len(fsum_row)
-    # matmul row plan: [nf float rows][1 count row][per int sum: L limb rows]
+    # matmul row plan: [nf float rows][1 count row][per int sum: L limb
+    # rows]; row_target[m] = which accumulator row takes matmul row m
+    float_sums = sorted(fsum_row.items(), key=lambda kv: kv[1])
     int_sums = sorted(isum_row.items(), key=lambda kv: kv[1][0])
+    row_target = [("f", r) for _vexpr, r in float_sums] + [("i", 0)]
+    for _vexpr, (start, L) in int_sums:
+        row_target += [("i", start + k) for k in range(L)]
+
+    def acc(r):
+        """Accumulator row ``r``'s [Hp, 128] block of out_f / out_i: group
+        h * 128 + l sits at [r * Hp + h, l] (whole vregs for H > 1)."""
+        return slice(r * Hp, (r + 1) * Hp)
+
     # params: [2*n_slots intervals][S num_docs][1 doc_base], held in SMEM as
     # one [1, n] row — 2-D so that a vmap over params (the launcher's
     # coalesced form) squeezes a LEADING dim and leaves a legal block
@@ -761,9 +805,9 @@ def build_kernel(spec: PallasSpec):
         m_i = mask.astype(jnp.int32)
         out_seg[0] += sum(m_i[r:r + 8] for r in range(0, RT, 8))
 
-        # -- matmul row stack [nf + 1 + sum(L), RT, 128] f32
+        # -- matmul row stack [nf + 1 + sum(L), T] f32 (docs flattened)
         rows = []
-        for vexpr, _r in sorted(fsum_row.items(), key=lambda kv: kv[1]):
+        for vexpr, _r in float_sums:
             rows.append(emit_vexpr(vexpr).astype(jnp.float32) * mask_f)
         rows.append(mask_f)                        # count row (out_i row 0)
         for vexpr, (start, L) in int_sums:
@@ -784,49 +828,64 @@ def build_kernel(spec: PallasSpec):
                 else:
                     limb = v >> (k * _LIMB_BITS)   # top limb keeps the sign
                 rows.append(limb.astype(jnp.float32))
-        R = jnp.stack(rows)                        # [M_mat, RT, 128]
+        R = jnp.stack(rows).reshape(len(rows), T)  # [M_mat, T]
 
-        for c in range(n_chunks):
+        # -- two-level one-hot accumulate: group g = hi * 128 + lo. ONE
+        # [T, 128] one-hot of ``lo`` a tile; ``hi`` expands every matmul
+        # row into Hp rows (row m*Hp + h keeps the docs whose hi == h), so
+        # part[m*Hp + h, l] is row m's partial of group h*128 + l and the
+        # MXU sees a full-height LHS once, not M rows against G/128
+        # one-hots. H == 1 (scalar aggregations, <= 128 groups) needs no
+        # expansion: the rows go in as they are. A plain 2-D matmul over
+        # the tile's flattened docs: Mosaic has no dot_general with two
+        # contracting dims, and takes the (RT, 128) -> T flattening as a
+        # relayout. HIGHEST keeps every MXU pass f32 (the limb/f32-
+        # exactness argument above: an expanded row holds limb values or 0)
+        lo = keys if H == 1 else keys & (_G_CHUNK - 1)
+        oh_lo = (lo[:, :, None] == jax.lax.broadcasted_iota(
+            jnp.int32, (RT, 128, _G_CHUNK), 2)
+        ).astype(jnp.float32).reshape(T, _G_CHUNK)
+        if H > 1:
+            # masked docs outside a narrowed key range: an arithmetic
+            # shift leaves hi negative or >= H, which selects no row (or a
+            # pad row h in [H, Hp) that the wrapper drops; their values
+            # are mask-zeroed anyway)
+            hi = (keys >> 7).reshape(1, T)
+            sel = hi == jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 0)
+
+        for m0 in range(0, len(rows), rows_per_dot):
+            m1 = min(m0 + rows_per_dot, len(rows))
+            lhs = R[m0:m1] if H == 1 else jnp.concatenate(
+                [jnp.where(sel, R[m:m + 1], 0.0) for m in range(m0, m1)],
+                axis=0)
+            part = jnp.dot(lhs, oh_lo,
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+            for m in range(m0, m1):
+                x = part[(m - m0) * Hp:(m - m0 + 1) * Hp]   # [Hp, 128]
+                kind, r = row_target[m]
+                if kind == "f":
+                    # float sums: Neumaier-compensated (sum, comp) pair
+                    a = out_f[acc(r)]
+                    t_ = a + x
+                    err = jnp.where(jnp.abs(a) >= jnp.abs(x),
+                                    (a - t_) + x, (x - t_) + a)
+                    out_f[acc(r)] = t_
+                    out_f[acc(r + 1)] += err
+                else:
+                    # count + int limb partials: f32 -> exact i32 (every
+                    # partial is an integer < 2^24 by the limb-width bound)
+                    out_i[acc(r)] += x.astype(jnp.int32)
+
+        # -- min/max rows reduce on the VPU per 128-group chunk
+        for c in range(H if mm_row else 0):
             g0 = c * _G_CHUNK
-            g_iota = g0 + jax.lax.broadcasted_iota(
+            eq = keys[:, :, None] == g0 + jax.lax.broadcasted_iota(
                 jnp.int32, (RT, 128, _G_CHUNK), 2)
-            oh = (keys[:, :, None] == g_iota).astype(jnp.float32)
-            # a plain 2-D matmul over the tile's flattened docs: Mosaic has
-            # no dot_general with two contracting dims, and takes the
-            # (RT, 128) -> T flattening as a relayout. HIGHEST keeps every
-            # MXU pass f32 (the limb/f32-exactness argument above)
-            part = jnp.dot(
-                R.reshape(len(rows), T), oh.reshape(T, _G_CHUNK),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)   # [M_mat, 128]
-
-            # float sums: Neumaier-compensated accumulation (sum, comp pair)
-            for j, (vexpr, r) in enumerate(
-                    sorted(fsum_row.items(), key=lambda kv: kv[1])):
-                x = part[j]
-                a = out_f[r, g0:g0 + _G_CHUNK]
-                t_ = a + x
-                err = jnp.where(jnp.abs(a) >= jnp.abs(x),
-                                (a - t_) + x, (x - t_) + a)
-                out_f[r, g0:g0 + _G_CHUNK] = t_
-                out_f[r + 1, g0:g0 + _G_CHUNK] += err
-
-            # count + int limb partials: f32 -> exact i32 (every partial is
-            # an integer < 2^24 by the limb-width bound)
-            out_i[0, g0:g0 + _G_CHUNK] += part[nf].astype(jnp.int32)
-            m = nf + 1
-            for vexpr, (start, L) in int_sums:
-                for k in range(L):
-                    out_i[start + k, g0:g0 + _G_CHUNK] += \
-                        part[m].astype(jnp.int32)
-                    m += 1
-
-            # -- min/max rows reduce on the VPU per chunk
             for (vexpr, kind), r in mm_row.items():
                 neutral = _POS if kind == "min" else _NEG
                 v = emit_vexpr(vexpr).astype(jnp.float32)
                 vm = jnp.where(mask, v, neutral)
-                eq = keys[:, :, None] == g_iota
                 v3 = jnp.where(eq, vm[:, :, None], neutral)
                 red = (v3.min(axis=(0, 1)) if kind == "min"
                        else v3.max(axis=(0, 1)))
@@ -841,10 +900,10 @@ def build_kernel(spec: PallasSpec):
         # keeping every row i32-bounded regardless of provider size
         for vexpr, (start, L) in int_sums:
             for k in range(L + 1):                 # rows start .. start+L
-                acc = out_i[start + k, :]
-                carry = acc >> _LIMB_BITS
-                out_i[start + k, :] = acc - (carry << _LIMB_BITS)
-                out_i[start + k + 1, :] += carry
+                a = out_i[acc(start + k)]
+                carry = a >> _LIMB_BITS
+                out_i[acc(start + k)] = a - (carry << _LIMB_BITS)
+                out_i[acc(start + k + 1)] += carry
 
     def block(shape0):
         nd = len(shape0)
@@ -860,15 +919,17 @@ def build_kernel(spec: PallasSpec):
         in_specs.append(block((RT, 128)))
 
     out_specs = (
-        pl.BlockSpec((Mf, G), lambda s, t: (0, 0), memory_space=pltpu.VMEM),
-        pl.BlockSpec((Mi, G), lambda s, t: (0, 0), memory_space=pltpu.VMEM),
+        pl.BlockSpec((Mf * Hp, _G_CHUNK), lambda s, t: (0, 0),
+                     memory_space=pltpu.VMEM),
+        pl.BlockSpec((Mi * Hp, _G_CHUNK), lambda s, t: (0, 0),
+                     memory_space=pltpu.VMEM),
         pl.BlockSpec((Mm, G), lambda s, t: (0, 0), memory_space=pltpu.VMEM),
         pl.BlockSpec((1, 8, 128), lambda s, t: (s, 0, 0),
                      memory_space=pltpu.VMEM),
     )
     out_shape = (
-        jax.ShapeDtypeStruct((Mf, G), jnp.float32),
-        jax.ShapeDtypeStruct((Mi, G), jnp.int32),
+        jax.ShapeDtypeStruct((Mf * Hp, _G_CHUNK), jnp.float32),
+        jax.ShapeDtypeStruct((Mi * Hp, _G_CHUNK), jnp.int32),
         jax.ShapeDtypeStruct((Mm, G), jnp.float32),
         jax.ShapeDtypeStruct((S, 8, 128), jnp.int32),
     )
@@ -887,11 +948,15 @@ def build_kernel(spec: PallasSpec):
         [S, 128]). Kernel body and index maps trace with 32-bit defaults:
         under jax_enable_x64 every weak Python scalar enters the jaxpr as
         a 64-bit literal, Mosaic has no 64 -> 32 conversion and wants i32
-        from an index map (every operand is already a 32-bit array)."""
+        from an index map (every operand is already a 32-bit array). The
+        kernel's [rows * Hp, 128] sum/count accumulators are [rows, G]
+        read row-major (less the pad rows h >= H)."""
         with jax.enable_x64(False):
             out_f, out_i, out_mm, out_seg = fused(params.reshape(1, -1),
                                                   *cols)
-            return out_f, out_i, out_mm, out_seg.sum(axis=1)
+            return (out_f.reshape(Mf, Hp * _G_CHUNK)[:, :G],
+                    out_i.reshape(Mi, Hp * _G_CHUNK)[:, :G],
+                    out_mm, out_seg.sum(axis=1))
 
     return pallas_scan
 

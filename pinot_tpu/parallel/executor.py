@@ -424,18 +424,22 @@ class ShardedQueryExecutor(ServerQueryExecutor):
             out = self._launch_sharded(pkey, plan, batch, S, kernel, params,
                                        num_docs, stats, req_out)
         finally:
+            req = req_out[-1] if req_out else None
+            pallas = req is not None and req.kernel.is_pallas
+            # which accumulate the fused scan took: `plan` is the plan the
+            # pallas kernel was bound to (probe-narrowed where it was)
+            took = self._note_pallas_launch(plan.spec) if pallas else {}
             if sp is not None:
-                req = req_out[-1] if req_out else None
                 rec.span_end(
                     sp,
                     queue_ms=(round(req.queue_wait_ms, 3)
                               if req is not None else None),
-                    kernel="pallas" if req is not None
-                    and req.kernel.is_pallas else "jnp",
+                    kernel="pallas" if pallas else "jnp",
                     segments=batch.num_segments,
                     batch_size=req.batch_size if req is not None else 0,
                     mesh=f"{self.mesh.shape[SEG_AXIS]}x"
-                         f"{self.mesh.shape[DOC_AXIS]}")
+                         f"{self.mesh.shape[DOC_AXIS]}",
+                    **took)
 
         # arrays were staged above: re-measure the resident and enforce the
         # budget now rather than waiting for end_query

@@ -654,7 +654,9 @@ class ServerInstance:
         reason each shape declines with — ``pallas_shape_blocked`` for
         runtime lowering failures, ``pallas_preflight_<rule>`` for
         preflight-seeded predictions) plus the last preflight verdict
-        table run against this executor (tools/preflight.py). A chip
+        table run against this executor (tools/preflight.py), and
+        ``launches``: fused-scan launches by the accumulate they took
+        (``single``: at most 128 groups; ``two_level``: more). A chip
         that fell over mid-round keeps its lessons visible here — and,
         with ``pinot.server.query.pallas.blocklist.path`` set, across
         restarts."""
@@ -669,6 +671,8 @@ class ServerInstance:
         verdicts = getattr(self.executor, "preflight_verdicts", None)
         out["preflight"] = verdicts if verdicts is not None else {
             "run": False}
+        launches = getattr(self.executor, "pallas_launches", None)
+        out["launches"] = launches() if launches is not None else {}
         return out
 
     def memory_debug(self) -> Dict[str, Any]:

@@ -451,6 +451,20 @@ def _check_gpad(mod: Module, env: Dict[str, Any],
                 and isinstance(st.targets[0], ast.Name)
                 and st.targets[0].id == name]
 
+    def padded(src: ast.expr, scope: Dict[str, Any]) -> bool:
+        return _lane_ok(_eval_int(src, scope)) or _is_ceil_chunk(src, env)
+
+    def pads_on_return(src: ast.expr) -> bool:
+        """A call of one of this module's functions whose every return is
+        lane-padded (``padded_groups``)."""
+        if not (isinstance(src, ast.Call) and isinstance(src.func, ast.Name)):
+            return False
+        defs = [d for d in mod.tree.body if isinstance(d, ast.FunctionDef)
+                and d.name == src.func.id]
+        rets = [r.value for d in defs for r in ast.walk(d)
+                if isinstance(r, ast.Return) and r.value is not None]
+        return bool(rets) and all(padded(r, env) for r in rets)
+
     for fn in ast.walk(mod.tree):
         if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
@@ -469,8 +483,7 @@ def _check_gpad(mod: Module, env: Dict[str, Any],
             elif isinstance(expr, ast.Name):
                 srcs = assigns_of(fn, expr.id)
                 ok = bool(srcs) and all(
-                    _lane_ok(_eval_int(s, _func_env(fn, env)))
-                    or _is_ceil_chunk(s, env)
+                    padded(s, _func_env(fn, env)) or pads_on_return(s)
                     for s in srcs)
             if not ok:
                 findings.append(Finding(
@@ -478,7 +491,7 @@ def _check_gpad(mod: Module, env: Dict[str, Any],
                     f"gpad:{ast.unparse(expr)[:40]}",
                     f"num_groups_padded={ast.unparse(expr)} is not "
                     f"provably lane-padded (ceil to _G_CHUNK); the "
-                    f"one-hot chunk loop and out blocks assume %128"))
+                    f"two-level accumulate and out blocks assume %128"))
 
 
 # -- SMEM cap vs the config table (smem-cap) --------------------------------

@@ -17,8 +17,9 @@ the JAX/Pallas references), so this module verifies them ahead of time:
   for.
 - :func:`preflight_spec` — one concrete :class:`PallasSpec` against the
   model: mirrors ``build_kernel``'s exact BlockSpec/accumulator layout
-  (via ``_row_layout``) and sizes every VMEM block, the matmul row stack
-  and one-hot temporaries, and the SMEM param vector. Emits a verdict
+  (via ``_row_layout``) and sizes every VMEM block, the matmul row stack,
+  the one-hot and the expanded row block of the two-level accumulate, and
+  the SMEM param vector. Emits a verdict
   row with the first violated rule's ``pallas_preflight_<rule>`` code
   (registered in ``tracing.PALLAS_PREFLIGHT_REASONS``).
 - :func:`extract_query_spec` — a SegmentPlan to its concrete kernel spec
@@ -81,7 +82,7 @@ RULES: Tuple[_Rule, ...] = (
           "fit the scalar-memory budget"),
     _Rule("pallas_preflight_vmem_budget",
           "per-step VMEM working set (blocks + matmul row stack + "
-          "one-hot temporaries) fits the ~16 MB/core budget"),
+          "one-hot + expanded row block) fits the ~16 MB/core budget"),
 )
 
 
@@ -165,14 +166,17 @@ class Verdict:
 
 def _vmem_estimate(spec, model: LoweringModel) -> int:
     """Per-grid-step VMEM bytes: every BlockSpec block build_kernel binds
-    plus the kernel's large intermediates (matmul row stack, one-hot /
-    iota / min-max select buffers). Mirrors pallas_kernels.build_kernel's
-    layout via the same ``_row_layout``."""
-    from pinot_tpu.engine.pallas_kernels import _row_layout
+    plus the kernel's large intermediates (matmul row stack, the one-hot
+    of the key's low 7 bits, one expanded row block of the two-level
+    accumulate, min-max select buffers). Mirrors
+    pallas_kernels.build_kernel's layout via the same ``_row_layout`` and
+    ``accumulate_rows``."""
+    from pinot_tpu.engine.pallas_kernels import _row_layout, accumulate_rows
 
     T = PALLAS_TILE
     _fsum, isum, mm_row, Mf, Mi, Mm = _row_layout(spec)
     G = spec.num_groups_padded
+    H, Hp, rows_per_dot = accumulate_rows(G)
     n_values = len(spec.value_is_int)
     vlimbs = spec.value_limbs or (0,) * n_values
     n_value_refs = sum(l if l else 1 for l in vlimbs)
@@ -186,15 +190,21 @@ def _vmem_estimate(spec, model: LoweringModel) -> int:
     total += n_value_refs * T * 4
     # unpacked dictId planes [RT, 128] i32 per packed column
     total += len(spec.packed_bits) * T * 4
-    # output accumulators (whole arrays resident across the grid)
-    total += (Mf + Mi + Mm) * G * 4
+    # output accumulators (whole arrays resident across the grid): sum and
+    # count rows as [rows * Hp, 128], min/max rows as [Mm, G]
+    total += (Mf + Mi) * Hp * model.lane * 4 + Mm * G * 4
     total += model.sublane_f32 * model.lane * 4  # out_seg block (1, 8, 128)
-    # matmul row stack R [M_mat, RT, 128] f32
+    # matmul row stack R [M_mat, T] f32
     n_limb_rows = sum(L for (_s, L) in isum.values())
     m_mat = (Mf // 2) + 1 + n_limb_rows
     total += m_mat * T * 4
-    # one-hot chunk buffers: g_iota + oh [RT, 128, 128] f32
+    # the tile's one-hot: lane iota + oh_lo [RT, 128, 128]
     total += 2 * T * model.lane * 4
+    if H > 1:
+        # the hi-select mask [Hp, T] and one expanded LHS block with its
+        # [rows, 128] partial (at most _EXPAND_ROWS rows, whole R rows)
+        rows = min(m_mat, rows_per_dot) * Hp
+        total += Hp * T * 4 + rows * (T + model.lane) * 4
     # min/max select buffers (eq + v3) when mm rows exist
     if mm_row:
         total += 2 * T * model.lane * 4
@@ -382,6 +392,12 @@ def fuzz_specs() -> List[Tuple[str, Any]]:
     # narrowed group ranges: the dense rung's spectrum, then over/unpadded
     for g in (128, 1024, 8192):
         shapes.append((f"groups{g}", _mk_spec(groups=g)))
+    # six matmul rows at the full fan-out: the two-level accumulate's
+    # expanded row stack (6 x 64 rows) runs in blocks of _EXPAND_ROWS
+    shapes.append(("rows6_groups8192", _mk_spec(
+        groups=8192, aggs=(("sum", ("v", 0), None), ("avg", ("v", 1), None),
+                           ("count", None, None), ("sum", ("v", 2), 3)),
+        value_is_int=(False, False, True), value_limbs=(0, 0, 0))))
     shapes.append(("groups16384_over", _mk_spec(groups=16384)))
     shapes.append(("groups8100_unpadded", _mk_spec(groups=8100)))
 

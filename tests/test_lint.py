@@ -1386,13 +1386,27 @@ def test_device_swapped_blockspec_dim(tmp_path):
     is no longer provably %128."""
     src = _real_src("engine/pallas_kernels.py")
     bad = src.replace(
-        "pl.BlockSpec((Mf, G), lambda s, t: (0, 0), "
+        "pl.BlockSpec((Mm, G), lambda s, t: (0, 0), "
         "memory_space=pltpu.VMEM),",
-        "pl.BlockSpec((G, Mf), lambda s, t: (0, 0), "
+        "pl.BlockSpec((G, Mm), lambda s, t: (0, 0), "
         "memory_space=pltpu.VMEM),")
     assert bad != src, "out-spec line moved; update the fixture"
     hits = _device_scratch(tmp_path, "pallas_kernels.py", bad)
     assert len(hits) == 1 and "lane dim" in hits[0].message, \
+        [f.render() for f in hits]
+
+
+def test_device_unpadded_group_count_through_helper(tmp_path):
+    """Seeded mutation: ``padded_groups`` (whose return reaches
+    ``num_groups_padded`` in extract_plan) stops rounding up to whole
+    128-lane chunks — the gpad rule follows the call into the helper."""
+    src = _real_src("engine/pallas_kernels.py")
+    bad = src.replace(
+        "    return -(-num_groups // _G_CHUNK) * _G_CHUNK\n",
+        "    return num_groups\n")
+    assert bad != src, "padded_groups moved; update the fixture"
+    hits = _device_scratch(tmp_path, "pallas_kernels.py", bad)
+    assert len(hits) == 1 and "lane-padded" in hits[0].message, \
         [f.render() for f in hits]
 
 

@@ -356,3 +356,149 @@ def test_sharded_lowering_failure_blocks_only_that_shape(setup, host_exec,
     got2, _ = ex.execute(compile_query(bad_sql), segs)
     assert got2.rows == want.rows
     assert len(ex._pallas_sharded) == 1
+
+
+# --------------------------------------------------------------------------
+# build_kernel against a numpy oracle: the two-level accumulate at every
+# shape of the group axis (H = G / 128 = 1, 2, 3, 32, 64)
+# --------------------------------------------------------------------------
+
+_KEY_OFFSET = 50      # the narrowed range's base: masked docs fall below it
+_SEG_DOCS = (2 * PALLAS_TILE - 1000, PALLAS_TILE + 17)   # partial last tiles
+
+
+def _pack_planar(ids, bits):
+    """[S, TPS, T] dictIds -> [S, TPS, W/128, 128] u32 planar words (value
+    j of a tile in word j % W at bit slot (j // W) * bits)."""
+    S, TPS, T = ids.shape
+    K = 32 // bits
+    W = T // K
+    planes = ids.reshape(S, TPS, K, W).astype(np.uint32)
+    words = np.zeros((S, TPS, W), dtype=np.uint32)
+    for k in range(K):
+        words |= planes[:, :, k, :] << np.uint32(k * bits)
+    return words.reshape(S, TPS, W // 128, 128)
+
+
+def _accumulate_case(G, case):
+    """(spec, params, cols, oracle rows) of one exactness case: a 16-bit
+    group column whose dictIds overshoot the narrowed range [50, 50 + Gn)
+    on both sides (the filter masks those docs; their keys are negative or
+    >= G), an 8-bit filter column, two segments with partial last tiles."""
+    from pinot_tpu.engine.pallas_kernels import PallasSpec
+
+    S, TPS, T = 2, 2, PALLAS_TILE
+    Gn = G - 7                      # real groups: the pad is not empty
+    rng = np.random.default_rng(G * 31 + len(case))
+    gid = rng.integers(0, G + 100, (S, TPS, T))
+    fid = rng.integers(0, 256, (S, TPS, T))
+    doc = np.arange(TPS * T).reshape(TPS, T)
+    valid = np.stack([doc < n for n in _SEG_DOCS])
+    mask = (valid & (gid >= _KEY_OFFSET) & (gid <= _KEY_OFFSET + Gn - 1)
+            & (fid <= 199))
+    keys = (gid - _KEY_OFFSET)[mask]
+    assert (gid - _KEY_OFFSET)[~mask].min() < 0 \
+        and (gid - _KEY_OFFSET)[~mask].max() >= G
+
+    def by_group(v):
+        out = np.zeros(G, dtype=v.dtype)
+        np.add.at(out, keys, v[mask])
+        return out
+
+    if case == "int":
+        # count + an int sum whose values take the signed top limb of 3
+        aggs = (("count", None, None), ("sum", ("v", 0), 3))
+        v = rng.integers(-2_000_000_000, 2_000_000_000, (S, TPS, T))
+        values, is_int, limbs = [v.astype(np.int32)], (True,), (0,)
+        want = {"count": by_group(np.ones_like(v)), "isum": by_group(v)}
+    elif case == "float":
+        aggs = (("sum", ("v", 0), None),)
+        v = rng.uniform(1.0, 1.0e4, (S, TPS, T)).astype(np.float32)
+        values, is_int, limbs = [v], (False,), (0,)
+        want = {"count": by_group(np.ones(v.shape, dtype=np.int64)),
+                "fsum": by_group(v.astype(np.float64))}
+    else:                           # an i64 column as 4 pre-split planes
+        aggs = (("sum", ("v64", 0), 4),)
+        v = rng.integers(-(1 << 46), 1 << 46, (S, TPS, T))
+        values = [((v >> (12 * k)) & 0xFFF if k < 3 else v >> 36)
+                  .astype(np.int32) for k in range(4)]
+        is_int, limbs = (True,), (4,)
+        want = {"count": by_group(np.ones_like(v)), "isum": by_group(v)}
+
+    spec = PallasSpec(
+        num_segs=S, tiles_per_seg=TPS, packed_bits=(16, 8),
+        filter_tree=("and", (("iv", 0, 0), ("iv", 1, 1))), n_slots=2,
+        group_idx=(0,), group_strides=(1,), group_key_offset=_KEY_OFFSET,
+        num_groups_padded=G, aggs=aggs, value_is_int=is_int,
+        value_limbs=limbs, interpret=True)
+    params = np.asarray([_KEY_OFFSET, _KEY_OFFSET + Gn - 1, 0, 199,
+                         *_SEG_DOCS, 0], dtype=np.int32)
+    cols = [_pack_planar(gid, 16), _pack_planar(fid, 8)] + [
+        x.reshape(S, TPS, T // 128, 128) for x in values]
+    want["seg"] = mask.reshape(S, -1).sum(axis=1)
+    return spec, params, cols, want
+
+
+@pytest.mark.parametrize("case", ["int", "float", "v64"])
+@pytest.mark.parametrize("G", [128, 256, 384, 4096, 8192])
+def test_build_kernel_accumulate_is_exact(G, case):
+    """Counts and int sums equal the oracle bit for bit at every group
+    shape (single chunk, two-level with a padded hi axis, two-level at the
+    Q2.1 shape and at MAX_PALLAS_GROUPS); float sums within the file's
+    tolerance. Masked docs whose narrowed key is negative or >= G match
+    nothing; the carry chain runs across tiles and segments."""
+    import jax
+
+    from pinot_tpu.engine.pallas_kernels import _row_layout, build_kernel
+
+    spec, params, cols, want = _accumulate_case(G, case)
+    out_f, out_i, _mm, out_seg = jax.jit(build_kernel(spec))(params, *cols)
+    out_f, out_i = np.asarray(out_f), np.asarray(out_i)
+    fsum_row, isum_row, _, Mf, Mi, _ = _row_layout(spec)
+    assert out_f.shape == (Mf, G) and out_i.shape == (Mi, G)
+    np.testing.assert_array_equal(out_i[0], want["count"])
+    np.testing.assert_array_equal(np.asarray(out_seg).sum(axis=1),
+                                  want["seg"])
+    assert want["count"][G - 7:].sum() == 0 and want["count"].sum() > 0
+    for _vexpr, (start, L) in isum_row.items():
+        rows = out_i[start:start + L + 2].astype(np.int64)
+        # normalized carry chain: every limb row back inside 12 bits
+        assert rows[:L + 1].min() >= 0 and rows[:L + 1].max() < (1 << 12)
+        got = sum(int(1 << (12 * k)) * rows[k] for k in range(L + 2))
+        np.testing.assert_array_equal(got, want["isum"])
+    for _vexpr, r in fsum_row.items():
+        got = out_f[r].astype(np.float64) + out_f[r + 1].astype(np.float64)
+        np.testing.assert_allclose(got, want["fsum"], rtol=1e-5, atol=1e-6)
+
+
+def test_pallas_launch_counter_loses_no_update_under_threads():
+    """``/debug/pallas`` ``launches`` is bumped from every query thread:
+    more threads than cores with a short switch interval must lose none."""
+    import os
+    import sys
+    import threading
+
+    ex = ServerQueryExecutor(use_device=False)
+    scalar = (("true",), (("count", False, None),), (), 1, None)
+    grouped = (("true",), (("count", False, None),), (("gdict", "c"),),
+               4000, None)
+    n_threads, each = 2 * (os.cpu_count() or 4), 500
+
+    def work():
+        for _ in range(each):
+            ex._note_pallas_launch(scalar)
+            ex._note_pallas_launch(grouped)
+
+    threads = [threading.Thread(target=work) for _ in range(n_threads)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert ex.pallas_launches() == {"single": n_threads * each,
+                                    "two_level": n_threads * each}

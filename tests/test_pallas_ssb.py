@@ -161,6 +161,65 @@ def test_sharded_all_13_parity_and_zero_declines(ssb_segs, ctxs):
     assert len(dev._pallas_sharded) > 0
 
 
+@pytest.mark.parametrize("qid, accumulate", [
+    ("Q1.1", "single"), ("Q2.1", "two_level")])
+def test_sharded_span_and_counter_say_which_accumulate(ssb_segs, qid,
+                                                       accumulate):
+    """A forced scan through the sharded executor: the ``ShardedCombine``
+    span carries the kernel's ``groups`` and the ``accumulate`` it took,
+    ``GET /debug/pallas`` counts the launch under that name, and a query
+    the jnp combine served counts under neither. (Q1.1 over every year:
+    its own year leaves one segment, which the per-segment ladder takes.)"""
+    from types import SimpleNamespace
+
+    from pinot_tpu.common.tracing import flatten_spans
+    from pinot_tpu.parallel import ShardedQueryExecutor
+    from pinot_tpu.server.server import ServerInstance
+
+    def launches(ex):
+        return ServerInstance.pallas_debug(
+            SimpleNamespace(executor=ex))["launches"]
+
+    dev = ShardedQueryExecutor(use_pallas=True)
+    assert launches(dev) == {"single": 0, "two_level": 0}
+    sql = (ssb.QUERIES[qid].replace("d_year = 1993", "d_year >= 1992")
+           + " LIMIT 100000 OPTION(useStarTree=false, trace=true)")
+    _got, stats = dev.execute(compile_query(sql), ssb_segs)
+    spans = {e["operator"]: e for e in flatten_spans(stats.spans)}
+    combine = spans["ShardedCombine"]
+    (spec, _plan_spec), = dev._pallas_sharded     # the one kernel it built
+    assert (spec.num_groups_padded > 128) is (accumulate == "two_level")
+    assert (combine["kernel"], combine["groups"], combine["accumulate"]) \
+        == ("pallas", spec.num_groups_padded, accumulate)
+    want = {"single": 0, "two_level": 0, accumulate: 1}
+    assert launches(dev) == want
+
+    jnp_only = ShardedQueryExecutor(use_pallas=False)
+    _got, stats = jnp_only.execute(compile_query(sql), ssb_segs)
+    combine = {e["operator"]: e
+               for e in flatten_spans(stats.spans)}["ShardedCombine"]
+    assert combine["kernel"] == "jnp" and "accumulate" not in combine
+    assert launches(jnp_only) == {"single": 0, "two_level": 0}
+
+
+def test_per_segment_kernel_span_says_which_accumulate(ssb_segs):
+    """The per-segment ladder's Pallas ``Kernel`` span (what a sharded
+    query pruned to one segment takes) carries the same two attributes,
+    and its launch is counted."""
+    from pinot_tpu.common.tracing import flatten_spans
+
+    ex = ServerQueryExecutor(use_device=True, use_pallas=True)
+    sql = (ssb.QUERIES["Q2.1"]
+           + " LIMIT 100000 OPTION(useStarTree=false, trace=true)")
+    _got, stats = ex.execute(compile_query(sql), ssb_segs[:1])
+    kernels = [e for e in flatten_spans(stats.spans)
+               if e["operator"] == "Kernel" and e.get("kernel") == "pallas"]
+    assert kernels and all(
+        (k["groups"], k["accumulate"]) == (4096, "two_level")
+        for k in kernels)
+    assert ex.pallas_launches() == {"single": 0, "two_level": len(kernels)}
+
+
 def test_narrow_declines_when_probe_cannot_shrink(tmp_path):
     """Adversarial shape: unfiltered high-card group columns keep their
     full ranges under the probe, so the narrowed product still exceeds

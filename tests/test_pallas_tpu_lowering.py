@@ -4,9 +4,14 @@
 ("tpu",))`` runs the Pallas -> Mosaic lowering with jax_enable_x64 on, as
 the executors run it: the check that caught the kernel's two refusals (a
 ``dot_general`` contracting two dims, 64-bit scalars leaking into the
-body) before any chip was asked. It guards the lowering, not libtpu's
-verdict — that is ``chip_smoke.py``'s job.
+body) before any chip was asked. The ``compiles_for_v5e`` tests go one
+step on: libtpu compiles the two-level accumulate at its widest shapes
+for a v5e that is described and not attached (VMEM, tiling and slices are
+judged there, not in the lowering). Neither runs anything:
+``chip_smoke.py`` and the benchmark do.
 """
+
+import os
 
 from dataclasses import replace
 
@@ -69,6 +74,44 @@ def _spec_of(qid, staged):
 @pytest.mark.parametrize("qid", sorted(ssb.QUERIES))
 def test_ssb_flight_lowers_for_tpu(staged, qid):
     _lower_for_tpu(_spec_of(qid, staged))
+
+
+@pytest.mark.parametrize("groups", [256, 384, 4096, 8192])
+def test_two_level_accumulate_lowers_for_tpu(staged, groups):
+    """The Q2.1 shape at every form of the hi axis: two and three chunks
+    (padded to eight sublane rows), its own 32, and MAX_PALLAS_GROUPS."""
+    _lower_for_tpu(replace(_spec_of("Q2.1", staged),
+                           num_groups_padded=groups))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A v5e chip that is described and not attached; libtpu is loaded by
+    this file's worker alone, and only once a test here has started."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("groups", [4096, 8192])
+def test_two_level_accumulate_compiles_for_v5e(staged, one_chip, groups):
+    """Mosaic's verdict on the Q2.1 program at the benchmark's grid (8
+    segments of 733 tiles) and at MAX_PALLAS_GROUPS, where the expanded
+    row block and the accumulators are largest."""
+    spec = replace(_spec_of("Q2.1", staged), interpret=False, num_segs=8,
+                   tiles_per_seg=733, num_groups_padded=groups)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in _abstract_args(spec)]
+    assert preflight.preflight_spec(spec).ok
+    jax.jit(build_kernel(spec)).lower(*args).compile()
 
 
 def test_sharded_spec_lowers_for_tpu(staged):

@@ -143,6 +143,36 @@ def test_fuzz_grid_rules_exact(table):
     assert set(EXPECTED_FUZZ_FAILS.values()) == PALLAS_PREFLIGHT_REASONS
 
 
+@pytest.mark.parametrize("label, fits", [
+    ("groups128", True), ("groups1024", True), ("groups8192", True),
+    ("rows6_groups8192", True), ("wide96_vmem_over", False)])
+def test_vmem_estimate_of_the_two_level_working_set(label, fits):
+    """The model's working set follows build_kernel's: one [T, 128]
+    one-hot a tile whatever the group count, plus, above 128 groups, the
+    hi-select mask and ONE expanded row block. The widest plans stay
+    inside the budget at 8192 groups; 96 aggregations there do not."""
+    from pinot_tpu.engine.pallas_kernels import _row_layout, accumulate_rows
+
+    model = preflight.TPU_V5E
+    spec = dict(preflight.fuzz_specs())[label]
+    got = preflight._vmem_estimate(spec, model)
+    assert (got <= model.vmem_budget) is fits
+    # the same plan at 128 groups: what the group axis adds, to the byte
+    T, lane = PALLAS_TILE, model.lane
+    G = spec.num_groups_padded
+    H, Hp, rows_per_dot = accumulate_rows(G)
+    _f, isum, _mm, Mf, Mi, Mm = _row_layout(spec)
+    m_mat = Mf // 2 + 1 + sum(L for _s, L in isum.values())
+    added = (Mf + Mi) * (Hp - 1) * lane * 4 + Mm * (G - lane) * 4
+    if H > 1:
+        block = min(m_mat, rows_per_dot) * Hp
+        assert block <= max(256, Hp)     # two MXU heights, or one R row
+        added += Hp * T * 4 + block * (T + lane) * 4
+    base = preflight._vmem_estimate(
+        dataclasses.replace(spec, num_groups_padded=lane), model)
+    assert got - base == added
+
+
 def test_fuzz_grid_covers_the_announced_axes():
     """The grid actually spans the axes it claims: limb counts, ivs run
     counts, group ranges, packed widths, remainder tiles."""

@@ -84,25 +84,11 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         # interleaved launches from two threads deadlock the runtime. The
         # old process-global _combine_lock is gone — every launch now flows
         # through the per-mesh LaunchScheduler, whose single dispatcher
-        # thread totally orders device programs AND coalesces same-kernel
-        # requests into one micro-batched launch (parallel/launcher.py).
+        # thread totally orders device programs and lets requests with the
+        # same device params share one launch (parallel/launcher.py).
         from pinot_tpu.parallel.launcher import launcher_for_mesh
-        from pinot_tpu.spi.config import CommonConstants, PinotConfiguration
 
-        cfg = self.config if self.config is not None else PinotConfiguration()
-        self._launch_max_batch = max(1, cfg.get_int(
-            CommonConstants.LAUNCH_MAX_BATCH_KEY,
-            CommonConstants.DEFAULT_LAUNCH_MAX_BATCH))
         self.launcher = launcher_for_mesh(self.mesh)
-        # adaptive micro-batch window knobs ride the shared per-mesh
-        # dispatcher (last executor to configure wins — one serving config
-        # per process in practice)
-        self.launcher.set_window(
-            max_ms=cfg.get_float(CommonConstants.LAUNCH_WINDOW_MS_KEY,
-                                 CommonConstants.DEFAULT_LAUNCH_WINDOW_MS),
-            hot_ms=cfg.get_float(
-                CommonConstants.LAUNCH_WINDOW_HOT_MS_KEY,
-                CommonConstants.DEFAULT_LAUNCH_WINDOW_HOT_MS))
         # PallasSpec -> jitted sharded fused kernel (literal params stay
         # runtime args, so same-shape queries share the compile)
         self._pallas_sharded: Dict = {}
@@ -562,8 +548,8 @@ class ShardedQueryExecutor(ServerQueryExecutor):
                 self._param_cache.popitem(last=False)
 
     def _launch_kernel(self, launch_key: Tuple, make_call, is_pallas: bool):
-        """Get-or-create the launch-tier entry: the coalescable
-        LaunchKernel every same-shape query (any literals) shares."""
+        """Get-or-create the launch-tier entry: the LaunchKernel every
+        same-shape query (any literals) shares."""
         from pinot_tpu.parallel.launcher import LaunchKernel
 
         with self._cache_lock:
@@ -576,8 +562,7 @@ class ShardedQueryExecutor(ServerQueryExecutor):
             kernel = self._launch_cache.get(launch_key)
             if kernel is None:
                 kernel = LaunchKernel(launch_key, call,
-                                      is_pallas=is_pallas,
-                                      max_batch=self._launch_max_batch)
+                                      is_pallas=is_pallas)
                 self._launch_cache[launch_key] = kernel
                 if len(self._launch_cache) > self._launch_cache_cap:
                     self._launch_cache.popitem(last=False)

@@ -581,21 +581,13 @@ class ServerInstance:
 
     def scheduler_debug(self) -> Dict[str, Any]:
         """Scheduler-tier state for ``GET /debug/scheduler``: dispatch
-        policy + queue depth, admission bounds/counters, the launch
-        dispatcher's adaptive-window state, and the per-segment kernel
-        single-flight counters — the millions-of-users ops view."""
+        policy + queue depth, admission bounds/counters, and the
+        per-segment kernel single-flight counters — the millions-of-users
+        ops view."""
         out: Dict[str, Any] = {"scheduler": self.scheduler.stats_snapshot()}
         admission = getattr(self.executor, "admission", None)
         if admission is not None:
             out["admission"] = admission.snapshot()
-        launcher = getattr(self.executor, "launcher", None)
-        if launcher is not None:
-            snap = launcher.snapshot()
-            out["launchWindow"] = {
-                k: snap.get(k) for k in
-                ("windowMaxMs", "windowHotMs", "arrivalEwmaMs",
-                 "windowWaits", "windowGathered", "windowLastMs",
-                 "queued")}
         flight = getattr(self.executor, "_kernel_flight", None)
         if flight is not None:
             out["kernelFlight"] = flight.snapshot()

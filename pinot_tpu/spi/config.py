@@ -201,20 +201,6 @@ class CommonConstants:
     # not forget its lowering failures on restart.
     PALLAS_BLOCKLIST_PATH_KEY = "pinot.server.query.pallas.blocklist.path"
     WORKER_THREADS_KEY = "pinot.server.query.worker.threads"
-    # Launch coalescing (parallel/launcher.py): max requests one vmapped
-    # combine launch may carry. 1 disables batching (dedup + single-thread
-    # dispatch ordering still apply).
-    LAUNCH_MAX_BATCH_KEY = "pinot.server.query.launch.max.batch"
-    DEFAULT_LAUNCH_MAX_BATCH = 8
-    # Adaptive micro-batch window (parallel/launcher.py): when the launch
-    # queue is hot (EWMA inter-arrival <= the hot threshold) the dispatcher
-    # holds up to this long for stragglers so vmap groups get bigger
-    # exactly when it pays; idle traffic pays zero added latency. <= 0
-    # disables the hold.
-    LAUNCH_WINDOW_MS_KEY = "pinot.server.query.launch.window.ms"
-    DEFAULT_LAUNCH_WINDOW_MS = 1.0
-    LAUNCH_WINDOW_HOT_MS_KEY = "pinot.server.query.launch.window.hot.ms"
-    DEFAULT_LAUNCH_WINDOW_HOT_MS = 2.0
     # Scheduler policy (server/scheduler.py make_scheduler): fcfs |
     # tokenbucket | priority | sewf (shortest-expected-work-first with an
     # age-based anti-starvation boost — the default).

@@ -252,16 +252,12 @@ class ServerMeter:
     STAGING_PROMOTIONS = "staging_promotions_total"
     STAGING_HOST_DROPS = "staging_host_drops_total"
     STAGING_SLICED = "staging_sliced_queries_total"
-    # launch coalescing (parallel/launcher.py; gauges launch_queue_depth /
+    # launch dispatcher (parallel/launcher.py; gauges launch_queue_depth /
     # launch_max_batch_size ride the same registry)
     LAUNCH_REQUESTS = "combine_launch_requests_total"
     LAUNCHES = "combine_launches_total"
     LAUNCHES_COALESCED = "combine_launches_coalesced_total"
     LAUNCHES_SAVED = "combine_launches_saved_total"
-    # adaptive micro-batch window (parallel/launcher.py): dispatch-loop
-    # holds taken and straggler requests gathered during a held window
-    LAUNCH_WINDOW_WAITS = "launch_window_waits_total"
-    LAUNCH_WINDOW_GATHERED = "launch_window_gathered_total"
     # admission gate (server/admission.py)
     ADMISSION_ADMITTED = "admission_admitted_total"
     ADMISSION_REJECTED = "admission_rejected_total"

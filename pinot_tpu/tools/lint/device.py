@@ -792,8 +792,8 @@ def _check_narrow(mod: Module, findings: List[Finding]) -> None:
             findings.append(Finding(
                 "device", mod.relpath, st.lineno, "pow2-narrow:num_groups",
                 "narrowed num_groups does not flow through _next_pow2 — "
-                "the dense rung and the vmapped cache key assume pow2 "
-                "padding survives narrowing"))
+                "the dense rung assumes pow2 padding survives "
+                "narrowing"))
         if not (isinstance(cap, ast.Name) and cap.id in spec_names):
             findings.append(Finding(
                 "device", mod.relpath, st.lineno, "pow2-narrow:capacity",

@@ -33,9 +33,7 @@ agree with it:
   offsets — only the ``strat == "gdict"`` branch (dictIds are i32 by
   construction) may cast the base directly.
 - **pow2-padding consistency**: every ``_next_pow2`` definition in the
-  package must be structurally identical, and the launcher's vmapped
-  kernel cache must key on a ``_next_pow2``-padded size (unbounded batch
-  sizes would mint unbounded compile variants).
+  package must be structurally identical.
 - **cursor tails**: a function that builds a ``_ParamCursor`` and takes
   from it must either call ``.finish()`` (the runtime mirror asserting
   full consumption) or hand the cursor to another function.
@@ -533,40 +531,9 @@ def _check_pow2(ctx: LintContext, findings):
                     "protocol", mod.relpath, node.lineno,
                     "_next_pow2:drift",
                     f"_next_pow2 in {mod.relpath} differs from "
-                    f"{defs[0][0].relpath} — plan padding and launcher "
-                    f"batch padding must round identically or vmapped "
-                    f"coalescing misaligns"))
-    # the vmapped kernel cache must key on pow2-padded sizes
-    for mod in ctx.modules:
-        for node in ast.walk(mod.tree):
-            if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                continue
-            touches = any(
-                (isinstance(s, ast.Subscript)
-                 and isinstance(s.value, ast.Attribute)
-                 and s.value.attr == "_vmapped")
-                or (isinstance(s, ast.Call)
-                    and isinstance(s.func, ast.Attribute)
-                    and s.func.attr in ("get", "setdefault")
-                    and isinstance(s.func.value, ast.Attribute)
-                    and s.func.value.attr == "_vmapped")
-                for s in ast.walk(node))
-            if not touches:
-                continue
-            calls_pow2 = any(
-                isinstance(s, ast.Call) and (
-                    (isinstance(s.func, ast.Name)
-                     and s.func.id == "_next_pow2")
-                    or (isinstance(s.func, ast.Attribute)
-                        and s.func.attr == "_next_pow2"))
-                for s in ast.walk(node))
-            if not calls_pow2:
-                findings.append(Finding(
-                    "protocol", mod.relpath, node.lineno,
-                    f"{node.name}:vmapped-pow2",
-                    f"{node.name}() keys the _vmapped batch cache without "
-                    f"_next_pow2 padding — unpadded sizes mint unbounded "
-                    f"compile variants"))
+                    f"{defs[0][0].relpath} — group-count padding in the "
+                    f"plan and in the device reduce must round "
+                    f"identically"))
 
 
 # -- cursor tails -----------------------------------------------------------

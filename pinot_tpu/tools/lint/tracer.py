@@ -4,7 +4,7 @@ A function traced by jax executes ONCE per compile-cache entry; host-side
 effects inside it either bake stale values into the compiled program
 (``time.*``, ``random.*``, global reads) or break under concurrent
 tracing (``threading.*``, global-dict mutation) — the class of bug that
-turns a coalesced vmapped launch nondeterministic.
+turns a launch nondeterministic.
 
 Roots: first arguments of ``jax.jit`` / ``jax.vmap`` / ``shard_map`` /
 ``pl.pallas_call`` calls and ``@jax.jit``-decorated defs. Reachability is

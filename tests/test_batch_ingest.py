@@ -29,6 +29,10 @@ REF_EXAMPLE = ("/root/reference/pinot-tools/src/main/resources/examples/"
 REF_TEAMS_CSV = ("/root/reference/pinot-core/src/test/resources/data/"
                  "dimBaseballTeams.csv")
 
+needs_reference = pytest.mark.skipif(
+    not os.path.isdir("/root/reference"),
+    reason="reads the Apache Pinot checkout at /root/reference, absent here")
+
 
 def _synth_baseball_csv(path: str, n: int, seed: int) -> pd.DataFrame:
     """Synthesized rawdata for the reference's baseballStats schema (the
@@ -52,6 +56,7 @@ def _synth_baseball_csv(path: str, n: int, seed: int) -> pd.DataFrame:
     return df
 
 
+@needs_reference
 def test_reference_jobspec_parses():
     spec = SegmentGenerationJobSpec.from_yaml(
         f"{REF_EXAMPLE}/ingestionJobSpec.yaml")
@@ -61,6 +66,7 @@ def test_reference_jobspec_parses():
     assert spec.data_format == "csv"
 
 
+@needs_reference
 def test_baseball_quickstart_e2e(tmp_path):
     """The SURVEY.md minimum end-to-end slice: reference configs -> CSV ->
     job runner -> embedded cluster -> SQL answers match pandas."""
@@ -122,6 +128,7 @@ def test_baseball_quickstart_e2e(tmp_path):
         cluster.shutdown()
 
 
+@needs_reference
 def test_real_reference_csv(tmp_path):
     """Ingest an actual CSV shipped in the reference checkout."""
     schema = Schema.from_dict({
@@ -184,6 +191,7 @@ def test_parquet_reader(tmp_path):
     assert list(cols["city"]) == ["sf", "nyc"]
 
 
+@needs_reference
 def test_cli_quickstart(tmp_path, capsys):
     """Quickstart subcommand over a reference-layout example dir."""
     from pinot_tpu.tools.admin import main
@@ -216,6 +224,7 @@ def test_cli_quickstart(tmp_path, capsys):
     assert resp["resultTable"]["rows"][0][0] == len(df)
 
 
+@needs_reference
 def test_cli_ingestion_job_command(tmp_path, capsys):
     """LaunchDataIngestionJob subcommand builds segments standalone."""
     from pinot_tpu.tools.admin import main

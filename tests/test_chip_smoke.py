@@ -33,12 +33,35 @@ def test_phases_at_toy_size_on_forced_cpu_mesh(tmp_path):
         assert queries[f"{qid}/scan"]["mesh"] == f"{n_dev}x1"
     # every device holds part of the sharded batch
     assert len(report["sharded_batch_bytes_per_device"]) == n_dev
-    # the burst met the dispatcher as a group; no batched variant is built,
-    # and the dispatcher builds none, so its members went one by one
-    launches = report["burst"]["launches"]
-    assert launches["unbuiltGroups"] > 0 and launches["maxBatchSize"] >= 2
-    assert launches["batchedRequests"] == 0
+    # every burst request passed the dispatcher once: launched, or riding
+    # an identical rider's launch
+    last = report["burst"]["last_round"]
+    assert last["requests"] == len(chip_smoke.BURST_QUANTITIES)
+    assert last["launches"] + last["launchesSaved"] == last["requests"]
     assert report["residency"]["counters"]["spills"] == 0
+
+
+@pytest.mark.parametrize("after, sent, fails", [
+    # eight distinct literals, one launch each
+    ({"requests": 108, "launches": 58, "launchesSaved": 7}, 8, None),
+    # three of the eight shared a rider's launch
+    ({"requests": 108, "launches": 55, "launchesSaved": 10}, 8, None),
+    # a request that never reached the dispatcher
+    ({"requests": 107, "launches": 57, "launchesSaved": 7}, 8,
+     "8 burst requests sent"),
+    # a rider neither launched nor counted as saved
+    ({"requests": 108, "launches": 57, "launchesSaved": 7}, 8,
+     "launches \\+ launchesSaved != requests"),
+])
+def test_burst_conservation_on_hand_made_counters(after, sent, fails):
+    before = {"requests": 100, "launches": 50, "launchesSaved": 7,
+              "queued": 0}
+    if fails is None:
+        delta = chip_smoke.burst_conservation(before, after, sent)
+        assert delta["requests"] == sent
+    else:
+        with pytest.raises(chip_smoke.SmokeFailure, match=fails):
+            chip_smoke.burst_conservation(before, after, sent)
 
 
 def test_wrong_platform_fails_at_the_device_line(tmp_path):

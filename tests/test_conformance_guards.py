@@ -142,33 +142,6 @@ class TestBrokerDeviceReduceConfig:
             h.shutdown()
 
 
-class TestBenchDecisionValidation:
-    """bench.py's runtime mirror of the lint `decisions` family: every
-    suite's decision histogram must parse against the reason registry."""
-
-    def test_registered_and_dynamic_reasons_pass(self):
-        import bench
-
-        ok = {tracing.decision_key("startree", "scan", "startree",
-                                   "tree3"): 2,
-              tracing.decision_key("routing", "pruned", "all_servers",
-                                   "time_prune"): 1}
-        bench._Worker._validate_decisions("ssb", ok)
-
-    def test_unregistered_reason_fails_loud(self, monkeypatch):
-        import bench
-
-        bad = {tracing.decision_key("startree", "scan", "startree",
-                                    "bogus_reason_zzz"): 1}
-        monkeypatch.delenv("BENCH_ALLOW_UNREGISTERED_REASON",
-                           raising=False)
-        with pytest.raises(AssertionError, match="bogus_reason_zzz"):
-            bench._Worker._validate_decisions("qps", bad)
-        # the bring-up escape downgrades to a log line
-        monkeypatch.setenv("BENCH_ALLOW_UNREGISTERED_REASON", "1")
-        bench._Worker._validate_decisions("qps", bad)
-
-
 class TestSloPrefixIsDeclared:
     def test_key_built_from_constant_parses(self):
         from pinot_tpu.common.telemetry import Telemetry

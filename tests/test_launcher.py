@@ -1,8 +1,9 @@
-"""Launch-coalescing tests: the micro-batched dispatcher must (a) return
+"""Launch-dispatcher tests: the one dispatcher thread must (a) return
 bit-identical results vs the serial path under concurrent mixed-shape load,
-(b) actually coalesce (batch size > 1) when requests pile up, and (c) never
-deadlock on the multi-device mesh — the original reason the old global
-combine lock existed. Plus the satellites that ride along: the
+(b) launch every distinct param set once, in arrival order, and let
+identical ones share a launch, (c) never overlap two launches, and (d)
+never deadlock on the multi-device mesh — the original reason the old
+global combine lock existed. Plus the satellites that ride along: the
 literal-normalized launch cache, the worker/runner pool config keys, the
 batch-column borrow path, and the QueryStats.launch wire."""
 
@@ -74,33 +75,37 @@ def setup(tmp_path_factory):
 
 
 # --------------------------------------------------------------------------
-# scheduler unit tests (fake kernels; deterministic coalescing via a
-# blocker request that pins the dispatcher while the batch piles up)
+# scheduler unit tests (fake kernels; deterministic groups via a blocker
+# request that pins the dispatcher while the group piles up)
 # --------------------------------------------------------------------------
 
-def _blocker():
-    """(kernel, release) whose single launch parks the dispatcher."""
+def _pin(sched):
+    """(request, release): a launch that parks the dispatcher. Returns once
+    the dispatcher is inside it, so that whatever is submitted next waits
+    in the queue and meets one drain."""
     gate = threading.Event()
+    entered = threading.Event()
 
     def call(params, num_docs):
+        entered.set()
         gate.wait(20)
         return params
 
-    return LaunchKernel(("blocker",), call, max_batch=1), gate
+    req = sched.submit(LaunchKernel(("blocker",), call), 0, 0)
+    assert entered.wait(30), "the dispatcher never picked the blocker up"
+    return req, gate
 
 
 def test_dedup_identical_params():
     sched = LaunchScheduler(name="t-dedup")
-    blocker, gate = _blocker()
     calls = []
 
     def counted(params, num_docs):
         calls.append(params)
         return ("out", params)
 
-    kern = LaunchKernel(("k1",), counted, max_batch=8)
-    kern.batchable = False  # isolate the dedup path from vmap
-    b = sched.submit(blocker, 0, 0)
+    kern = LaunchKernel(("k1",), counted)
+    b, gate = _pin(sched)
     params = ("p",)
     reqs = [sched.submit(kern, params, 7) for _ in range(3)]
     gate.set()
@@ -115,108 +120,119 @@ def test_dedup_identical_params():
     assert snap["coalescedLaunches"] >= 1
 
 
-def test_vmapped_batch_distinct_params():
-    import jax.numpy as jnp
-
-    sched = LaunchScheduler(name="t-batch")
-    blocker, gate = _blocker()
-    launches = []
+@pytest.mark.parametrize("n", [2, 3, 8])
+def test_distinct_params_each_launch_once_in_arrival_order(n):
+    """A same-kernel group of n distinct param sets queued behind a slow
+    launch: each rider gets its own answer from its own launch."""
+    sched = LaunchScheduler(name=f"t-distinct-{n}")
+    calls = []
 
     def call(params, num_docs):
-        launches.append(1)
-        return params * num_docs
+        calls.append(params)
+        return ("out", params, num_docs)
 
-    kern = LaunchKernel(("k2",), call, max_batch=8)
-    nd = jnp.int32(3)
-    # the dispatcher rides a batched variant, it never builds one: built
-    # here, off the serving path (three pad to the variant of four)
-    kern.run_many([jnp.float32(v) for v in (0.0, 7.0, 9.0)], nd)
-    launches.clear()
-    b = sched.submit(blocker, 0, 0)
-    reqs = [sched.submit(kern, jnp.float32(v), nd) for v in (1.0, 2.0, 5.0)]
+    kern = LaunchKernel(("kd",), call)
+    mark = sched.stats_snapshot()
+    b, gate = _pin(sched)
+    reqs = [sched.submit(kern, (f"p{i}",), 5) for i in range(n)]
+    gate.set()
+    assert b.result(30) == 0
+    assert [r.result(30) for r in reqs] == [("out", (f"p{i}",), 5)
+                                            for i in range(n)]
+    assert calls == [(f"p{i}",) for i in range(n)]
+    assert all(r.batch_size == n and r.launches_saved == 0
+               and not r.deduped for r in reqs)
+    snap = sched.stats_snapshot()
+    assert snap["launches"] - mark["launches"] == 1 + n   # the blocker's
+    assert snap["launchesSaved"] == 0 and snap["coalescedLaunches"] == 0
+    assert snap["maxBatchSize"] == n
+
+
+@pytest.mark.parametrize("order", ["aab", "abab"])
+def test_mixed_group_shares_identical_params_only(order):
+    sched = LaunchScheduler(name=f"t-mixed-{order}")
+    calls = []
+
+    def call(params, num_docs):
+        calls.append(params)
+        return ("out", params)
+
+    kern = LaunchKernel(("km",), call)
+    objs = {"a": ("a",), "b": ("b",)}
+    b, gate = _pin(sched)
+    mark = sched.stats_snapshot()
+    reqs = [sched.submit(kern, objs[c], 0) for c in order]
     gate.set()
     b.result(30)
-    outs = [float(np.asarray(r.result(30))) for r in reqs]
-    assert outs == [3.0, 6.0, 15.0]
-    # one vmapped trace serves the whole chunk (the solo fn body runs once
-    # under the batching trace, not once per request)
-    assert len(launches) == 1
-    assert all(r.batch_size == 3 for r in reqs)
-    assert sched.stats_snapshot()["launchesSaved"] >= 2
-    assert sched.stats_snapshot()["unbuiltGroups"] == 0
+    assert [r.result(30) for r in reqs] == [("out", objs[c]) for c in order]
+    assert calls == [("a",), ("b",)], "one launch a distinct param object"
+    assert [r.deduped for r in reqs] == [order.count(c) > 1 for c in order]
+    assert all(r.launches_saved == len(order) - 2 for r in reqs)
+    snap = sched.stats_snapshot()
+    delta = {k: snap[k] - mark[k] for k in ("requests", "launches",
+                                            "launchesSaved",
+                                            "dedupedRequests")}
+    # the blocker's own launch is counted after the mark
+    assert delta["requests"] == 1 + len(order)
+    assert delta["launches"] + delta["launchesSaved"] == delta["requests"]
+    assert delta["launches"] == 1 + 2
+    assert delta["dedupedRequests"] == len(order) - 2
 
 
-def test_unbuilt_batched_variant_is_never_built_by_the_dispatcher():
-    """A group whose batched variant the kernel has not built launches its
-    members one by one and is counted: tracing and compiling a batched
-    program inside a live query's launch makes its riders wait seconds on
-    the chip. Built off the serving path, the next group rides it."""
-    import jax.numpy as jnp
-
-    sched = LaunchScheduler(name="t-unbuilt")
-    launches = []
-
-    def call(params, num_docs):
-        launches.append(1)
-        return params * num_docs
-
-    kern = LaunchKernel(("ku",), call, max_batch=8)
-    nd = jnp.int32(3)
-    for round_, want_launches in ((0, 2), (1, 1)):
-        blocker, gate = _blocker()
-        b = sched.submit(blocker, 0, 0)
-        reqs = [sched.submit(kern, jnp.float32(v), nd) for v in (1.0, 2.0)]
-        gate.set()
-        b.result(30)
-        assert [float(np.asarray(r.result(30))) for r in reqs] == [3.0, 6.0]
-        assert len(launches) == want_launches, round_
-        assert all(r.batch_size == 2 for r in reqs)
-        assert reqs[0].launches_saved == 2 - want_launches
-        assert sched.snapshot()["unbuiltGroups"] == 1
-        if round_ == 0:
-            assert not kern.has_batched(2)
-            kern.run_many([jnp.float32(0.0), jnp.float32(4.0)], nd)
-            assert kern.has_batched(2) and not kern.has_batched(3)
-            launches.clear()
-
-
-def test_unbatchable_kernel_falls_back_serial():
-    sched = LaunchScheduler(name="t-serial")
-    blocker, gate = _blocker()
+def test_no_two_launches_overlap():
+    """The invariant the dispatcher exists for: whatever threads submit and
+    whichever kernels they name, one launch runs at a time."""
+    sched = LaunchScheduler(name="t-overlap")
+    state = {"inside": 0, "overlaps": 0, "calls": 0}
+    guard = threading.Lock()
 
     def call(params, num_docs):
-        # .item() works on concrete values, explodes under a vmap trace —
-        # the shape of backend batching-rule failures
-        return params.item() * 2
+        with guard:
+            state["inside"] += 1
+            state["calls"] += 1
+            if state["inside"] > 1:
+                state["overlaps"] += 1
+        time.sleep(0.001)       # wide enough for a second launcher to show
+        with guard:
+            state["inside"] -= 1
+        return params
 
-    import jax.numpy as jnp
+    kernels = [LaunchKernel((f"ko{i}",), call) for i in range(2)]
+    errors = []
+    start = threading.Barrier(4)
 
-    import jax
+    def pump(tid: int) -> None:
+        try:
+            start.wait(30)
+            for it in range(25):
+                params = (tid, it)
+                got = sched.submit(kernels[(tid + it) % 2], params,
+                                   0).result(30)
+                assert got is params
+        except BaseException as e:  # noqa: BLE001 — surfaced below
+            errors.append(e)
 
-    kern = LaunchKernel(("k3",), call, max_batch=8)
-    # a variant that passes for built and fails when it runs
-    kern._vmapped[2] = jax.vmap(call, in_axes=(0, None))
-    b = sched.submit(blocker, 0, 0)
-    reqs = [sched.submit(kern, jnp.float32(v), 0) for v in (1.0, 4.0)]
-    gate.set()
-    b.result(30)
-    assert [r.result(30) for r in reqs] == [2.0, 8.0]
-    assert kern.batchable is False, "failed vmap must disable batching"
-    # a later round stays serial and still serves
-    r2 = sched.submit(kern, jnp.float32(3.0), 0)
-    assert r2.result(30) == 6.0
+    threads = [threading.Thread(target=pump, args=(t,), daemon=True)
+               for t in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(60)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors[:3]
+    assert state["calls"] == 100 and state["overlaps"] == 0
+    snap = sched.stats_snapshot()
+    assert snap["requests"] == 100 == snap["launches"]
 
 
 def test_launch_errors_reach_every_rider():
     sched = LaunchScheduler(name="t-err")
-    blocker, gate = _blocker()
 
     def boom(params, num_docs):
         raise RuntimeError("kernel exploded")
 
-    kern = LaunchKernel(("k4",), boom, max_batch=4)
-    kern.batchable = False
-    b = sched.submit(blocker, 0, 0)
+    kern = LaunchKernel(("k4",), boom)
+    b, gate = _pin(sched)
     params = ("same",)
     reqs = [sched.submit(kern, params, 0) for _ in range(2)]
     gate.set()
@@ -242,8 +258,7 @@ def test_dispatcher_crash_completes_waiters_and_recovers(monkeypatch):
         return orig(self, reqs)
 
     monkeypatch.setattr(LaunchScheduler, "_launch_group", flaky)
-    kern = LaunchKernel(("k5",), lambda params, num_docs: params,
-                        max_batch=1)
+    kern = LaunchKernel(("k5",), lambda params, num_docs: params)
     req = sched.submit(kern, ("p1",), 0)
     with pytest.raises(RuntimeError, match="synthetic dispatcher bug"):
         req.result(30)
@@ -257,7 +272,7 @@ def test_dispatcher_crash_completes_waiters_and_recovers(monkeypatch):
 
 HAMMER_QUERIES = [
     # same shape, different literals: share one compiled kernel (the
-    # literal-normalized launch tier) and stack into vmapped launches
+    # literal-normalized launch tier), one launch each
     "SELECT region, sum(qty), count(*) FROM sales WHERE year >= 2016 "
     "GROUP BY region ORDER BY region",
     "SELECT region, sum(qty), count(*) FROM sales WHERE year >= 2018 "
@@ -276,21 +291,6 @@ THREADS = 8
 ITERS = 6
 
 
-def _build_batched(dev, segs, sizes=(2, 4, 8)) -> None:
-    """Every launch kernel's batched variants, built off the serving path
-    (the dispatcher rides them and never builds one)."""
-    from pinot_tpu.parallel.combine import SEG_AXIS, pad_segments
-
-    batch = dev.batch_for(segs)
-    S = pad_segments(batch.num_segments, dev.mesh.shape[SEG_AXIS])
-    num_docs = dev._device_num_docs(batch, S)
-    with dev._cache_lock:
-        entries = list(dev._param_cache.values())
-    for _, lkey, params in entries:
-        for size in sizes:
-            dev._launch_cache[lkey].run_many([params] * size, num_docs)
-
-
 def test_concurrency_hammer(setup):
     _, segs = setup
     dev = ShardedQueryExecutor()  # the suite-wide virtual 8-device mesh
@@ -300,11 +300,9 @@ def test_concurrency_hammer(setup):
     for ctx in ctxs:
         rt, _ = dev.execute(ctx, segs)
         serial.append(rt.rows)
-    _build_batched(dev, segs)
     mark = dev.launcher.stats_snapshot()
 
     errors = []
-    coalesced_seen = []
     start = threading.Barrier(THREADS)
 
     def pump(tid: int) -> None:
@@ -312,13 +310,11 @@ def test_concurrency_hammer(setup):
             start.wait(30)
             for it in range(ITERS):
                 qi = (tid + it) % len(ctxs)
-                stats = QueryStats()
                 rt, stats = dev.execute(ctxs[qi], segs)
                 # (a) bit-identical vs the serial path
                 assert rt.rows == serial[qi], \
                     f"thread {tid} iter {it} q{qi} diverged"
-                if stats.launch.get("batchSize", 0) > 1:
-                    coalesced_seen.append(stats.launch)
+                assert stats.launch["launches"] == 1
         except BaseException as e:  # noqa: BLE001 — surfaced below
             errors.append(e)
 
@@ -329,17 +325,19 @@ def test_concurrency_hammer(setup):
     deadline = time.monotonic() + 120
     for t in threads:
         t.join(max(0.0, deadline - time.monotonic()))
-    # (c) no deadlock on the multi-device mesh
+    # (d) no deadlock on the multi-device mesh
     assert not any(t.is_alive() for t in threads), \
         "hammer threads hung: combine launches deadlocked"
     assert not errors, errors[:3]
-    # (b) at least one coalesced launch with batch size > 1
-    delta = dev.launcher.stats_snapshot()
-    assert delta["coalescedLaunches"] > mark["coalescedLaunches"], \
-        f"no coalescing under {THREADS}-thread load: {delta}"
-    assert delta["maxBatchSize"] >= 2
-    assert coalesced_seen, "no query reported riding a coalesced batch"
-    assert delta["launchesSaved"] > mark["launchesSaved"]
+    # (b) every request passed the dispatcher once: launched, or riding an
+    # identical rider's launch (which of the two is arrival timing)
+    snap = dev.launcher.stats_snapshot()
+    delta = {k: snap[k] - mark[k] for k in ("requests", "launches",
+                                            "launchesSaved", "failures")}
+    # (queries the executor's single flight merged never reach it)
+    assert 0 < delta["requests"] <= THREADS * ITERS
+    assert delta["launches"] + delta["launchesSaved"] == delta["requests"]
+    assert delta["failures"] == 0
 
 
 def test_uncontended_single_query_stats(setup):
@@ -353,34 +351,44 @@ def test_uncontended_single_query_stats(setup):
     assert stats.launch["coalesced"] == 0
 
 
-def test_vmapped_real_combine_bit_identical(setup):
-    """The vmapped form of the ACTUAL sharded combine (shard_map + psum +
-    all_gather on the 8-device mesh) must produce bit-identical packed
-    outputs to solo launches — the property the hammer's exactness rides
-    on even when scheduling happens to dedup instead of batch."""
+@pytest.mark.parametrize("template", [
+    "SELECT count(*), sum(qty) FROM sales WHERE year >= {y}",
+    "SELECT region, sum(qty), count(*) FROM sales WHERE year >= {y} "
+    "GROUP BY region ORDER BY region",
+], ids=["scalar", "group_by"])
+def test_two_literals_together_answer_as_alone(setup, template):
+    """Two literals of one shape met by one drain of the real sharded
+    executor's dispatcher (shard_map + psum on the 8-device mesh): one
+    kernel, two launches, each answer bit for bit what it is alone."""
     _, segs = setup
-    from pinot_tpu.parallel.combine import SEG_AXIS, pad_segments
-
     dev = ShardedQueryExecutor()
-    sqls = [f"SELECT region, sum(qty), count(*) FROM sales "
-            f"WHERE year >= {y} GROUP BY region ORDER BY region"
-            for y in (2016, 2019)]
-    for sql in sqls:  # populate both cache tiers
-        dev.execute(compile_query(sql), segs)
-    with dev._cache_lock:
-        entries = list(dev._param_cache.values())
-    assert len(entries) == 2
-    (_, lkey0, params0), (_, lkey1, params1) = entries
-    assert lkey0 == lkey1, "same-shape literals must share the launch key"
-    kernel = dev._launch_cache[lkey0]
-    batch = dev.batch_for(segs)
-    S = pad_segments(batch.num_segments, dev.mesh.shape[SEG_AXIS])
-    num_docs = dev._device_num_docs(batch, S)
-    solo = [np.asarray(kernel.run_one(p, num_docs))
-            for p in (params0, params1)]
-    rows = kernel.run_many([params0, params1], num_docs)
-    assert np.array_equal(np.asarray(rows[0]), solo[0])
-    assert np.array_equal(np.asarray(rows[1]), solo[1])
+    ctxs = [compile_query(template.format(y=y)) for y in (2016, 2019)]
+    alone = [dev.execute(ctx, segs)[0].rows for ctx in ctxs]
+    assert alone[0] != alone[1]
+    b, gate = _pin(dev.launcher)
+    got = [None, None]
+
+    def query(i: int) -> None:
+        got[i] = dev.execute(ctxs[i], segs)
+
+    threads = [threading.Thread(target=query, args=(i,), daemon=True)
+               for i in range(2)]
+    for t in threads:
+        t.start()
+    deadline = time.monotonic() + 60
+    while len(dev.launcher._queue) < 2 and time.monotonic() < deadline:
+        time.sleep(0.005)
+    queued = len(dev.launcher._queue)
+    gate.set()
+    b.result(30)
+    for t in threads:
+        t.join(60)
+    assert queued == 2 and not any(t.is_alive() for t in threads)
+    for i, (rt, stats) in enumerate(got):
+        assert rt.rows == alone[i]
+        assert stats.launch["batchSize"] == 2, "one kernel, one group"
+        assert stats.launch["launches"] == 1
+        assert stats.launch["launchesSaved"] == 0
 
 
 # --------------------------------------------------------------------------
@@ -465,101 +473,6 @@ def test_runner_threads_config_key():
         sched.shutdown(timeout_s=1)
 
 
-def test_launch_max_batch_config_key():
-    cfg = PinotConfiguration({CommonConstants.LAUNCH_MAX_BATCH_KEY: 1})
-    dev = ShardedQueryExecutor(config=cfg)
-    assert dev._launch_max_batch == 1
-
-
-# --------------------------------------------------------------------------
-# adaptive micro-batch window (the straggler hold)
-# --------------------------------------------------------------------------
-
-def test_window_gathers_stragglers_into_one_batch():
-    """With a hot arrival EWMA the dispatcher holds the window open, so a
-    straggler submitted ~20 ms behind the first request still rides the
-    SAME vmapped launch — no blocker pinning needed."""
-    import jax.numpy as jnp
-
-    sched = LaunchScheduler(name="t-window")
-    # hot_ms=inf: any PRIMED ewma counts as hot, so the hold is
-    # deterministic; prime with a tight synthetic arrival train
-    sched.set_window(max_ms=250.0, hot_ms=float("inf"))
-    with sched._cond:
-        t = time.perf_counter()
-        for i in range(5):
-            sched._note_arrival_locked(t + i * 0.0005)
-    launches = []
-
-    def call(params, num_docs):
-        launches.append(1)
-        return params * num_docs
-
-    kern = LaunchKernel(("kw",), call, max_batch=8)
-    kern.run_many([jnp.float32(0.0), jnp.float32(1.0)], jnp.int32(3))
-    launches.clear()
-    r1 = sched.submit(kern, jnp.float32(2.0), jnp.int32(3))
-    time.sleep(0.02)  # arrives mid-window: must join r1's drain
-    r2 = sched.submit(kern, jnp.float32(5.0), jnp.int32(3))
-    assert float(np.asarray(r1.result(30))) == 6.0
-    assert float(np.asarray(r2.result(30))) == 15.0
-    assert r1.batch_size == 2 and r2.batch_size == 2, \
-        "the straggler rode the held window into one batch"
-    assert len(launches) == 1
-    snap = sched.stats_snapshot()
-    assert snap["windowWaits"] >= 1
-    assert snap["windowGathered"] >= 1
-    assert sched.snapshot()["windowMaxMs"] == 250.0
-
-
-def test_window_idle_traffic_pays_no_hold():
-    """Cold EWMA (hot_ms=0 means nothing ever counts hot): a lone request
-    must dispatch immediately — no added latency at low QPS."""
-    sched = LaunchScheduler(name="t-window-idle")
-    sched.set_window(max_ms=500.0, hot_ms=0.0)
-
-    def call(params, num_docs):
-        return params
-
-    kern = LaunchKernel(("ki",), call, max_batch=8)
-    t0 = time.perf_counter()
-    r = sched.submit(kern, ("p",), 0)
-    assert r.result(30) == ("p",)
-    assert (time.perf_counter() - t0) < 0.4, \
-        "idle dispatch must not wait out the window"
-    assert sched.stats_snapshot()["windowWaits"] == 0
-
-
-def test_window_arrival_ewma_tracks_and_resets():
-    sched = LaunchScheduler(name="t-ewma")
-    sched.set_window(max_ms=1.0, hot_ms=2.0)
-    with sched._cond:
-        t = 100.0
-        sched._note_arrival_locked(t)
-        for _ in range(10):  # 1 ms apart: hot
-            t += 0.001
-            sched._note_arrival_locked(t)
-        hot = sched._arrival_ewma_ms
-        assert hot is not None and hot < 2.0
-        t += 10.0  # a 10 s gap must RESET, not decay over many arrivals
-        sched._note_arrival_locked(t)
-        assert sched._arrival_ewma_ms > 2.0
-    assert sched._window_hold_s(1) == 0.0
-
-
-def test_window_config_keys():
-    cfg = PinotConfiguration({
-        CommonConstants.LAUNCH_WINDOW_MS_KEY: 3.5,
-        CommonConstants.LAUNCH_WINDOW_HOT_MS_KEY: 9.0})
-    dev = ShardedQueryExecutor(config=cfg)
-    assert dev.launcher.window_max_ms == 3.5
-    assert dev.launcher.window_hot_ms == 9.0
-    # restore the shared per-mesh dispatcher for other tests
-    dev.launcher.set_window(
-        max_ms=CommonConstants.DEFAULT_LAUNCH_WINDOW_MS,
-        hot_ms=CommonConstants.DEFAULT_LAUNCH_WINDOW_HOT_MS)
-
-
 # --------------------------------------------------------------------------
 # cross-query column dedup (batch -> per-segment borrow satellite)
 # --------------------------------------------------------------------------
@@ -641,6 +554,27 @@ def test_launch_stats_merge_and_wire():
     # absent stays absent (no phantom key on host-path replies)
     empty = DataTable.for_aggregation([1.0], QueryStats())
     assert DataTable.from_bytes(empty.to_bytes()).stats.launch == {}
+
+
+LAUNCHES_KEYS = {
+    "enabled", "requests", "launches", "coalescedLaunches", "launchesSaved",
+    "dedupedRequests", "failures", "maxBatchSize", "queueWaitMsTotal",
+    "queueWaitMsMax", "queued", "dispatcherAlive"}
+SCHEDULER_KEYS = {"scheduler", "admission", "kernelFlight", "queryFlight"}
+
+
+@pytest.mark.parametrize("method, keys", [
+    ("launch_debug", LAUNCHES_KEYS), ("scheduler_debug", SCHEDULER_KEYS)],
+    ids=["launches", "scheduler"])
+def test_debug_endpoint_key_sets(method, keys):
+    """``/debug/launches`` and ``/debug/scheduler`` hold these keys and no
+    other: what went with the batching and the window stays gone."""
+    from pinot_tpu.controller.state import ClusterStateStore
+    from pinot_tpu.server.server import ServerInstance
+
+    inst = ServerInstance(f"Server_keys_{method}", ClusterStateStore(),
+                          executor=ShardedQueryExecutor())
+    assert set(getattr(inst, method)()) == keys
 
 
 def test_debug_launches_endpoint(setup):

@@ -53,7 +53,7 @@ def ssb_segs(tmp_path_factory):
 
 @pytest.fixture(scope="module")
 def ctxs():
-    # explicit LIMIT: full group sets, same as bench.py
+    # explicit LIMIT: full group sets
     return {qid: compile_query(q + " LIMIT 100000")
             for qid, q in ssb.QUERIES.items()}
 

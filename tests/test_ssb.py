@@ -1,4 +1,4 @@
-"""SSB suite parity at test scale (bench.py runs the timed version).
+"""SSB suite parity at test scale (benchmarks/run.py measures on the chip).
 
 Ref: contrib/pinot-druid-benchmark (the reference's macro benchmark
 harness); pandas is the oracle here, mirroring the reference's H2-parity

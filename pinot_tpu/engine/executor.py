@@ -811,6 +811,8 @@ class ServerQueryExecutor:
         Returns ``(result, rung)`` — rung 'startree_device' when the node
         arrays served through the device kernels, 'startree' for the host
         walker — or None (no fit / untranslatable predicate -> scan)."""
+        import time as _time
+
         from pinot_tpu.engine import startree_device, startree_exec
 
         def declined(reason: str) -> None:
@@ -828,10 +830,18 @@ class ServerQueryExecutor:
                                                     on_decline=declined)
             if matches is None:
                 return None  # predicate not dictId-translatable -> scan
-            idx = tree.select_records(matches,
-                                      [e.name for e in ctx.group_by])
-            if sp is not None:
-                sp.attrs.update(tree=tree_index, records=int(idx.shape[0]))
+            group_cols = [e.name for e in ctx.group_by]
+            if sp is None:
+                idx = tree.select_records(matches, group_cols)
+            else:
+                # traced: what the walk did (nodes, emitted, gathered) and
+                # the wall time of select_records alone
+                walk: Dict[str, int] = {}
+                t0 = _time.perf_counter()
+                idx = tree.select_records(matches, group_cols, walk)
+                sp.attrs.update(
+                    tree=tree_index, records=int(idx.shape[0]), **walk,
+                    selectMs=round((_time.perf_counter() - t0) * 1e3, 3))
 
         def chose(rung: str) -> None:
             # the CHOSEN tree rides the ledger and QueryStats: with

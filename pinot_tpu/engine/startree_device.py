@@ -2,14 +2,17 @@
 
 The device promotion of ``engine/startree_exec.py``'s host walker
 (re-design of ``StarTreeFilterOperator.java:87`` +
-``StarTreeGroupByExecutor.java:43``): the *tree walk* stays host-side — it
-is a pointer chase over R pre-aggregated records (R << num_docs) — but the
-aggregation runs on device through the SAME group-by kernel ladder the
-forward-index scan uses:
+``StarTreeGroupByExecutor.java:43``): the *tree walk* stays host-side — a
+few nodes descended, then binary searches inside the sorted leaves, over R
+pre-aggregated records (R << num_docs) — but the aggregation runs on device
+through the SAME group-by kernel ladder the forward-index scan uses:
 
 1. ``resolve_matches`` + ``StarTree.select_records`` pick the answering
-   record indices (a few hundred to a few thousand for the SSB Q2.x
-   shape — vs a 3M-doc scan).
+   record indices, in ascending order (a few hundred to a few thousand
+   for the SSB Q2.x shape — vs a 3M-doc scan): the walk descends the
+   matching children, cuts every leaf it reaches to the matching
+   sub-ranges by ``searchsorted`` on the leaf's own sort order, and reads
+   and masks only the dimensions the search cannot decide.
 2. The indices pad to a power-of-two capacity and ride to the device as
    ONE small int32 array; the jitted kernel gathers the staged node
    columns (``StagedSegment.startree_nodes`` — byte-accounted, pinned,

@@ -11,8 +11,13 @@ dictIds with ``STAR = -1`` sentinels and one contiguous float64/int64 column
 per aggregation function pair — so the selected record ranges feed the same
 masked-reduction kernels as regular columns. The *tree walk* stays host-side:
 it is a pruning structure over R pre-aggregated records (R << num_docs),
-where pointer chasing is cheap and a dense device scan would waste the
-pre-aggregation.
+where a dense device scan would waste the pre-aggregation. The walk
+(``StarTree.select_records``) descends a few nodes, their fields held in
+memory, and goes on inside every leaf it reaches by binary search: a node's
+record range is sorted on the dimensions in split order, so a leaf at depth
+``L`` is a sorted column on dimension ``L``, and on the next dimension below
+every single value. Only what the search cannot decide is read and masked,
+over the narrowed ranges. The record indices come back in ascending order.
 """
 
 from __future__ import annotations
@@ -506,6 +511,87 @@ class StarTreeBuilder:
             self._split(c, depth + 1)
 
 
+# A set of at most this many dictIds is walked value by value: two searches
+# a value, and the search goes on to the next dimension below each. A larger
+# set falls to its bounding range, its gaps left to the mask. SSB's IN-lists
+# (Q3.3 and Q3.4 two cities, Q4.1 two manufacturers) hold 2; its longer sets
+# are BETWEENs, contiguous, so their bounding range is exact.
+_MAX_PINNED_VALUES = 4
+# A set is tested against a column through one boolean table over the
+# dictIds 0..hi; ids further out than this are too far apart for a table
+# and ``np.isin`` tests them.
+_MAX_TABLE_IDS = 1 << 16
+_MAX_DICT_ID = np.iinfo(np.int32).max - 1   # so that a needle hi + 1 fits
+
+
+class _DimMatch:
+    """One dimension's predicate as the walk uses it. The dictIds
+    ``[lo, hi]`` bound it; ``exact`` says that every id between them
+    matches (a :class:`DictIdRange`, a contiguous set), ``pinned`` that it
+    is walked value by value. DictIds are >= 0: STAR matches nothing."""
+
+    __slots__ = ("lo", "hi", "exact", "pinned", "needles", "_ids",
+                 "_member")
+
+    def __init__(self, match):
+        lo, hi = match_bounds(match)
+        is_range = isinstance(match, DictIdRange)
+        if lo < 0 <= hi and not is_range:
+            match = [v for v in match if v >= 0]
+            lo = min(match)
+        self.lo = lo = max(lo, 0)
+        self.hi = hi = min(hi, _MAX_DICT_ID)
+        count = hi - lo + 1 if is_range else len(match)
+        self.exact = count == hi - lo + 1
+        self.pinned = count <= _MAX_PINNED_VALUES
+        if self.pinned:
+            # [v, v + 1) a value, in dictId order
+            values = range(lo, hi + 1) if self.exact else sorted(match)
+            self.needles = np.array([x for v in values for x in (v, v + 1)],
+                                    dtype=np.int32)
+        else:
+            self.needles = np.array([lo, hi + 1], dtype=np.int32)
+        self._ids = match
+        self._member = None
+
+    def spans(self, col: np.ndarray) -> List[Tuple[int, int]]:
+        """Index spans ``[a, b)`` of the sorted ``col`` that may hold a
+        match: one a value when pinned (``col`` is that one value there),
+        else the one span of ``[lo, hi]``. Binary searches only: the
+        needles are int32 like the column (with int64 needles numpy copies
+        the column first, and the search is O(n))."""
+        cuts = col.searchsorted(self.needles).tolist()
+        return [(a, b) for a, b in zip(cuts[::2], cuts[1::2]) if a < b]
+
+    def test(self, col: np.ndarray) -> np.ndarray:
+        """Boolean mask of ``col`` (dictIds in any order; STAR is False)."""
+        if self.exact:
+            if self.lo == self.hi:
+                return col == self.lo
+            return (col >= self.lo) & (col <= self.hi)
+        if self._member is None:
+            # made once a dimension a walk, and only if something is masked
+            ids = np.fromiter(self._ids, dtype=np.int32, count=len(self._ids))
+            if self.hi < _MAX_TABLE_IDS:
+                # entry hi + 1 stays False: ids past hi land on it, and
+                # STAR = -1 reads it from the end
+                top = self.hi + 1
+                table = np.zeros(top + 1, dtype=bool)
+                table[ids] = True
+                self._member = lambda c: table[np.minimum(c, top)]
+            else:
+                self._member = lambda c: np.isin(c, ids)
+        return self._member(col)
+
+
+def _arange_of_ranges(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
+    """``concatenate([arange(s, s + n) for s, n in zip(starts, lens)])``
+    in one vectorised pass."""
+    ends = np.cumsum(lens)
+    return (np.arange(int(ends[-1]), dtype=np.int64)
+            + np.repeat(starts - (ends - lens), lens))
+
+
 class StarTree:
     """A built (or loaded) star-tree: flat record columns + node array."""
 
@@ -517,6 +603,16 @@ class StarTree:
         self.nodes = nodes        # _NODE_DTYPE array; root = 0
         self._dim_index = {d: i for i, d
                            in enumerate(config.dimensions_split_order)}
+        # what the walk reads, so that a loaded tree walks like a built one
+        # and no step passes ``np.memmap.__getitem__``: every node field a
+        # contiguous array in memory (SSB's largest tree has 54,404 nodes,
+        # 1.3 MB; ``save`` still writes ``self.nodes``), the records a
+        # plain view of ``dims``, mapped or not (a search reads only the
+        # rows it probes)
+        (self._node_dim, self._node_value, self._node_start, self._node_end,
+         self._child_first, self._child_last) = (
+            np.array(nodes[f]) for f in _NODE_DTYPE.names)
+        self._walk_dims = np.asarray(dims)
 
     @property
     def num_records(self) -> int:
@@ -556,64 +652,116 @@ class StarTree:
     # -- query-time traversal (ref: StarTreeFilterOperator.java:87) ----------
     def select_records(self,
                        eq_in_per_dim: Dict[str, Any],
-                       group_by_dims: List[str]) -> np.ndarray:
-        """Record indices answering the query: for each split dimension —
-        with a predicate: descend matching children; grouped: descend all
-        non-star children; otherwise: descend the star child (fall back to
-        scanning all children + post-mask when absent). Predicate matches
-        are dictId sets or contiguous :class:`DictIdRange` slices (both
-        support ``in``; the post-filter branches on the kind)."""
-        grouped = set(self._dim_index[d] for d in group_by_dims)
-        predicates = {self._dim_index[d]: ids
-                      for d, ids in eq_in_per_dim.items()}
+                       group_by_dims: List[str],
+                       walk: Optional[Dict[str, int]] = None) -> np.ndarray:
+        """Record indices answering the query, **in ascending order**
+        (callers sum over them: float64 sums of integers, exact in any
+        order).
 
-        out: List[np.ndarray] = []
-        # stack of (node index, needs_postfilter)
-        stack: List[int] = [0]
-        nodes = self.nodes
+        Down the nodes, for each split dimension — with a predicate: the
+        matching children (value children stand in dictId order, so a
+        binary search finds them); grouped: all non-star children;
+        otherwise the star child, or every child where the dimension has
+        none. A leaf reached at depth ``L`` has its dimensions ``< L``
+        decided by the path, holds no STAR on those ``>= L`` (stars are
+        written at split dimensions only) and is sorted on them in split
+        order, so the walk goes on inside it: while dimension ``d`` has a
+        predicate, the range is cut to its matching sub-range(s) by
+        ``searchsorted`` on ``dims[s:e, d]``, and ``d + 1`` is searched
+        only below a single value (below a range, or below a grouped or
+        free dimension, which keep every value, the next column is no
+        longer sorted). Predicates on the dimensions after that are masked,
+        over the cut ranges alone; a grouped dimension needs no test there.
+
+        Predicate matches are dictId sets or contiguous
+        :class:`DictIdRange` slices. ``walk``, when given (a traced query),
+        receives what the walk did: ``nodes`` read, ``emitted`` records of
+        the leaf ranges reached, ``gathered`` records still read and masked
+        after the search."""
+        empty = np.empty(0, dtype=np.int64)
+        if walk is not None:
+            walk.update(nodes=0, emitted=0, gathered=0)
+        preds: Dict[int, _DimMatch] = {}
+        for name, match in eq_in_per_dim.items():
+            m = _DimMatch(match)
+            if m.hi < m.lo:
+                return empty    # before a node is read
+            preds[self._dim_index[name]] = m
+        pred_dims = sorted(preds)
+        grouped = {self._dim_index[d] for d in group_by_dims}
+
+        value, child_first = self._node_value, self._child_first
+        # leaf ranges reached, as (start, end, depth)
+        work: List[Tuple[int, int, int]] = []
+        stack = [(0, 0)]
+        visited = 0
         while stack:
-            ni = stack.pop()
-            n = nodes[ni]
-            if n["child_first"] < 0:  # leaf: emit record range
-                out.append(np.arange(n["start"], n["end"], dtype=np.int64))
+            ni, depth = stack.pop()
+            visited += 1
+            first = int(child_first[ni])
+            if first < 0:
+                work.append((int(self._node_start[ni]),
+                             int(self._node_end[ni]), depth))
                 continue
-            dim = int(n["dim"])
-            first, last = int(n["child_first"]), int(n["child_last"])
-            kids = range(first, last)
-            if dim in predicates:
-                match = predicates[dim]
-                for c in kids:
-                    if int(nodes[c]["value"]) in match:
-                        stack.append(c)
-            elif dim in grouped:
-                for c in kids:
-                    if int(nodes[c]["value"]) != STAR:
-                        stack.append(c)
+            last = int(self._child_last[ni])
+            dim = int(self._node_dim[ni])
+            # both builders write the star child last
+            values_end = last - int(value[last - 1] == STAR)
+            m = preds.get(dim)
+            if m is not None:
+                kv = value[first:values_end]
+                for a, b in m.spans(kv):
+                    if m.pinned or m.exact:
+                        kids = range(first + a, first + b)
+                    else:
+                        kids = (first + a
+                                + np.flatnonzero(m.test(kv[a:b]))).tolist()
+                    stack.extend((c, dim + 1) for c in kids)
+            elif dim in grouped or values_end == last:
+                # grouped, or free with no star child: every value child
+                stack.extend((c, dim + 1) for c in range(first, values_end))
             else:
-                star = next((c for c in kids
-                             if int(nodes[c]["value"]) == STAR), None)
-                if star is not None:
-                    stack.append(star)
-                else:
-                    for c in kids:
-                        stack.append(c)
-        if not out:
-            return np.empty(0, dtype=np.int64)
-        idx = np.concatenate(out)
-        # post-filter: leaves cover un-split tails, so records may still hold
-        # concrete values where the query needs specific ones, and STAR rows
-        # must never leak into predicate/grouped dims
-        mask = np.ones(idx.shape[0], dtype=bool)
-        for dim, match in predicates.items():
-            col = self.dims[idx, dim]
-            if isinstance(match, DictIdRange):
-                mask &= (col >= match.lo) & (col <= match.hi)
-            else:
-                mask &= np.isin(col, np.fromiter(match, dtype=np.int32,
-                                                 count=len(match)))
-        for dim in grouped:
-            mask &= self.dims[idx, dim] != STAR
-        # free dims need no post-filter: each emitted leaf range holds either
-        # the star-aggregated rows (star child taken) or the full concrete
-        # partition (no star child / leaf before that depth) — never both
-        return idx[mask]
+                stack.append((last - 1, dim + 1))
+        if walk is not None:
+            walk.update(nodes=visited, emitted=sum(e - s for s, e, _ in work))
+
+        dims = self._walk_dims
+        gathered = 0
+        # record ranges selected, as (start, end, mask over them or None)
+        parts: List[Tuple[int, int, Optional[np.ndarray]]] = []
+        while work:
+            s, e, d = work.pop()
+            m = preds.get(d)
+            if m is not None:
+                spans = m.spans(dims[s:e, d])
+                if m.pinned:
+                    work.extend((s + a, s + b, d + 1) for a, b in spans)
+                    continue
+                if not spans:
+                    continue
+                (a, b), = spans
+                s, e = s + a, s + b
+                if m.exact:
+                    d += 1      # decided; else its gaps are masked below
+            mask = None
+            for dim in pred_dims:
+                if dim >= d:
+                    t = preds[dim].test(dims[s:e, dim])
+                    mask = t if mask is None else mask & t
+            if mask is not None:
+                gathered += e - s
+            parts.append((s, e, mask))
+
+        if walk is not None:
+            walk["gathered"] = gathered
+        if not parts:
+            return empty
+        parts.sort(key=lambda p: p[0])
+        starts = np.array([p[0] for p in parts], dtype=np.int64)
+        lens = np.array([p[1] for p in parts], dtype=np.int64) - starts
+        idx = _arange_of_ranges(starts, lens)
+        if gathered:
+            idx = idx[np.concatenate([np.ones(e - s, dtype=bool)
+                                      if mask is None else mask
+                                      for s, e, mask in parts])]
+        return idx

@@ -330,6 +330,61 @@ class TestNamedSpans:
             launch["Dispatch"]["startMs"] + launch["Dispatch"]["ms"],
             abs=EPS_MS)
 
+    def test_the_walk_span_says_what_the_walk_did(self, executor, segs):
+        """``nodes`` read, ``emitted`` records of the leaf ranges reached,
+        ``gathered`` still read and masked after the search inside the
+        leaves, ``selectMs`` the wall time of ``select_records`` alone."""
+        tree = _traced(executor, segs, TREE_SQL)
+        walks = [c for seg in tree["children"]
+                 if seg["name"] == "SegmentGroupBy"
+                 for c in seg["children"] if c["name"] == "StarTreeWalk"]
+        assert len(walks) == NUM_SEGMENTS
+        for w in walks:
+            assert {"tree", "records", "nodes", "emitted", "gathered",
+                    "selectMs"} <= set(w)
+            assert w["nodes"] >= 1
+            assert 0 <= w["gathered"] <= w["emitted"]
+            assert 0 < w["records"] <= w["emitted"]
+            assert 0 <= w["selectMs"] <= w["ms"] + EPS_MS
+
+    def test_an_untraced_walk_is_handed_no_counter(self, executor, segs,
+                                                   monkeypatch):
+        """``select_records`` fills its counters through an out-parameter
+        that only a traced query hands in; untraced it gets none. Either
+        way it reads no clock itself: ``selectMs`` is taken around it, by
+        the traced caller."""
+        from pinot_tpu.segment.startree import StarTree
+
+        handed, reads = [], []
+        real = StarTree.select_records
+        real_clocks = {name: getattr(time, name) for name
+                       in ("perf_counter", "thread_time", "monotonic")}
+
+        def spy(self, matches, group_by, walk=None):
+            handed.append(walk)
+            before = len(reads)
+            out = real(self, matches, group_by, walk)
+            assert len(reads) == before, reads[before:]
+            return out
+
+        def counted(name):
+            def clock():
+                reads.append(name)
+                return real_clocks[name]()
+            return clock
+
+        executor.execute(compile_query(TREE_SQL), segs)     # warm
+        monkeypatch.setattr(StarTree, "select_records", spy)
+        for name in real_clocks:
+            monkeypatch.setattr(time, name, counted(name))
+        executor.execute(compile_query(TREE_SQL), segs)
+        assert handed == [None] * NUM_SEGMENTS
+        del handed[:]
+        _traced(executor, segs, TREE_SQL)
+        assert len(handed) == NUM_SEGMENTS
+        assert all(set(w) == {"nodes", "emitted", "gathered"}
+                   for w in handed)
+
     def test_served_queries_serialize_under_the_server_root(self, cluster):
         resp = cluster.query(TREE_SQL + " OPTION(trace=true)")
         broker = resp.to_dict()["traceInfo"]["spans"][0]

@@ -787,3 +787,329 @@ class TestPerTreeResidency:
 # (The star-tree reason-registry conformance test moved to
 # tests/test_reasons.py: ONE generic harness parameterized over
 # tracing.reason_registry() replaced the per-module scans.)
+
+
+# --------------------------------------------------------------------------
+# the walk: binary search inside the sorted leaves, against the old loop
+# --------------------------------------------------------------------------
+
+def _reference_select_records(tree, eq_in_per_dim, group_by_dims):
+    """The walk as it was before it searched the leaves, kept as the
+    tests' oracle: a pointer chase over ``tree.nodes``, every leaf's whole
+    range emitted, then every predicate and grouped dimension gathered and
+    tested over all of it. Order of the indices: the stack's."""
+    grouped = set(tree._dim_index[d] for d in group_by_dims)
+    predicates = {tree._dim_index[d]: ids
+                  for d, ids in eq_in_per_dim.items()}
+    out = []
+    stack = [0]
+    nodes = tree.nodes
+    while stack:
+        ni = stack.pop()
+        n = nodes[ni]
+        if n["child_first"] < 0:
+            out.append(np.arange(n["start"], n["end"], dtype=np.int64))
+            continue
+        dim = int(n["dim"])
+        kids = range(int(n["child_first"]), int(n["child_last"]))
+        if dim in predicates:
+            match = predicates[dim]
+            for c in kids:
+                if int(nodes[c]["value"]) in match:
+                    stack.append(c)
+        elif dim in grouped:
+            for c in kids:
+                if int(nodes[c]["value"]) != STAR:
+                    stack.append(c)
+        else:
+            star = next((c for c in kids
+                         if int(nodes[c]["value"]) == STAR), None)
+            if star is not None:
+                stack.append(star)
+            else:
+                for c in kids:
+                    stack.append(c)
+    if not out:
+        return np.empty(0, dtype=np.int64)
+    idx = np.concatenate(out)
+    mask = np.ones(idx.shape[0], dtype=bool)
+    for dim, match in predicates.items():
+        col = tree.dims[idx, dim]
+        if isinstance(match, DictIdRange):
+            mask &= (col >= match.lo) & (col <= match.hi)
+        else:
+            mask &= np.isin(col, np.fromiter(match, dtype=np.int32,
+                                             count=len(match)))
+    for dim in grouped:
+        mask &= tree.dims[idx, dim] != STAR
+    return idx[mask]
+
+
+WALK_CARDS = [7, 4, 4, 5, 20]      # dictIds a dimension of SSB_DIMS below
+WALK_TREES = {
+    # name -> (builder engine, max_leaf_records, skip_star_creation, loaded)
+    "lexsort": ("lexsort", 64, [], False),
+    "recursive": ("recursive", 64, [], False),
+    "lexsort-loaded": ("lexsort", 64, [], True),
+    "recursive-loaded": ("recursive", 64, [], True),
+    "skip-star": ("lexsort", 64, ["c_region", "p_category"], False),
+    "skip-star-recursive": ("recursive", 64, ["c_region", "p_category"],
+                            False),
+    "root-is-a-leaf": ("lexsort", 10 ** 9, [], False),
+    "tiny-leaves": ("lexsort", 1, [], False),
+    "tiny-leaves-skip-star": ("recursive", 1, ["d_year", "p_brand1"], True),
+    "fat-leaves-loaded": ("lexsort", 1500, [], True),
+}
+WALK_TREE_NAMES = ["ssb-shaped-segment"] + sorted(WALK_TREES)
+
+
+@pytest.fixture(scope="module")
+def walk_trees(ssb_shaped, tmp_path_factory):
+    """Trees over one set of SSB-shaped dictIds by both builders, built
+    and loaded, beside the segment builder's own loaded tree."""
+    rng = np.random.default_rng(77)
+    n = 6000
+    dims = {d: rng.integers(0, c, n).astype(np.int32)
+            for d, c in zip(SSB_DIMS, WALK_CARDS)}
+    revenue = rng.integers(100, 900_000, n).astype(np.int64)
+    trees = {"ssb-shaped-segment": ssb_shaped[0].star_trees[0]}
+    for name, (engine, max_leaf, skip, loaded) in WALK_TREES.items():
+        cfg = StarTreeConfig(list(SSB_DIMS),
+                             [("count", "*"), ("sum", "lo_revenue")],
+                             max_leaf_records=max_leaf,
+                             skip_star_creation=skip)
+        tree = StarTreeBuilder(cfg).build(
+            dict(dims), {"lo_revenue": revenue}, n, engine=engine)
+        if loaded:
+            out = str(tmp_path_factory.mktemp("walk"))
+            tree.save(out)
+            tree = StarTree.load(out)
+        trees[name] = tree
+    return trees
+
+
+# name -> (matches, grouped dimensions[, the matches the old loop is asked:
+# where a match reaches below dictId 0 the old loop took STAR for a value])
+WALK_CASES = {
+    "nothing": ({}, []),
+    "eq-first-dim": ({"d_year": {3}}, []),
+    "eq-last-dim": ({"p_brand1": {7}}, []),
+    "eq-absent-value": ({"s_region": {99}}, ["d_year"]),
+    "eq-every-dim": ({"d_year": {2}, "c_region": {1}, "s_region": {3},
+                      "p_category": {0}, "p_brand1": {11}}, []),
+    # either side of _MAX_PINNED_VALUES: four values are searched one by
+    # one, five fall to their bounding range and the mask
+    "in-4-pinned": ({"p_brand1": {1, 5, 9, 13}}, []),
+    "in-5-masked": ({"p_brand1": {1, 5, 9, 13, 17}}, []),
+    "in-2-then-eq": ({"s_region": {0, 3}, "p_category": {2}}, ["d_year"]),
+    "in-4-then-eq": ({"p_category": {0, 2, 3, 4}, "p_brand1": {6}}, []),
+    "in-5-then-eq": ({"s_region": {1}, "p_category": {0, 1, 2, 3, 4},
+                      "p_brand1": {6}}, []),
+    "in-5-gaps-first-dim": ({"d_year": {0, 2, 4, 5, 6}}, ["s_region"]),
+    "in-5-gaps-then-in-5-gaps": ({"d_year": {0, 2, 4, 5, 6},
+                                  "p_brand1": {0, 3, 4, 18, 19}}, []),
+    # ids too far apart for a table: np.isin tests them
+    "in-5-sparse-ids": ({"p_brand1": {1, 5, 9, 13, 3_000_000}},
+                        ["p_category"]),
+    "in-contiguous-6": ({"p_brand1": set(range(3, 9))}, ["d_year"]),
+    "range": ({"p_brand1": DictIdRange(3, 9)}, ["d_year"]),
+    "range-3-pinned": ({"c_region": DictIdRange(1, 3), "s_region": {2}}, []),
+    "range-one-value": ({"p_category": DictIdRange(2, 2),
+                         "p_brand1": DictIdRange(4, 11)}, []),
+    "range-past-the-end": ({"p_brand1": DictIdRange(15, 2 ** 31 - 1)}, []),
+    # below a range the next column is not sorted: the equality is masked
+    "range-then-eq": ({"d_year": DictIdRange(1, 5), "c_region": {2}}, []),
+    "range-then-eq-in-leaf": ({"p_category": DictIdRange(0, 4),
+                               "p_brand1": {10}}, ["d_year"]),
+    "eq-range-eq": ({"s_region": {2}, "p_category": DictIdRange(0, 4),
+                     "p_brand1": {5}}, []),
+    # STAR is -1 and no dictId: a match that reaches below 0 selects what
+    # its part from 0 up selects
+    "range-below-zero": ({"c_region": DictIdRange(-5, 1)}, [],
+                         {"c_region": DictIdRange(0, 1)}),
+    "range-below-zero-in-leaf": ({"d_year": {4},
+                                  "p_brand1": DictIdRange(-1, 6)}, [],
+                                 {"d_year": {4},
+                                  "p_brand1": DictIdRange(0, 6)}),
+    "range-all-below-zero": ({"s_region": DictIdRange(-9, -1)}, ["d_year"],
+                             {"s_region": set()}),
+    "set-with-star": ({"c_region": {-1, 1}, "p_brand1": {-1, 0, 1, 2, 8, 9}},
+                      [], {"c_region": {1}, "p_brand1": {0, 1, 2, 8, 9}}),
+    "empty-set": ({"s_region": set()}, ["d_year"]),
+    "empty-range": ({"p_brand1": DictIdRange(5, 4), "d_year": {1}}, []),
+    "q2.1-shape": ({"p_category": {2}, "s_region": {1}},
+                   ["d_year", "p_brand1"]),
+    "q2.2-shape": ({"p_brand1": set(range(8, 16)), "s_region": {2}},
+                   ["d_year", "p_brand1"]),
+    "q3.3-shape": ({"c_region": {0, 2}, "s_region": {1, 3},
+                    "d_year": set(range(0, 6))}, ["d_year"]),
+    "grouped-only": ({}, ["c_region", "p_category"]),
+    "grouped-then-eq": ({"p_brand1": {3}}, ["p_category"]),
+    "grouped-and-filtered": ({"p_brand1": {2, 3, 11}}, ["p_brand1"]),
+    "free-then-eq": ({"p_category": {1}}, []),
+    "every-dim": ({"d_year": {0, 6}, "c_region": DictIdRange(1, 2),
+                   "s_region": {0, 1, 3}, "p_category": {4},
+                   "p_brand1": set(range(0, 20, 3))}, []),
+}
+WALK_RANDOM_SEEDS = (11, 12, 13)
+WALK_RANDOM_DRAWS = 100
+
+
+def _random_walk_queries(seed, draws=WALK_RANDOM_DRAWS):
+    """Seeded predicate / group-by draws over SSB_DIMS' dictIds."""
+    rng = np.random.default_rng(seed)
+    for _ in range(draws):
+        matches, groups = {}, []
+        for d, card in zip(SSB_DIMS, WALK_CARDS):
+            if rng.random() < 0.25:
+                groups.append(d)
+            kind = rng.integers(0, 10)
+            if kind == 0:
+                matches[d] = {int(rng.integers(0, card))}
+            elif kind == 1:     # a set, its size on both sides of the cap
+                k = int(rng.integers(2, min(card, 8) + 1))
+                matches[d] = set(rng.choice(card, k, replace=False).tolist())
+            elif kind == 2:
+                lo = int(rng.integers(0, card))
+                matches[d] = set(range(lo, int(rng.integers(lo, card)) + 1))
+            elif kind == 3:
+                lo = int(rng.integers(0, card))
+                matches[d] = DictIdRange(lo, int(rng.integers(lo, card + 2)))
+            elif kind == 4 and rng.random() < 0.1:
+                matches[d] = set()
+        yield matches, groups
+
+
+@pytest.mark.parametrize("case", sorted(WALK_CASES)
+                         + [f"random-{seed}" for seed in WALK_RANDOM_SEEDS])
+@pytest.mark.parametrize("tree_name", WALK_TREE_NAMES)
+def test_walk_selects_the_reference_records(walk_trees, tree_name, case):
+    tree = walk_trees[tree_name]
+    queries = ([WALK_CASES[case]] if case in WALK_CASES
+               else _random_walk_queries(int(case.split("-")[1])))
+    for matches, groups, *asked in queries:
+        walk = {}
+        got = tree.select_records(matches, groups, walk)
+        want = np.sort(_reference_select_records(
+            tree, asked[0] if asked else matches, groups))
+        # the same records, and in ascending order
+        np.testing.assert_array_equal(got, want,
+                                      err_msg=f"{matches} {groups}")
+        assert got.dtype == np.int64
+        np.testing.assert_array_equal(
+            got, tree.select_records(matches, groups))
+        assert 0 <= walk["gathered"] <= walk["emitted"]
+        assert got.shape[0] <= walk["emitted"]
+        assert walk["nodes"] >= 1 or got.shape[0] == 0
+
+
+def _nodes_with_depth(tree):
+    """(node index, depth) of every node, from the root down."""
+    out, stack = [], [(0, 0)]
+    while stack:
+        ni, depth = stack.pop()
+        out.append((ni, depth))
+        n = tree.nodes[ni]
+        if n["child_first"] >= 0:
+            assert n["dim"] == depth
+            stack.extend((c, depth + 1) for c
+                         in range(int(n["child_first"]),
+                                  int(n["child_last"])))
+    return out
+
+
+@pytest.mark.parametrize("tree_name", WALK_TREE_NAMES)
+def test_what_the_search_inside_a_leaf_stands_on(walk_trees, tree_name):
+    """Both builders: a star child, where there is one, is its parent's
+    last child and the value children stand in dictId order; a leaf at
+    depth L is constant on the dimensions < L, holds no STAR on those
+    >= L and is sorted on them in split order."""
+    tree = walk_trees[tree_name]
+    dims = np.asarray(tree.dims)
+    nodes = tree.nodes
+    leaves = stars = 0
+    for ni, depth in _nodes_with_depth(tree):
+        n = nodes[ni]
+        rows = dims[int(n["start"]):int(n["end"])]
+        if n["child_first"] >= 0:
+            values = nodes["value"][int(n["child_first"]):
+                                    int(n["child_last"])]
+            stars += int(values[-1] == STAR)
+            concrete = values[:-1] if values[-1] == STAR else values
+            assert not np.any(concrete == STAR)
+            assert np.all(np.diff(concrete) > 0)
+            continue
+        leaves += 1
+        assert rows.shape[0] > 0
+        assert np.all(rows[:, :depth] == rows[0, :depth])
+        tail = rows[:, depth:]
+        assert not np.any(tail == STAR)
+        if tail.shape[1]:
+            order = np.lexsort(tuple(tail[:, i] for i
+                                     in range(tail.shape[1] - 1, -1, -1)))
+            np.testing.assert_array_equal(order, np.arange(tail.shape[0]))
+    assert leaves >= 1
+    assert stars >= 1 or tree_name == "root-is-a-leaf"
+
+
+def test_the_search_decides_where_the_old_walk_gathered(walk_trees):
+    """On a root that is one sorted leaf: Q2.1's shape has d_year grouped,
+    which stops the search at once, so all is masked; with d_year and
+    c_region pinned the two equalities after them are searched too and
+    nothing is masked; an IN-list of two is two pinned searches; a range
+    cuts the leaf and leaves the equality after it to the mask."""
+    tree = walk_trees["root-is-a-leaf"]
+    n, walk = tree.num_records, {}
+    tree.select_records({"p_category": {2}, "s_region": {1}},
+                        ["d_year", "p_brand1"], walk)
+    assert walk == {"nodes": 1, "emitted": n, "gathered": n}
+    got = tree.select_records({"d_year": {3}, "c_region": {1},
+                               "s_region": {1}, "p_category": {2}},
+                              ["p_brand1"], walk)
+    assert walk == {"nodes": 1, "emitted": n, "gathered": 0}
+    assert got.shape[0] > 0
+    got = tree.select_records({"d_year": {3, 5}, "c_region": {1}}, [], walk)
+    assert walk == {"nodes": 1, "emitted": n, "gathered": 0}
+    assert got.shape[0] > 0
+    got = tree.select_records({"d_year": DictIdRange(0, 4),
+                               "c_region": {1}}, [], walk)
+    in_range = int(np.sum(np.asarray(tree.dims)[:, 0] <= 4))
+    assert walk == {"nodes": 1, "emitted": n, "gathered": in_range}
+    assert 0 < got.shape[0] < in_range < n
+    # an empty match returns before a node is read
+    tree.select_records({"d_year": {3}, "c_region": set()}, [], walk)
+    assert walk == {"nodes": 0, "emitted": 0, "gathered": 0}
+
+
+@pytest.mark.parametrize("tree_name", ["ssb-shaped-segment",
+                                       "lexsort-loaded",
+                                       "fat-leaves-loaded"])
+def test_a_loaded_tree_walks_in_memory(walk_trees, tree_name, monkeypatch):
+    """``dims.npy`` and ``nodes.npy`` are mapped; the walk reads node
+    fields copied into memory and a plain view of the records, so no step
+    of it passes ``np.memmap.__getitem__``."""
+    tree = walk_trees[tree_name]
+    assert isinstance(tree.dims, np.memmap)
+    assert isinstance(tree.nodes, np.memmap)
+    fields = dict(dim=tree._node_dim, value=tree._node_value,
+                  start=tree._node_start, end=tree._node_end,
+                  child_first=tree._child_first, child_last=tree._child_last)
+    for name, arr in fields.items():
+        assert type(arr) is np.ndarray and arr.flags.c_contiguous
+        np.testing.assert_array_equal(arr, np.asarray(tree.nodes[name]))
+    assert type(tree._walk_dims) is np.ndarray
+    queries = [({"p_brand1": {1, 5, 9, 13, 17}, "s_region": {2}},
+                ["d_year"]),
+               ({"d_year": {2}, "c_region": {0, 3}}, ["p_category"])]
+    want = [np.sort(_reference_select_records(tree, m, g))
+            for m, g in queries]
+
+    def refuse(self, index):
+        raise AssertionError("the walk read a np.memmap")
+
+    monkeypatch.setattr(np.memmap, "__getitem__", refuse)
+    got = [tree.select_records(m, g) for m, g in queries]
+    monkeypatch.undo()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+        assert g.shape[0] > 0

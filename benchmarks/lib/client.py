@@ -11,9 +11,12 @@ Runners:
 - ``script``: client ``i`` sends the strings ``plans[i]`` names, in order,
   once (set-up uses it: the walk of the whole cycle, the warming bursts).
   With ``lockstep`` the clients send each step together.
-- ``closed``: client ``i`` starts at ``offsets[i]`` and walks the cycle,
-  sending its next request when the last one has answered, until
-  ``seconds`` have passed. Requests in flight at the close are awaited.
+- ``closed``: a queue walks the cycle from its entry of ``offsets``,
+  again and again; client ``i`` takes the next string of queue
+  ``i % len(offsets)`` when its last request has answered, until
+  ``seconds`` have passed. One queue a client is each client on a walk
+  of its own; one queue for all is upstream's runner. Requests in flight
+  at the close are awaited.
 - ``open``: requests are due at ``rate`` a second from the first, cycle
   order; ``clients`` connections take them as they come due. A request
   that finds no free connection goes out late and its wait counts.
@@ -51,6 +54,7 @@ class Run:
         self.t0_wall = 0.0
         self.t0 = 0.0
         self.next_due = 0       # open loop: index of the next request due
+        self.drawn = list(job.get("offsets", ()))   # closed: a queue's next
         self.barrier = (threading.Barrier(job["clients"])
                         if job.get("lockstep") else None)
 
@@ -80,12 +84,16 @@ class Run:
             self.job["host"], self.job["port"],
             timeout=self.job.get("timeout_s", 300.0))
 
-    def closed(self, client: int, offset: int) -> None:
+    def closed(self, client: int) -> None:
         conn = self.connect()
+        queue = client % len(self.drawn)
         deadline = self.t0 + self.job["seconds"]
         step = 0
         while time.perf_counter() < deadline:
-            self.send(conn, client, step, (offset + step) % len(self.sqls))
+            with self.lock:
+                k = self.drawn[queue]
+                self.drawn[queue] += 1
+            self.send(conn, client, step, k % len(self.sqls))
             step += 1
         conn.close()
 
@@ -118,8 +126,8 @@ class Run:
         job = self.job
         self.t0_wall = time.time()
         self.t0 = time.perf_counter()
-        targets = {"closed": lambda i: self.closed(i, job["offsets"][i]),
-                   "open": self.open, "script": self.script}
+        targets = {"closed": self.closed, "open": self.open,
+                   "script": self.script}
         threads = [threading.Thread(target=targets[job["runner"]],
                                     args=(i,), name=f"client-{i}")
                    for i in range(job["clients"])]

@@ -6,6 +6,24 @@ summed expression, group keys. A text literal selects the codes of the
 domain's members that satisfy the comparison as text.
 Sums are exact: every measure is an integer and float64 holds their sums.
 
+Its cost is not rows x strings. ``Reference`` holds, for one table, what the
+strings of a cycle share:
+
+- a conjunct's mask over the whole table is computed once and kept for the
+  strings that repeat it (SSB's variants share most of theirs), as far as
+  ``MASK_CACHE_BYTES`` reach;
+- a conjunct ``=`` / ``in`` on an integer column that takes more than
+  ``INDEX_MIN_DISTINCT`` distinct values in this table (a member's key; no
+  column of a ``STRING_DOMAINS`` entry) narrows by an index of the
+  reference's own, built once a column: the stable argsort and the column
+  sorted. The query's candidate rows are two ``searchsorted`` a literal,
+  sorted ascending, and the other conjuncts are applied to those rows
+  alone. Ascending rows keep the float32 control's ``np.add.at`` order, so
+  ``want`` and ``control`` are the lists the full mask gives. None of the
+  13 SSB families has such a conjunct (``d_year`` 7 values, ``lo_discount``
+  11, ``lo_quantity`` 50, ``d_weeknuminyear`` 53, ``d_yearmonthnum`` 84):
+  on SSB every query takes the full mask, as it always did.
+
 Run as a script it is the oracle child of a benchmark run: it makes the
 table from the seed, answers the cell's cycle and writes the answers as JSON.
 numpy and the standard library only, so it never touches the chip.
@@ -17,37 +35,136 @@ import importlib
 import json
 import os
 import sys
+import time
 
-from typing import Any, Dict, List, Tuple
+from collections import Counter
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
 Row = Tuple[Any, ...]
 
+# an integer column with more distinct values than this is a key worth an
+# index; every filter column of the 13 SSB families has at most 84
+INDEX_MIN_DISTINCT = 4096
+# whole-table masks kept for the strings that repeat a conjunct
+MASK_CACHE_BYTES = 1 << 30
 
-def _mask(table_mod, cols: Dict[str, np.ndarray], where: List[list]
-          ) -> np.ndarray:
-    """The conjunction, on codes: a text literal selects the codes of the
-    domain's members that satisfy the comparison as text."""
+
+def _conjunct(table_mod, column: str, op: str, lits: list, col: np.ndarray
+              ) -> np.ndarray:
+    """One comparison over ``col`` (the whole column or some rows of it),
+    on codes: a text literal selects the codes of the domain's members that
+    satisfy the comparison as text."""
+    domain = table_mod.STRING_DOMAINS.get(column)
+    if op not in ("=", "in", "<", "between"):
+        raise ValueError(f"unknown comparison {op!r}")
+    if domain is not None:
+        test = {"=": lambda v: v == lits[0], "in": lambda v: v in lits,
+                "<": lambda v: v < lits[0],
+                "between": lambda v: lits[0] <= v <= lits[1]}[op]
+        return np.isin(col, [i for i, v in enumerate(domain) if test(v)])
+    if op == "<":
+        return col < int(lits[0])
+    if op == "between":
+        return (col >= int(lits[0])) & (col <= int(lits[1]))
+    return np.isin(col, [int(v) for v in lits])
+
+
+def _mask(table_mod, cols: Dict[str, np.ndarray], where: List[list],
+          idx: Optional[np.ndarray] = None) -> Optional[np.ndarray]:
+    """The conjunction over the whole table, or over the rows ``idx``
+    alone. No conjunct gives None: every row."""
     mask = None
     for column, op, *lits in where:
-        col = cols[column]
-        domain = table_mod.STRING_DOMAINS.get(column)
-        if op not in ("=", "in", "<", "between"):
-            raise ValueError(f"unknown comparison {op!r}")
-        if domain is not None:
-            test = {"=": lambda v: v == lits[0], "in": lambda v: v in lits,
-                    "<": lambda v: v < lits[0],
-                    "between": lambda v: lits[0] <= v <= lits[1]}[op]
-            m = np.isin(col, [i for i, v in enumerate(domain) if test(v)])
-        elif op == "<":
-            m = col < int(lits[0])
-        elif op == "between":
-            m = (col >= int(lits[0])) & (col <= int(lits[1]))
-        else:
-            m = np.isin(col, [int(v) for v in lits])
+        col = cols[column] if idx is None else cols[column][idx]
+        m = _conjunct(table_mod, column, op, lits, col)
         mask = m if mask is None else mask & m
     return mask
+
+
+class Reference:
+    """One table's rows and what the strings of a cycle share over them:
+    the masks of conjuncts that repeat, the index of a key column. The
+    counters are for the tests and for the set-up line."""
+
+    def __init__(self, table_mod, cols: Dict[str, np.ndarray],
+                 cycle: Optional[List[Dict[str, Any]]] = None):
+        self.table_mod, self.cols = table_mod, cols
+        self.repeats = Counter(tuple(c) for q in cycle or ()
+                               for c in q["where"])
+        self.masks: Dict[tuple, np.ndarray] = {}
+        self.sorted: Dict[str, Optional[Tuple[np.ndarray, np.ndarray]]] = {}
+        self.narrowed = 0       # queries that took their rows by an index
+        self.masks_shared = 0   # conjunct masks found instead of computed
+
+    def index(self, column: str
+              ) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """``(order, column[order])`` for a key column, None for any
+        other. The range of an integer column bounds its distinct values,
+        so no SSB column is ever sorted to find out."""
+        if column not in self.sorted:
+            col, found = self.cols[column], None
+            if (column not in self.table_mod.STRING_DOMAINS
+                    and col.dtype.kind in "iu" and len(col)
+                    and int(col.max()) - int(col.min()) >= INDEX_MIN_DISTINCT):
+                order = np.argsort(col, kind="stable")
+                ordered = col[order]
+                steps = np.count_nonzero(ordered[1:] != ordered[:-1])
+                if steps + 1 > INDEX_MIN_DISTINCT:
+                    found = (order, ordered)
+            self.sorted[column] = found
+        return self.sorted[column]
+
+    def _narrow(self, where: List[list]) -> Optional[np.ndarray]:
+        """The rows of the first conjunct an index serves, ascending, with
+        the other conjuncts applied to them; None where no conjunct is
+        one."""
+        for n, (column, op, *lits) in enumerate(where):
+            found = self.index(column) if op in ("=", "in") else None
+            if found is None:
+                continue
+            order, ordered = found
+            info = np.iinfo(ordered.dtype)
+            # needles of the column's own dtype: numpy copies the column
+            # to search it with any other
+            needles = np.unique(np.asarray(
+                [v for v in map(int, lits) if info.min <= v <= info.max],
+                dtype=ordered.dtype))
+            lo = np.searchsorted(ordered, needles, "left")
+            hi = np.searchsorted(ordered, needles, "right")
+            idx = np.sort(np.concatenate(
+                [order[a:b] for a, b in zip(lo, hi)] or [order[:0]]))
+            rest = _mask(self.table_mod, self.cols,
+                         where[:n] + where[n + 1:], idx)
+            self.narrowed += 1
+            return idx if rest is None else idx[rest]
+        return None
+
+    def _shared_mask(self, conjunct: list) -> np.ndarray:
+        key = tuple(conjunct)
+        if key in self.masks:
+            self.masks_shared += 1
+            return self.masks[key]
+        column, op, *lits = conjunct
+        m = _conjunct(self.table_mod, column, op, lits, self.cols[column])
+        if self.repeats[key] > 1:
+            self.masks[key] = m
+            kept = sum(k.nbytes for k in self.masks.values())
+            while kept > MASK_CACHE_BYTES:
+                kept -= self.masks.pop(next(iter(self.masks))).nbytes
+        return m
+
+    def rows(self, where: List[list]) -> np.ndarray:
+        """The rows the conjunction keeps, ascending."""
+        idx = self._narrow(where)
+        if idx is not None:
+            return idx
+        mask = None
+        for conjunct in where:
+            m = self._shared_mask(conjunct)
+            mask = m if mask is None else mask & m
+        return np.flatnonzero(mask)
 
 
 def _value(cols: Dict[str, np.ndarray], value: list, idx: np.ndarray,
@@ -60,11 +177,14 @@ def _value(cols: Dict[str, np.ndarray], value: list, idx: np.ndarray,
 
 
 def answer(table_mod, cols: Dict[str, np.ndarray], query: Dict[str, Any],
-           dtype=np.float64) -> List[Row]:
+           dtype=np.float64, idx: Optional[np.ndarray] = None) -> List[Row]:
     """Rows shaped like the engine's resultTable: group keys as the engine
     prints them, then the sum. ``dtype`` float32 is the control: the sum
-    accumulated in the precision below the deployment's exact answers."""
-    idx = np.flatnonzero(_mask(table_mod, cols, query["where"]))
+    accumulated in the precision below the deployment's exact answers.
+    ``idx``: the rows the conjunction keeps, ascending, where the caller
+    has them; else the full mask finds them."""
+    if idx is None:
+        idx = np.flatnonzero(_mask(table_mod, cols, query["where"]))
     vals = _value(cols, query["value"], idx, dtype)
     keys = query["group_by"]
     if not keys:
@@ -98,16 +218,36 @@ def answer(table_mod, cols: Dict[str, np.ndarray], query: Dict[str, Any],
     return rows
 
 
+def answers(table_mod, cols: Dict[str, np.ndarray],
+            cycle: List[Dict[str, Any]], control: bool) -> Dict[str, Any]:
+    """``want`` (and the float32 ``control``) for every string of the
+    cycle, by its id."""
+    ref = Reference(table_mod, cols, cycle)
+    ids = [str(q["id"]) for q in cycle]     # the answers in cycle order
+    out: Dict[str, Any] = {"want": dict.fromkeys(ids)}
+    if control:
+        out["control"] = dict.fromkeys(ids)
+    # strings that share conjuncts stand together: their masks stay found
+    for q in sorted(cycle, key=lambda q: (q["flight"], q["sql"])):
+        idx = ref.rows(q["where"])
+        out["want"][str(q["id"])] = answer(table_mod, cols, q, idx=idx)
+        if control:
+            out["control"][str(q["id"])] = answer(table_mod, cols, q,
+                                                  np.float32, idx=idx)
+    out["oracle"] = {"strings": len(cycle), "narrowed": ref.narrowed,
+                     "masks_shared": ref.masks_shared}
+    return out
+
+
 def answers_for(table: str, num_segments: int, rows: int, seed: int,
                 cycle: List[Dict[str, Any]], control: bool
                 ) -> Dict[str, Any]:
+    t0 = time.perf_counter()
     table_mod = importlib.import_module(f"benchmarks.tables.{table}")
     cols = table_mod.table_codes(num_segments, rows, seed)
-    out: Dict[str, Any] = {"want": {str(q["id"]): answer(table_mod, cols, q)
-                                    for q in cycle}}
-    if control:
-        out["control"] = {str(q["id"]): answer(table_mod, cols, q, np.float32)
-                          for q in cycle}
+    made = time.perf_counter() - t0
+    out = answers(table_mod, cols, cycle, control)
+    out["oracle"].update(table_s=made, seconds=time.perf_counter() - t0)
     return out
 
 

@@ -3,10 +3,16 @@
 Each family gives ``variants_per_flight`` literal sets, and the seed one
 permutation of all the strings: the cycle. The literal sets are drawn from
 the traffic file's ``variants_seed``, so that every seed sends the same set
-of strings (the same work) in another order over other rows. Client ``i``
-of ``c`` starts at offset ``i * len(cycle) // c`` and walks the cycle for
-the whole window, so two windows of one code hold the same flights in the
-same order and no two clients are on one string at one step.
+of strings (the same work) in another order over other rows. The
+closed-loop clients draw their strings from queues (``offsets``): as a rule
+each client has its own, the cycle from offset ``i * len(cycle) // c``,
+so two windows of one code hold the same flights in the same order and no
+two clients are on one string at one step. They are at their own pace,
+though, and where a window is long enough for one client to catch the next
+the broker merges the twins and the run is no measurement. A traffic file
+with ``"queues": 1`` feeds all its clients from one queue in cycle order,
+as upstream's ``QueryRunner`` feeds its threads: a string goes out again
+only after every other string has.
 
 Standard library only: the client process and the oracle child import it.
 """
@@ -115,5 +121,9 @@ def spec_queries(traffic: Dict[str, Any]) -> List[Dict[str, Any]]:
     return [render(f, f["spec"], traffic) for f in traffic["families"]]
 
 
-def offsets(clients: int, cycle_len: int) -> List[int]:
-    return [i * cycle_len // clients for i in range(clients)]
+def offsets(traffic: Dict[str, Any], cycle_len: int) -> List[int]:
+    """Where in the cycle each queue of the closed-loop clients starts;
+    client ``i`` draws from queue ``i % queues``. ``queues`` is the
+    traffic file's, and one a client where it names none."""
+    queues = traffic.get("queues", traffic["clients"])
+    return [i * cycle_len // queues for i in range(queues)]

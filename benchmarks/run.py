@@ -11,7 +11,10 @@ window is compared with the plain reference (``lib/oracle.py``, numpy over
 the same seeded rows, computed in a child beside the build).
 
 The last line of standard output is the result (``correct``, ``attempted``,
-``failed``, ``metrics``, ``device``, the numbers compared). With
+``failed``, ``metrics``, ``device``, the numbers compared). A run that
+cannot stand as a measurement, or that raised, exits non-zero and its last
+line says why: ``{"correct": false, "failed_run": <reason>, "phase":
+<set_up | warm | window | compare | reduce>, "metrics": {}}``. With
 ``--trace 0`` the metrics are the cell's end-to-end metrics; with
 ``--trace 1`` every query carries ``trace=true``, a few seconds of the
 window are recorded with ``jax.profiler``, and the metrics are the cell's
@@ -31,6 +34,7 @@ import time
 _T0 = time.time()       # process start, as near as Python gives it
 
 import argparse  # noqa: E402
+import contextlib  # noqa: E402
 import gc  # noqa: E402
 import glob  # noqa: E402
 import importlib  # noqa: E402
@@ -40,6 +44,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import subprocess  # noqa: E402
 import sys  # noqa: E402
+import traceback  # noqa: E402
 
 from typing import Any, Dict, List, Optional  # noqa: E402
 
@@ -62,6 +67,33 @@ def log(msg: str) -> None:
 
 class RunFailed(RuntimeError):
     """The run cannot stand as a measurement (not: an answer was wrong)."""
+
+
+PHASES = ("set_up", "warm", "window", "compare", "reduce")
+
+
+@contextlib.contextmanager
+def phase(name: str):
+    """What is raised inside says in which phase of the run (the
+    innermost); outside any, a run is in its set-up."""
+    assert name in PHASES, name
+    try:
+        yield
+    except Exception as e:
+        if not hasattr(e, "phase"):
+            e.phase = name
+        raise
+
+
+def failed_line(error: Exception) -> str:
+    """What a failed run prints last on standard output: the driver keeps
+    that line, and of standard error only the exit code."""
+    reason = (str(error) if isinstance(error, RunFailed)
+              else f"{type(error).__name__}: {error}")
+    return json.dumps({"correct": False,
+                       "failed_run": " ".join(reason.split())[:300],
+                       "phase": getattr(error, "phase", PHASES[0]),
+                       "metrics": {}})
 
 
 # --------------------------------------------------------------------------
@@ -162,14 +194,14 @@ def client_job(served, sqls: List[str], **kw: Any) -> Dict[str, Any]:
                 path="/query/sql", sqls=sqls, timeout_s=300.0, **kw)
 
 
-def own_job(served, sqls: List[str], traffic: Dict[str, Any],
-            seconds: float) -> Dict[str, Any]:
+def own_job(served, cycle: List[Dict[str, Any]], sqls: List[str],
+            traffic: Dict[str, Any], seconds: float) -> Dict[str, Any]:
     """The cell's own traffic for ``seconds``: the window, and the
     warm-up's slices of it."""
     kw: Dict[str, Any] = {"runner": traffic["runner"],
                           "clients": traffic["clients"], "seconds": seconds}
     if traffic["runner"] == "closed":
-        kw["offsets"] = schedule.offsets(traffic["clients"], len(sqls))
+        kw["offsets"] = schedule.offsets(traffic, len(cycle))
     else:
         kw["rate"] = traffic["rate"]
     return client_job(served, sqls, **kw)
@@ -252,7 +284,8 @@ def run_window(served, setup: Dict[str, Any], seconds: float, trace: bool,
     traffic, cycle = setup["traffic"], setup["cycle"]
     sqls = [with_trace(q["sql"]) if trace else q["sql"] for q in cycle]
     before = served.counters()
-    child = Child("client.py", own_job(served, sqls, traffic, seconds),
+    child = Child("client.py",
+                  own_job(served, cycle, sqls, traffic, seconds),
                   setup["work"], tag)
     try:
         profile = (profile_inside(setup["work"], seconds, traffic)
@@ -404,14 +437,18 @@ def set_up(workload: str, seed: int, expect_platform: str,
         phases["load_and_stage_s"] = time.perf_counter() - t
         setup["counters_at_start"] = served.counters()
         t = time.perf_counter()
-        phases.update(warm(served, setup))
+        with phase("warm"):
+            phases.update(warm(served, setup))
         phases["warm_s"] = time.perf_counter() - t
         served.wait_staged()
         # the oracle ran beside the build and the warm-up; by now it has
-        # as a rule ended, and the window never opens before it has
+        # as a rule ended, and the window never opens before it has.
+        # oracle_s is the child's own time, table and answers: where it
+        # passes the rest of the set-up, setup_s measures the reference
         t = time.perf_counter()
         answers = oracle.join(1200.0)
         phases["oracle_join_s"] = time.perf_counter() - t
+        phases["oracle_s"] = answers["oracle"]["seconds"]
         setup["want"] = answers["want"]
         setup["control"] = answers.get("control")
         gc.collect()
@@ -463,7 +500,8 @@ def warm(served, setup: Dict[str, Any]) -> Dict[str, float]:
     log(f"device peak after the bursts: {serve_peak()} bytes")
     for attempt in range(5):
         before = served.counters()
-        Child("client.py", own_job(served, sqls, traffic, WARM_OWN_SECONDS),
+        Child("client.py",
+              own_job(served, cycle, sqls, traffic, WARM_OWN_SECONDS),
               work, f"warm_own_{attempt}").join(WARM_OWN_SECONDS + 600.0)
         added = served.compiled_between(before, served.counters())
         if not added:
@@ -477,35 +515,44 @@ def warm(served, setup: Dict[str, Any]) -> Dict[str, float]:
 def measure(setup: Dict[str, Any], seconds: float, trace: bool, tag: str,
             platform: str, strict: bool = True) -> str:
     """One window, judged and reduced to its result line."""
-    import jax
-
     from benchmarks.lib import serve
 
     served = setup["served"]
-    win = run_window(served, setup, seconds, trace, tag)
+    with phase("window"):
+        win = run_window(served, setup, seconds, trace, tag)
     if "setup_s" not in setup:      # process start to the first request
         setup["setup_s"] = win["t0_wall"] - _T0
         log("set-up " + " ".join(f"{k}={v:.1f}" for k, v in
                                  setup["phases"].items())
+            + f" oracle_strings={len(setup['cycle'])}"
             + f" setup_s={setup['setup_s']:.1f}")
     peak_bytes = serve.memory_peak_bytes()
-    verdict = judge(served, setup, win, platform)
-    unsound = []
-    if verdict["compiled_in_window"]:
-        unsound.append(
-            f"{len(verdict['compiled_in_window'])} programs were "
-            f"compiled inside the measured window: a shape was not warm, "
-            f"the run is no measurement "
-            f"({verdict['compiled_in_window'][:6]})")
-    if verdict["single_flight_hits"]:
-        unsound.append(
-            f"{verdict['single_flight_hits']} requests were merged with a "
-            f"twin in flight (the broker's single-flight): the window held "
-            f"less work than its schedule, the run is no measurement")
-    for msg in unsound:
-        if strict:
-            raise RunFailed(msg)
-        log(msg)
+    with phase("compare"):
+        verdict = judge(served, setup, win, platform)
+        unsound = []
+        if verdict["compiled_in_window"]:
+            unsound.append(
+                f"{len(verdict['compiled_in_window'])} programs were "
+                f"compiled inside the measured window: a shape was not "
+                f"warm, the run is no measurement "
+                f"({verdict['compiled_in_window'][:6]})")
+        if verdict["single_flight_hits"]:
+            unsound.append(
+                f"{verdict['single_flight_hits']} requests were merged with "
+                f"a twin in flight (the broker's single-flight): the window "
+                f"held less work than its schedule, the run is no "
+                f"measurement")
+        for msg in unsound:
+            if strict:
+                raise RunFailed(msg)
+            log(msg)
+    with phase("reduce"):
+        return result_line(setup, win, verdict, peak_bytes, trace, platform)
+
+
+def result_line(setup: Dict[str, Any], win: Dict[str, Any],
+                verdict: Dict[str, Any], peak_bytes: int, trace: bool,
+                platform: str) -> str:
     records = ok_records(win, setup["cycle"])
     late = [r["late_ms"] for r in win["records"] if "late_ms" in r]
     if late:
@@ -610,12 +657,21 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--keep-trace", default=None,
                     help="copy the traced run's .xplane.pb here")
     args = ap.parse_args(argv)
+    # the contract's bare directory (BENCHMARK.json and benchmarks/ alone)
+    # exits non-zero and prints no line; without this the program's
+    # ImportError would print the failed_run line below
+    if importlib.util.find_spec("pinot_tpu") is None:
+        raise SystemExit("bench: the program (pinot_tpu) is not in this "
+                         "checkout; nothing to measure")
     try:
         lines = run(args.workload, args.seed, args.seconds, bool(args.trace),
                     windows=args.windows, control=bool(args.control),
                     keep_trace=args.keep_trace)
-    except RunFailed as e:
+    except Exception as e:      # not SystemExit: no chip prints no line
+        if not isinstance(e, RunFailed):
+            traceback.print_exc()
         print(f"bench: run failed: {e}", file=sys.stderr, flush=True)
+        print(failed_line(e), flush=True)
         return 1
     print(lines[-1], flush=True)
     return 0

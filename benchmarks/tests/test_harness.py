@@ -17,8 +17,8 @@ CELL = "ssb_scan.flights_c2"
 ROWS, SEED = 40_000, 2 ** 31 + 1234
 
 
-def go(tmp, trace, **kw):
-    lines = bench.run(CELL, SEED, 3.0, trace, expect_platform="cpu",
+def go(tmp, trace, cell=CELL, **kw):
+    lines = bench.run(cell, SEED, 3.0, trace, expect_platform="cpu",
                       rows=ROWS, data_root=str(tmp), strict=False, **kw)
     return json.loads(lines[-1])
 
@@ -50,24 +50,39 @@ def test_timed_run_counts_and_is_correct(timed):
     assert compared["responses_compared"]["value"] == timed["attempted"]
 
 
+def sources(cell_name):
+    cell = bench.load_cell(cell_name)
+    return {m["name"]: m["source"]
+            for m in cell["end_to_end"] + cell["per_layer"]}
+
+
 def test_a_cpu_run_reports_no_time(timed, traced):
+    # two clients leave the tail to which two strings meet: c2's p95 is a
+    # per-layer reading (PERF.md section 2), the loaded cells' has a bound
     assert set(timed["metrics"]) == {"queries_per_s", "latency_p50_ms",
-                                     "latency_p95_ms", "setup_s"}
+                                     "setup_s"}
+    assert "request_p95_ms" in traced["metrics"]
+    assert "latency_p95_ms" in sources(CELL_C8V)
+    source = sources(CELL)
+    counts = 0
     for line in (timed, traced):
         for name, m in line["metrics"].items():
-            if name == "staged_bytes_per_row":
-                assert isinstance(m["value"], float)     # a count
+            if source[name] in bench.COUNT_SOURCES:
+                assert isinstance(m["value"], float), name      # a count
+                counts += 1
             else:
                 assert m["value"] == bench.NOT_MEASURED, name
         assert line["device"]["memory_peak_bytes"] == bench.NOT_MEASURED
+    assert counts >= 2      # the traced run's, at the least
 
 
 def test_traced_run_reads_spans_and_counters(traced):
     assert traced["correct"] is True
     names = set(traced["metrics"])
-    assert {"rest_overhead_ms", "broker_self_ms", "sched_wait_ms",
-            "exec_host_self_ms", "staged_bytes_per_row",
-            "flight_q1_p50_ms"} <= names
+    assert {"rest_overhead_ms", "broker_self_ms", "exec_host_self_ms",
+            "staged_bytes_per_row", "flight_q1_p50_ms"} <= names
+    # it moves latency_p95_ms, which c2 no longer reports end to end
+    assert "sched_wait_ms" not in names
     # no device plane on the CPU: those readers found nothing to read
     assert not names & {"scan_roofline", "device_idle_share",
                         "kernel_device_ms_per_query", "launches_per_query"}
@@ -85,7 +100,9 @@ def test_the_command_line_wants_a_tpu(tmp_path):
     assert "needs 1 tpu" in p.stderr
 
 
-def test_an_altered_answer_makes_the_run_incorrect(data_root, monkeypatch):
+@pytest.mark.parametrize("cell", [CELL, "ssb_scan.flights_c8v"])
+def test_an_altered_answer_makes_the_run_incorrect(data_root, monkeypatch,
+                                                   cell):
     """The timed path broken underneath: the broker's reduce adds one to
     the first sum of every answer it produces."""
     from pinot_tpu.broker.reduce import ReduceAccumulator
@@ -102,7 +119,7 @@ def test_an_altered_answer_makes_the_run_incorrect(data_root, monkeypatch):
 
     monkeypatch.setattr(ReduceAccumulator, "finish", altered)
     # warm-up sees HTTP 200 and goes on; the comparison is the window's
-    line = go(data_root, False)
+    line = go(data_root, False, cell)
     assert line["correct"] is False
     # (an answer with no row at this toy size has nothing to alter)
     assert line["failed"] == line["compared"]["responses_wrong"]["value"] > 0
@@ -251,3 +268,226 @@ def test_every_seed_warms_the_same_strings_in_the_same_order():
                         for plan in bench.burst_plans(cycle, 8)]))
     assert warmed[0] == warmed[1]
     assert sorted(warmed[0][0]) == sorted(sql)      # the whole cycle, once
+
+
+# --------------------------------------------------------------------------
+# the eight-client cell on the scan table (PR 34)
+# --------------------------------------------------------------------------
+
+CELL_C8V = "ssb_scan.flights_c8v"
+
+
+def test_the_eight_client_scan_cell_loads():
+    from benchmarks.lib import schedule
+
+    cell = bench.load_cell(CELL_C8V)
+    assert cell["cell"]["chips"] == 1
+    assert cell["config"] == bench.load_cell(CELL)["config"]    # c2's table
+    traffic = cell["traffic"]
+    assert (traffic["runner"], traffic["clients"]) == ("closed", 8)
+    cycle = schedule.build_cycle(traffic, SEED)
+    assert len({q["sql"] for q in cycle}) == len(cycle) == 13 * 25
+    names = {m["name"] for m in cell["per_layer"]}
+    assert {"launch_queue_wait_ms", "scan_roofline", "flight_q4_p50_ms",
+            "residency_hit_share", "device_idle_share"} <= names
+    assert not names & {"segment_queue_ms", "startree_walk_cpu_ms",
+                        "scan_roofline_mesh", "device_wait_ms.x4"}
+    # no accepted cell's line gains the new metric
+    for other in ("ssb_startree.flights_c8", CELL, "ssb_scan_x4.flights_c2"):
+        assert "launch_queue_wait_ms" not in {
+            m["name"] for m in bench.load_cell(other)["per_layer"]}
+
+
+def test_the_scan_cells_draw_from_one_queue_and_the_tree_cell_does_not():
+    """Clients that walk one cycle each at its own pace meet on the scan
+    table: eight inside most windows (PERF.md section 4), c2's two in one
+    window of some forty (415 twins merged, PR 34: what refused PR 26 and
+    PR 31). The scan cells' files feed their clients from one queue, as
+    upstream's runner does; flights_c8 on the trees, where no run of the
+    ledger's has met a twin, keeps a queue a client."""
+    from benchmarks.lib import schedule
+
+    traffic = schedule.load_traffic("flights_c8v")
+    assert schedule.offsets(traffic, 325) == [0]
+    job = bench.own_job(type("S", (), {"broker_port": 1}), [{}] * 325,
+                        [""] * 325, traffic, 1.0)
+    assert job["offsets"] == [0] and job["clients"] == 8
+    assert schedule.offsets(schedule.load_traffic("flights_c2"),
+                            104) == [0]
+    assert schedule.offsets(schedule.load_traffic("flights_c8"),
+                            104) == [13 * i for i in range(8)]
+
+
+@pytest.mark.parametrize("clients,offsets", [(8, [0]), (2, [0, 8]),
+                                             (8, [0, 4, 8, 12])])
+def test_a_queue_hands_a_string_out_again_only_after_every_other(
+        clients, offsets):
+    """The closed runner against a server that answers at once: every
+    queue's strings go out in cycle order from its offset, whichever of
+    its clients takes them, and a queue of one client is that client's
+    own walk."""
+    import http.server
+    import threading
+
+    from benchmarks.lib import client
+
+    class Echo(http.server.BaseHTTPRequestHandler):
+        protocol_version = "HTTP/1.1"
+
+        def do_POST(self):
+            self.rfile.read(int(self.headers["Content-Length"]))
+            self.send_response(200)
+            self.send_header("Content-Length", "2")
+            self.end_headers()
+            self.wfile.write(b"{}")
+
+        def log_message(self, *a):
+            pass
+
+    server = http.server.ThreadingHTTPServer(("127.0.0.1", 0), Echo)
+    threading.Thread(target=server.serve_forever, daemon=True).start()
+    try:
+        out = client.Run({
+            "host": "127.0.0.1", "port": server.server_address[1],
+            "path": "/query/sql", "sqls": [str(i) for i in range(16)],
+            "runner": "closed", "clients": clients, "offsets": offsets,
+            "seconds": 0.5}).run()
+    finally:
+        server.shutdown()
+        server.server_close()
+    records = out["records"]
+    assert len(records) > 16 and all(r["status"] == 200 for r in records)
+    for q, offset in enumerate(offsets):
+        mine = [r["index"] for r in records
+                if r["client"] % len(offsets) == q]
+        want = [(offset + k) % 16 for k in range(len(mine))]
+        assert sorted(mine) == sorted(want)     # no string skipped or twice
+        if clients == len(offsets):             # its own walk, in order
+            assert mine == want
+
+
+def traced_record(children):
+    return {"ok": True, "raw": {"traceInfo": {"spans": [{
+        "name": "BrokerQuery", "ms": 30.0, "children": [{
+            "name": "ScatterGather", "ms": 28.0, "children": [{
+                "name": "ServerQuery", "ms": 25.0,
+                "children": children}]}]}]}}}
+
+
+def test_launch_queue_wait_reads_the_sharded_combine():
+    read = bench.metric_reader("launch_queue_wait_ms")
+
+    def combine(queue_ms):
+        return {"name": "ShardedCombine", "ms": queue_ms + 9.0,
+                "queueMs": queue_ms, "workMs": 9.0}
+
+    records = [traced_record([combine(4.0)]),
+               traced_record([combine(6.5), combine(1.0)]),    # summed
+               traced_record([combine(12.0)]),
+               # the per-segment ladder: no sharded launch, left out
+               traced_record([{"name": "SegmentAggregate", "ms": 20.0}]),
+               dict(traced_record([combine(90.0)]), ok=False)]
+    assert read({"records": records}) == 7.5
+    assert read({"records": records[3:4]}) is None
+    assert read({"records": []}) is None
+
+
+@pytest.fixture(scope="module")
+def c8v(data_root):
+    return go(data_root, True, CELL_C8V)
+
+
+def test_a_toy_drive_of_the_eight_client_cell_is_correct(c8v):
+    assert c8v["correct"] is True and c8v["failed"] == 0
+    assert c8v["attempted"] >= 8
+    source = sources(CELL_C8V)
+    assert {"launch_queue_wait_ms", "flight_q1_p50_ms", "sched_wait_ms",
+            "residency_hit_share"} <= set(c8v["metrics"])
+    for name, m in c8v["metrics"].items():
+        if source[name] not in bench.COUNT_SOURCES:
+            assert m["value"] == bench.NOT_MEASURED, name
+    assert c8v["device"]["memory_peak_bytes"] == bench.NOT_MEASURED
+
+
+# --------------------------------------------------------------------------
+# a failed run says why on the line the driver keeps
+# --------------------------------------------------------------------------
+
+def main_on_the_cpu(monkeypatch, data_root, trace):
+    """``main`` as the driver calls it, but for the look for a chip."""
+    real = bench.run
+
+    def run(workload, seed, seconds, trace, **kw):
+        return real(workload, seed, seconds, trace, expect_platform="cpu",
+                    rows=ROWS, data_root=str(data_root), strict=False, **kw)
+
+    monkeypatch.setattr(bench, "run", run)
+    return bench.main(["--workload", CELL, "--seed", str(SEED), "--seconds",
+                       "2", "--trace", str(trace)])
+
+
+def last_line(capsys):
+    out = capsys.readouterr().out.strip().splitlines()
+    return json.loads(out[-1])
+
+
+def test_a_refused_warm_up_request_is_a_failed_run_that_says_so(
+        data_root, monkeypatch, capsys):
+    sound = bench.client_job
+
+    def refused(served, sqls, **kw):
+        return dict(sound(served, sqls, **kw), path="/query/nowhere")
+
+    monkeypatch.setattr(bench, "client_job", refused)
+    assert main_on_the_cpu(monkeypatch, data_root, 0) == 1
+    line = last_line(capsys)
+    assert list(line) == ["correct", "failed_run", "phase", "metrics"]
+    assert line["correct"] is False and line["metrics"] == {}
+    assert line["phase"] == "warm"
+    assert "warm-up warm_walk: 104 requests failed" in line["failed_run"]
+    assert len(line["failed_run"]) <= 300 and "\n" not in line["failed_run"]
+
+
+def test_an_exception_in_a_reader_is_a_failed_run_that_says_so(
+        data_root, monkeypatch, capsys):
+    def broken(name):
+        def read(ctx):
+            raise KeyError(f"{name} found no such span\nin the tree")
+        return read
+
+    monkeypatch.setattr(bench, "metric_reader", broken)
+    assert main_on_the_cpu(monkeypatch, data_root, 1) == 1
+    line = last_line(capsys)
+    assert line["correct"] is False and line["phase"] == "reduce"
+    assert line["failed_run"].startswith("KeyError: ")
+    assert "\n" not in line["failed_run"]
+
+
+def test_a_run_that_ends_well_keeps_its_line(data_root, monkeypatch, capsys):
+    assert main_on_the_cpu(monkeypatch, data_root, 0) == 0
+    line = last_line(capsys)
+    assert "failed_run" not in line and "phase" not in line
+    assert list(line)[:5] == ["correct", "attempted", "failed", "metrics",
+                              "device"]
+
+
+def test_the_failed_line_is_one_line_of_300_characters_at_most():
+    with pytest.raises(bench.RunFailed) as caught:
+        with bench.phase("compare"):
+            with bench.phase("reduce"):     # the innermost phase stands
+                raise bench.RunFailed("x " * 400 + "\n\tend")
+    line = json.loads(bench.failed_line(caught.value))
+    assert line["phase"] == "reduce" and len(line["failed_run"]) == 300
+    assert line["failed_run"].startswith("x x ")
+    # raised outside any phase, a run is in its set-up
+    assert json.loads(bench.failed_line(OSError("disk")))["phase"] \
+        == "set_up"
+
+
+def test_the_set_up_line_names_the_oracles_own_seconds(data_root, capfd):
+    bench.run(CELL, SEED, 1.0, False, expect_platform="cpu", rows=ROWS,
+              data_root=str(data_root), strict=False)
+    line = next(ln for ln in capfd.readouterr().err.splitlines()
+                if " set-up " in ln)
+    assert " oracle_join_s=" in line and " oracle_s=" in line
+    assert " oracle_strings=104 " in line

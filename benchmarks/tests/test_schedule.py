@@ -42,7 +42,7 @@ def test_every_seed_has_every_flight_eight_times():
 
 @pytest.mark.parametrize("clients", [1, 2, 8, 13])
 def test_no_two_clients_on_one_string_at_one_step(clients):
-    offs = schedule.offsets(clients, 104)
+    offs = schedule.offsets({"clients": clients}, 104)
     for step in range(104):
         at = [(o + step) % 104 for o in offs]
         assert len(set(at)) == clients

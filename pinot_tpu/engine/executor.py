@@ -582,7 +582,13 @@ class ServerQueryExecutor:
         from pinot_tpu.spi.metrics import ServerQueryPhase
 
         t0 = _time.perf_counter()
-        kept = prune_segments(ctx, segments, stats)
+        with maybe_span(stats, "Prune", segments=len(segments)) as sp:
+            if sp is None:
+                kept = prune_segments(ctx, segments, stats)
+            else:       # traced: how many each kind of proof excluded
+                why: Dict[str, int] = {}
+                kept = prune_segments(ctx, segments, stats, why)
+                sp.attrs.update(kept=len(kept), **why)
         stats.add_phase_ms(ServerQueryPhase.SEGMENT_PRUNING,
                            (_time.perf_counter() - t0) * 1e3)
         if not kept:

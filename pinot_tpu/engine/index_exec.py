@@ -9,7 +9,12 @@ gather-then-kernel shape PR-6 proved out for star-tree node slices:
 1. HOST resolves the matching docIds — sorted-postings decode + union for
    EQ/IN over inverted columns, binary search over the sorted forward index
    or the range-index permutation, ``np.intersect1d`` across the AND
-   conjuncts, shortest list first. All vectorized numpy; no per-doc Python.
+   conjuncts, shortest list first. A conjunct whose own match count is far
+   over the candidates already left (or that has no index at all, beside
+   one that has) is PROBED instead: evaluated on the forward index of the
+   candidates alone, the reference's scan-based filter operator running
+   under an index-based one in an AND. All vectorized numpy; no per-doc
+   Python.
 2. The docIds pad to a power-of-two capacity and ride to the device as ONE
    compact int32 array; the SAME jitted gather kernel the star-tree rung
    uses (``startree_device.build_startree_kernel``) gathers the staged
@@ -49,6 +54,7 @@ from pinot_tpu.engine.plan import (
 from pinot_tpu.engine.results import QueryStats
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.query.expressions import Identifier, Predicate, PredicateType
+from pinot_tpu.segment.dictionary import needle_for
 
 # fraction of the table above which an estimated match count declines to the
 # scan rungs: past this the gather reads most of the table anyway and the
@@ -63,6 +69,12 @@ SELECTIVITY_THRESHOLD = 0.05
 _MAX_ID_LISTS = 1024
 
 _MIN_CAPACITY = 128
+
+# a conjunct whose match count is over this many times the candidates left
+# is probed on their forward index: resolving it decodes and sorts its
+# whole match list to keep a sliver of it, probing reads one value a
+# candidate
+_PROBE_OVER = 8
 
 _EMPTY = np.empty(0, dtype=np.int64)
 
@@ -113,14 +125,44 @@ class _Decline(Exception):
 
 class _Route:
     """One predicate's index path: an exact match-count estimate computed
-    WITHOUT decoding postings, and a resolver producing the sorted unique
-    int64 docId array when the cost gate passes."""
+    WITHOUT decoding postings, a resolver producing the sorted unique
+    int64 docId array when the cost gate passes (None: the column has no
+    index, the conjunct can only be probed), and a probe giving the
+    conjunct's mask over a docId array (None: a multi-value column)."""
 
-    __slots__ = ("estimate", "resolve")
+    __slots__ = ("estimate", "resolve", "probe")
 
-    def __init__(self, estimate: int, resolve: Callable[[], np.ndarray]):
+    def __init__(self, estimate: int,
+                 resolve: Optional[Callable[[], np.ndarray]],
+                 probe: Optional[Callable[[np.ndarray], np.ndarray]] = None):
         self.estimate = estimate
         self.resolve = resolve
+        self.probe = probe
+
+
+def _dict_probe(ds, ids: np.ndarray) -> Callable[[np.ndarray], np.ndarray]:
+    """A single-value dictionary column's conjunct on the forward index
+    of some docs: their dictIds against the matching ones."""
+    def probe(idx: np.ndarray) -> np.ndarray:
+        if ids.size == 0:
+            return np.zeros(idx.size, dtype=bool)
+        got = np.asarray(ds.forward_index)[idx]
+        if int(ids[-1] - ids[0]) + 1 == ids.size:   # contiguous interval
+            return (got >= ids[0]) & (got <= ids[-1])
+        return np.isin(got, ids)
+
+    return probe
+
+
+def _raw_probe(ds, cm, pred: Predicate) -> Callable[[np.ndarray], np.ndarray]:
+    """A single-value RAW column's conjunct on the values of some docs."""
+    def probe(idx: np.ndarray) -> np.ndarray:
+        from pinot_tpu.engine.host_eval import _compare_values
+
+        return _compare_values(np.asarray(ds.forward_index)[idx], pred,
+                               cm.data_type)
+
+    return probe
 
 
 def _postings_route(ds, cm, ids: np.ndarray) -> _Route:
@@ -144,24 +186,30 @@ def _postings_route(ds, cm, ids: np.ndarray) -> _Route:
             return np.unique(docs)
         return docs if len(parts) == 1 else np.sort(docs)
 
-    return _Route(est, resolve)
+    return _Route(est, resolve,
+                  None if multi_value else _dict_probe(ds, ids))
 
 
 def _sorted_route(ds, ids: np.ndarray, num_docs: int) -> _Route:
     """Sorted dictionary column: dictIds map to contiguous docId runs, so
     matches are binary searches over the forward index — the sorted-column
     analogue of SortedIndexReader's docId ranges."""
+    probe = _dict_probe(ds, ids)
     if ids.size == 0:
-        return _Route(0, lambda: _EMPTY)
+        return _Route(0, lambda: _EMPTY, probe)
     fwd = np.asarray(ds.forward_index[:num_docs])
+    # needles of the column's dtype: dictIds fit it, and numpy copies the
+    # column to search it with any other
+    needles = ids.astype(fwd.dtype, copy=False)
     if int(ids[-1] - ids[0]) + 1 == ids.size:  # contiguous dictId interval
-        lo = int(np.searchsorted(fwd, ids[0], side="left"))
-        hi = int(np.searchsorted(fwd, ids[-1], side="right"))
-        return _Route(hi - lo, lambda: np.arange(lo, hi, dtype=np.int64))
+        lo = int(np.searchsorted(fwd, needles[0], side="left"))
+        hi = int(np.searchsorted(fwd, needles[-1], side="right"))
+        return _Route(hi - lo, lambda: np.arange(lo, hi, dtype=np.int64),
+                      probe)
     if ids.size > _MAX_ID_LISTS:
         raise _Decline("index_selectivity_over_threshold")
-    los = np.searchsorted(fwd, ids, side="left")
-    his = np.searchsorted(fwd, ids, side="right")
+    los = np.searchsorted(fwd, needles, side="left")
+    his = np.searchsorted(fwd, needles, side="right")
     est = int((his - los).sum())
 
     def resolve() -> np.ndarray:
@@ -171,7 +219,7 @@ def _sorted_route(ds, ids: np.ndarray, num_docs: int) -> _Route:
             return _EMPTY
         return parts[0] if len(parts) == 1 else np.concatenate(parts)
 
-    return _Route(est, resolve)
+    return _Route(est, resolve, probe)
 
 
 def _range_route(ds, cm, pred: Predicate, num_docs: int) -> _Route:
@@ -183,16 +231,16 @@ def _range_route(ds, cm, pred: Predicate, num_docs: int) -> _Route:
     dt = cm.data_type
     lo_i, hi_i = 0, num_docs
     if pred.type is PredicateType.EQ:
-        v = dt.convert(pred.value)
+        v = needle_for(sorted_vals, dt.convert(pred.value))
         lo_i = int(np.searchsorted(sorted_vals, v, side="left"))
         hi_i = int(np.searchsorted(sorted_vals, v, side="right"))
     else:
         if pred.lower is not None:
-            v = dt.convert(pred.lower)
+            v = needle_for(sorted_vals, dt.convert(pred.lower))
             side = "left" if pred.lower_inclusive else "right"
             lo_i = int(np.searchsorted(sorted_vals, v, side=side))
         if pred.upper is not None:
-            v = dt.convert(pred.upper)
+            v = needle_for(sorted_vals, dt.convert(pred.upper))
             side = "right" if pred.upper_inclusive else "left"
             hi_i = int(np.searchsorted(sorted_vals, v, side=side))
     est = max(0, hi_i - lo_i)
@@ -203,7 +251,7 @@ def _range_route(ds, cm, pred: Predicate, num_docs: int) -> _Route:
             return _EMPTY
         return np.sort(np.asarray(order[lo_i:hi_i]).astype(np.int64))
 
-    return _Route(est, resolve)
+    return _Route(est, resolve, _raw_probe(ds, cm, pred))
 
 
 def _pred_route(segment, pred: Predicate, num_docs: int) -> _Route:
@@ -224,30 +272,63 @@ def _pred_route(segment, pred: Predicate, num_docs: int) -> _Route:
             return _sorted_route(ds, ids, num_docs)
         if cm.has_inverted_index:
             return _postings_route(ds, cm, ids)
+        if cm.single_value:     # no index: probed beside a conjunct with one
+            return _Route(num_docs, None, _dict_probe(ds, ids))
         raise _Decline("index_missing_index")
     if (cm.single_value
             and pred.type in (PredicateType.EQ, PredicateType.RANGE)
             and getattr(ds, "range_order", None) is not None):
         return _range_route(ds, cm, pred, num_docs)
+    if cm.single_value:
+        return _Route(num_docs, None, _raw_probe(ds, cm, pred))
     raise _Decline("index_missing_index")
 
 
-def resolve_doc_ids(segment, preds: List[Predicate], num_docs: int,
-                    threshold: int) -> Optional[np.ndarray]:
-    """Conjunction -> sorted unique int64 docIds, or None past the cost
-    gate (raises _Decline for ineligible shapes). The gate runs on exact
-    per-predicate counts BEFORE any posting list decodes; resolution then
-    intersects shortest-first so the working set never exceeds the most
-    selective predicate's match count."""
+def _gated_routes(segment, preds: List[Predicate], num_docs: int,
+                  threshold: int) -> Optional[List[_Route]]:
+    """The conjuncts' routes in the order they are applied, or None past
+    the cost gate; raises _Decline for ineligible shapes. The gate runs
+    on exact per-predicate counts BEFORE any posting list decodes, and on
+    the conjuncts that have an index: one of them has to start the
+    candidates. A conjunct without one never starts them."""
     routes = [_pred_route(segment, p, num_docs) for p in preds]
-    if min(r.estimate for r in routes) > threshold:
+    indexed = [r.estimate for r in routes if r.resolve is not None]
+    if not indexed:
+        raise _Decline("index_missing_index")
+    if min(indexed) > threshold:
         return None
-    routes.sort(key=lambda r: r.estimate)
+    routes.sort(key=lambda r: (r.resolve is None, r.estimate))
+    return routes
+
+
+def resolve_doc_ids(segment, preds: List[Predicate], num_docs: int,
+                    threshold: int, trace: Optional[dict] = None
+                    ) -> Optional[np.ndarray]:
+    """Conjunction -> sorted unique int64 docIds, or None past the cost
+    gate (raises _Decline for ineligible shapes). Resolution starts from
+    the most selective conjunct's docIds, so the working set never exceeds
+    its match count; each further conjunct is probed on the forward index
+    of what is left where its own count is over ``_PROBE_OVER`` times that
+    (or it has no index), else resolved and intersected. Both give the
+    same docIds. ``trace`` (a traced query's) takes ``candidates``,
+    ``resolved`` and ``probed``."""
+    routes = _gated_routes(segment, preds, num_docs, threshold)
+    if routes is None:
+        return None
     idx = routes[0].resolve()
+    candidates, resolved, probed = int(idx.size), 1, 0
     for r in routes[1:]:
         if idx.size == 0:
             break
-        idx = np.intersect1d(idx, r.resolve(), assume_unique=True)
+        if r.probe is not None and (
+                r.resolve is None or r.estimate > _PROBE_OVER * idx.size):
+            idx = idx[r.probe(idx)]
+            probed += 1
+        else:
+            idx = np.intersect1d(idx, r.resolve(), assume_unique=True)
+            resolved += 1
+    if trace is not None:
+        trace.update(candidates=candidates, resolved=resolved, probed=probed)
     return idx
 
 
@@ -317,10 +398,9 @@ def batch_index_eligible(executor, ctx: QueryContext, segments) -> bool:
         num_docs = segment.num_docs
         threshold = max(1, int(num_docs * SELECTIVITY_THRESHOLD))
         try:
-            routes = [_pred_route(segment, p, num_docs) for p in preds]
+            if _gated_routes(segment, preds, num_docs, threshold) is None:
+                return False
         except _Decline:
-            return False
-        if min(r.estimate for r in routes) > threshold:
             return False
     return True
 
@@ -352,7 +432,15 @@ def try_index_rung(executor, ctx: QueryContext, aggs: List[AggDef],
     num_docs = segment.num_docs
     threshold = max(1, int(num_docs * SELECTIVITY_THRESHOLD))
     try:
-        idx = resolve_doc_ids(segment, preds, num_docs, threshold)
+        with maybe_span(stats, "IndexRoute",
+                        segment=segment.segment_name) as sp:
+            if sp is None:
+                idx = resolve_doc_ids(segment, preds, num_docs, threshold)
+            else:       # traced: what the resolution did
+                idx = resolve_doc_ids(segment, preds, num_docs, threshold,
+                                      sp.attrs)
+                if idx is not None:
+                    sp.attrs["matched"] = int(idx.size)
     except _Decline as d:
         _decline(stats, d.reason)
         return None
@@ -362,7 +450,7 @@ def try_index_rung(executor, ctx: QueryContext, aggs: List[AggDef],
     n = int(idx.size)
 
     try:
-        plan = gather_plan(executor._plan_for(ctx, segment), n)
+        plan = gather_plan(executor._plan_for(ctx, segment, stats), n)
     except PlanError:
         # the scan branch re-plans, re-raises, and ledgers the specific
         # plan-decline code; here only the rung outcome is recorded
@@ -386,19 +474,23 @@ def try_index_rung(executor, ctx: QueryContext, aggs: List[AggDef],
         executor.residency.account(segment.segment_name, lease)
 
         def launch():
-            from pinot_tpu.engine.kernels import unpack_outputs
+            from pinot_tpu.engine.kernels import fetch_outputs, unpack_outputs
 
-            cols = {name: staged.column(name).tree()
-                    for name in plan.columns}
-            kernel = executor._index_kernel(plan.spec)
-            packed = kernel(cols, idx_dev, tuple(plan.params), np.int32(n))
-            return unpack_outputs(packed, plan.spec)  # may raise PlanError
+            with maybe_span(stats, "Dispatch"):
+                cols = {name: staged.column(name).tree()
+                        for name in plan.columns}
+                kernel = executor._index_kernel(plan.spec)
+                packed = kernel(cols, idx_dev, tuple(plan.params),
+                                np.int32(n))
+            # may raise PlanError
+            return unpack_outputs(fetch_outputs(stats, packed), plan.spec)
 
         # per-segment coalescing: concurrent identical dashboard queries —
         # the SAME compiled ctx over the same resident — share one gather
         # launch + D2H (host docId resolution above stays per-caller)
         with maybe_span(stats, "Kernel", kernel="index_gather",
-                        segment=segment.segment_name, records=n):
+                        segment=segment.segment_name, records=n,
+                        capacity=capacity):
             out, _ = executor._kernel_flight.do(
                 ("index", id(ctx), segment.segment_name, id(staged)),
                 launch)

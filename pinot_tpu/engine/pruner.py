@@ -15,7 +15,7 @@ unification, and never burns HBM bandwidth in the dense scan.
 
 from __future__ import annotations
 
-from typing import Any, List, Optional
+from typing import Any, Dict, List, Optional
 
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.query.expressions import (
@@ -28,34 +28,67 @@ from pinot_tpu.query.expressions import (
 from pinot_tpu.utils.partition import get_partition_function
 
 
+# what proved a segment empty, as the ``Prune`` span counts it
+BY_PARTITION, BY_BOUNDS, BY_BLOOM = "byPartition", "byBounds", "byBloom"
+
+
 def prune_segments(ctx: QueryContext, segments: List,
-                   stats=None) -> List:
+                   stats=None, why: Optional[Dict[str, int]] = None) -> List:
     """Segments the query may still match (ref:
-    SegmentPrunerService.prune called at ServerQueryExecutorV1Impl:277)."""
+    SegmentPrunerService.prune called at ServerQueryExecutorV1Impl:277).
+    ``why`` (a traced query's) takes the count of segments each kind of
+    proof excluded."""
     if ctx.filter is None:
         return segments
-    kept = [s for s in segments if _may_match(ctx.filter, s)]
+    kept = []
+    for s in segments:
+        proof = _proof_of_empty(ctx.filter, s)
+        if proof is None:
+            kept.append(s)
+        elif why is not None:
+            why[proof] = why.get(proof, 0) + 1
     if stats is not None:
         stats.num_segments_pruned += len(segments) - len(kept)
     return kept
 
 
-def _may_match(node: FilterNode, seg) -> bool:
+def _proof_of_empty(node: FilterNode, seg) -> Optional[str]:
+    """What proves the filter empty on the segment (the first proof found),
+    or None: it may match."""
     if node.op is FilterOp.AND:
-        return all(_may_match(c, seg) for c in node.children)
+        for c in node.children:
+            proof = _proof_of_empty(c, seg)
+            if proof is not None:
+                return proof
+        return None
     if node.op is FilterOp.OR:
-        return any(_may_match(c, seg) for c in node.children)
+        proof = None
+        for c in node.children:
+            proof = _proof_of_empty(c, seg)
+            if proof is None:
+                return None
+        return proof
     if node.op is FilterOp.NOT:
-        return True  # negations are not provable from min/max
-    return _predicate_may_match(node.predicate, seg)
+        return None  # negations are not provable from min/max
+    return _predicate_proof(node.predicate, seg)
 
 
-def _predicate_may_match(pred: Predicate, seg) -> bool:
+def _value_proof(seg, cm, v) -> Optional[str]:
+    if not _within_bounds(cm, v):
+        return BY_BOUNDS
+    if not _partition_may_contain(cm, v):
+        return BY_PARTITION
+    if not _bloom_may_contain(seg, cm, v):
+        return BY_BLOOM
+    return None
+
+
+def _predicate_proof(pred: Predicate, seg) -> Optional[str]:
     if not isinstance(pred.lhs, Identifier):
-        return True
+        return None
     cm = seg.metadata.columns.get(pred.lhs.name)
     if cm is None or not cm.single_value:
-        return True
+        return None
     t = pred.type
 
     def conv(v) -> Optional[Any]:
@@ -77,21 +110,22 @@ def _predicate_may_match(pred: Predicate, seg) -> bool:
     if t is PredicateType.EQ:
         v = conv(pred.value)
         if v is None:
-            return True
-        return (_within_bounds(cm, v)
-                and _partition_may_contain(cm, v)
-                and _bloom_may_contain(seg, cm, v))
+            return None
+        return _value_proof(seg, cm, v)
     if t is PredicateType.IN:
         vals = [conv(x) for x in pred.values]
         vals = [v for v in vals if v is not None]
         if not vals:
-            return True
-        return any(_within_bounds(cm, v)
-                   and _partition_may_contain(cm, v)
-                   and _bloom_may_contain(seg, cm, v) for v in vals)
+            return None
+        proof = None
+        for v in vals:
+            proof = _value_proof(seg, cm, v)
+            if proof is None:
+                return None
+        return proof
     if t is PredicateType.RANGE:
-        return _range_overlaps(cm, pred, conv)
-    return True
+        return None if _range_overlaps(cm, pred, conv) else BY_BOUNDS
+    return None
 
 
 def _within_bounds(cm, v) -> bool:

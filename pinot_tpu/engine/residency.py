@@ -281,7 +281,8 @@ class QueryLease:
 
 
 class _Entry:
-    __slots__ = ("resident", "pins", "nbytes", "by_device", "touch")
+    __slots__ = ("resident", "pins", "nbytes", "by_device", "touch",
+                 "stamp")
 
     def __init__(self, resident):
         self.resident = resident
@@ -289,6 +290,9 @@ class _Entry:
         self.nbytes = 0     # all devices together
         self.by_device: Dict[int, int] = {}
         self.touch = 0
+        # the resident's mutation count when by_device was read (a
+        # StagedSegment's; None: a resident that has none is read anew)
+        self.stamp: Optional[int] = None
 
 
 class ResidencyManager:
@@ -981,11 +985,16 @@ class ResidencyManager:
     def _refresh_locked(self) -> None:
         by_device: Dict[int, int] = {}
         for e in self._entries.values():
-            try:
-                e.by_device = _device_bytes_of(e.resident)
-            except Exception:
-                e.by_device = {}
-            e.nbytes = sum(e.by_device.values())
+            # every resident, several times a query: one whose staged
+            # arrays have not changed since it was read keeps its reading
+            stamp = getattr(e.resident, "_mutations", None)
+            if stamp is None or stamp != e.stamp:
+                try:
+                    e.by_device = _device_bytes_of(e.resident)
+                except Exception:
+                    e.by_device = {}
+                e.nbytes = sum(e.by_device.values())
+                e.stamp = stamp
             _add_bytes(by_device, e.by_device)
         total = sum(by_device.values())
         self._staged_by_device = by_device

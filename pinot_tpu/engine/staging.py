@@ -245,6 +245,12 @@ class StagedSegment:
         self._index_slices: "OrderedDict[Any, jnp.ndarray]" = OrderedDict()  # guarded-by-writes: _lock
         self._valid_cache = None  # guarded-by-writes: _lock
         self._lock = threading.Lock()
+        # device_nbytes() is asked for every resident several times a
+        # query; the walk is remembered until a staged array comes or
+        # goes. _mutations is bumped AFTER each such change, so a walk
+        # that raced one is stamped with the older count and walked again
+        self._mutations = 0  # guarded-by-writes: _lock
+        self._nbytes_memo = None  # (mutations, bytes by device)
         # cross-query dedup hook: ``borrower(segment, name)`` may return a
         # StagedColumn built from a resident sharded batch's device copy of
         # the same column (no second H2D, dictvals buffer shared) — wired
@@ -264,6 +270,7 @@ class StagedSegment:
                     if col is None:
                         col = self._stage(name)
                     self._columns[name] = col
+                    self._mutations += 1
         return col
 
     def _promote_column(self, name: str) -> Optional[StagedColumn]:
@@ -330,6 +337,7 @@ class StagedSegment:
                     if pc is None:
                         return None
                     self._packed[name] = pc
+                    self._mutations += 1
         return pc
 
     def _promote_packed(self, name: str) -> Optional["PackedColumn"]:
@@ -381,6 +389,7 @@ class StagedSegment:
                         if hv is not None:
                             v = jnp.asarray(hv)
                             self._values[name] = v
+                            self._mutations += 1
                 if v is not None:
                     return v
             ds = self.segment.data_source(name)
@@ -403,6 +412,7 @@ class StagedSegment:
                     if pad:
                         v = jnp.pad(v, (0, pad))
                     self._values[name] = v
+                    self._mutations += 1
         return v
 
     @staticmethod
@@ -440,6 +450,7 @@ class StagedSegment:
                     planes = [jnp.asarray(v) for v in hv]
                     for k, p in zip(keys, planes):
                         self._values[k] = p
+                    self._mutations += 1
                     return planes
                 for k, v in zip(keys, hv):   # partial image: rebuild cold
                     if v is not None:
@@ -464,6 +475,7 @@ class StagedSegment:
                 planes.append(jnp.asarray(p))
             for k, p in zip(keys, planes):
                 self._values[k] = p
+            self._mutations += 1
         return planes
 
     def startree_nodes(self, tree_index: int) -> Dict[str, jnp.ndarray]:
@@ -482,6 +494,7 @@ class StagedSegment:
                     if t is None:
                         t = self._stage_startree(key)
                     self._startree[key] = t
+                    self._mutations += 1
         return t
 
     def release_startree(self, tree_index: int) -> int:
@@ -494,6 +507,7 @@ class StagedSegment:
         by reference; only the residency accounting lets go here."""
         with self._lock:
             t = self._startree.pop(int(tree_index), None)
+            self._mutations += 1
         if t is None:
             return 0
         return sum(int(getattr(a, "nbytes", 0)) for a in t.values())
@@ -553,6 +567,7 @@ class StagedSegment:
                 self._index_slices[key] = arr
                 while len(self._index_slices) > _INDEX_SLICE_CAP:
                     self._index_slices.popitem(last=False)
+                self._mutations += 1
         return arr
 
     def release_index_slices(self) -> int:
@@ -562,6 +577,7 @@ class StagedSegment:
         with self._lock:
             slices = list(self._index_slices.values())
             self._index_slices.clear()
+            self._mutations += 1
         return sum(int(getattr(a, "nbytes", 0)) for a in slices)
 
     def index_nbytes(self) -> int:
@@ -593,6 +609,7 @@ class StagedSegment:
         arr = jnp.asarray(snap)
         with self._lock:
             self._valid_cache = (ver, arr)
+            self._mutations += 1
         return arr
 
     def device_nbytes(self) -> Dict[int, int]:
@@ -600,7 +617,12 @@ class StagedSegment:
         lie on (HBM accounting for the residency manager): its own arrays
         on the device they were put on, a column borrowed from a sharded
         batch wherever that slice lives. Walks the staged arrays — list()
-        snapshots the dicts against concurrent stagers."""
+        snapshots the dicts against concurrent stagers — once a state of
+        them: the answer is kept until ``_mutations`` moves."""
+        seen = self._mutations
+        memo = self._nbytes_memo
+        if memo is not None and memo[0] == seen:
+            return dict(memo[1])
         into: Dict[int, int] = {}
         for col in list(self._columns.values()):
             for arr in col.tree().values():
@@ -617,7 +639,8 @@ class StagedSegment:
         vc = self._valid_cache
         if vc is not None:
             add_device_bytes(vc[1], into)
-        return into
+        self._nbytes_memo = (seen, into)
+        return dict(into)
 
     def nbytes(self) -> int:
         """All of ``device_nbytes()``: on one device what it always was."""
@@ -682,6 +705,10 @@ class StagedSegment:
             # host image; release drops them outright
             self._index_slices.clear()
             self._valid_cache = None
+            # (a plain assignment: the lint's cache-parity rule wants
+            # every populated field assigned in release())
+            self._mutations = self._mutations + 1
+            self._nbytes_memo = None
             img = self._host_image
             if img is not None:
                 # demote() re-homed anything worth keeping before calling

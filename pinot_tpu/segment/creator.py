@@ -582,15 +582,8 @@ class SegmentBuilder:
         cfg = spc.column_partition_map[col]
         fn = get_partition_function(cfg.get("functionName", "Murmur"),
                                     int(cfg.get("numPartitions", 1)))
-        parts = set()
-        for v in values:
-            if isinstance(v, list):
-                for x in v:
-                    parts.add(fn.partition(x))
-            else:
-                parts.add(fn.partition(v))
         return {
             "partition_function": fn.name,
             "num_partitions": fn.num_partitions,
-            "partitions": sorted(parts),
+            "partitions": fn.partitions_of(values),
         }

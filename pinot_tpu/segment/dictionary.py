@@ -106,6 +106,19 @@ class Dictionary:
         return a, b
 
 
+def needle_for(arr: np.ndarray, v: Any) -> Any:
+    """``v`` as a scalar of ``arr``'s own integer dtype where it fits:
+    numpy copies an int32 array to search it with an int64 or a Python
+    int, which is the whole column (or dictionary) a query."""
+    dt = arr.dtype
+    if (dt.kind in "iu" and isinstance(v, (int, np.integer))
+            and not isinstance(v, bool)):
+        info = np.iinfo(dt)
+        if info.min <= int(v) <= info.max:
+            return dt.type(v)
+    return v
+
+
 class NumericDictionary(Dictionary):
     def __init__(self, values: np.ndarray, data_type: DataType):
         # values must be sorted ascending and unique
@@ -116,13 +129,15 @@ class NumericDictionary(Dictionary):
         return int(self._values.shape[0])
 
     def index_of(self, value: Any) -> int:
-        i = int(np.searchsorted(self._values, value))
+        i = int(np.searchsorted(self._values,
+                                needle_for(self._values, value)))
         if i < len(self._values) and self._values[i] == value:
             return i
         return -1
 
     def insertion_index_of(self, value: Any) -> int:
-        i = int(np.searchsorted(self._values, value))
+        i = int(np.searchsorted(self._values,
+                                needle_for(self._values, value)))
         if i < len(self._values) and self._values[i] == value:
             return i
         return -(i + 1)

@@ -84,6 +84,10 @@ class ServerInstance:
         # segment lifecycle -> HBM residency: adds prefetch, removals evict
         self.data_manager = InstanceDataManager(listener=self)
         residency = getattr(self.executor, "residency", None)
+        # gaps with requests in flight and none done (/debug/scheduler)
+        from pinot_tpu.server.stall import StallWatch
+
+        self.stall_watch = StallWatch(residency=residency)
         if residency is not None:
             residency.bind_metrics(self.metrics)
         # launch-coalescing meters/gauges (sharded executors only)
@@ -140,6 +144,7 @@ class ServerInstance:
             self._reconcile_table(table)
         self._started = True
         self._queries_enabled = True
+        self.stall_watch.start()
         if heartbeat_interval_s > 0:
             # the ephemeral-znode keepalive: the controller's liveness
             # check marks us dead when these stop
@@ -169,6 +174,7 @@ class ServerInstance:
             # resurrect the instance (touch sets alive=True)
             self._hb_thread.join(timeout=5)
         self.scheduler.shutdown()
+        self.stall_watch.stop()
         self.data_manager.shutdown()
         close = getattr(self.executor, "close", None)
         if close is not None:
@@ -410,12 +416,16 @@ class ServerInstance:
             return DataTable.for_exception(
                 f"server {self.instance_id} is shut down")
         submit_t = time.perf_counter()
-        # the shape key feeds the SEWF policy's per-shape latency EWMAs:
-        # same table + same SQL text = same expected work
-        future = self.scheduler.submit(
-            lambda: self._execute(ctx, table, segment_names, submit_t),
-            table=table, shape=(table, ctx.sql))
-        return future.result()
+        self.stall_watch.begin()
+        try:
+            # the shape key feeds the SEWF policy's per-shape latency
+            # EWMAs: same table + same SQL text = same expected work
+            future = self.scheduler.submit(
+                lambda: self._execute(ctx, table, segment_names, submit_t),
+                table=table, shape=(table, ctx.sql))
+            return future.result()
+        finally:
+            self.stall_watch.end()
 
     def _execute(self, ctx: QueryContext, table: str,
                  segment_names: Optional[List[str]],
@@ -584,7 +594,8 @@ class ServerInstance:
         policy + queue depth, admission bounds/counters, and the
         per-segment kernel single-flight counters — the millions-of-users
         ops view."""
-        out: Dict[str, Any] = {"scheduler": self.scheduler.stats_snapshot()}
+        out: Dict[str, Any] = {"scheduler": self.scheduler.stats_snapshot(),
+                               "stallWatch": self.stall_watch.snapshot()}
         admission = getattr(self.executor, "admission", None)
         if admission is not None:
             out["admission"] = admission.snapshot()

@@ -92,6 +92,7 @@ THREAD_NAME_ROLES: Tuple[Tuple[str, str], ...] = (
     ("combine-launch", "dispatcher"),
     ("hbm-prefetch", "prefetch"),
     ("telemetry-sampler", "sampler"),
+    ("stall-watch", "sampler"),
     ("heartbeat", "sampler"),
     ("controller-periodic", "sampler"),
     ("state-replica-poller", "writer"),

@@ -9,7 +9,9 @@ with Kafka-partitioned streams.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, List
+
+import numpy as np
 
 
 def _murmur2(data: bytes) -> int:
@@ -61,6 +63,26 @@ class PartitionFunction:
 
     def partition(self, value: Any) -> int:
         return self._fn(value, self.num_partitions)
+
+    def partitions_of(self, values) -> List[int]:
+        """The sorted distinct partitions of a column's values (an MV
+        column's rows are lists): what ``sorted({partition(v) for v})``
+        gives, without a Python call a row. The function is applied to the
+        distinct values alone, and ``Modulo`` over an integer array is one
+        array operation (numpy's ``%`` takes the divisor's sign, as
+        Python's does)."""
+        if isinstance(values, np.ndarray) and values.dtype.kind in "iu":
+            distinct = np.unique(values)
+            if self._fn is _modulo_partition:
+                return np.unique(distinct.astype(np.int64)
+                                 % self.num_partitions).tolist()
+            distinct = distinct.tolist()
+        else:
+            if isinstance(values, np.ndarray):
+                values = values.tolist()
+            distinct = {x for v in values
+                        for x in (v if isinstance(v, list) else (v,))}
+        return sorted({self.partition(v) for v in distinct})
 
 
 def _murmur_partition(value: Any, n: int) -> int:

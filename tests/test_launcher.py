@@ -560,7 +560,8 @@ LAUNCHES_KEYS = {
     "enabled", "requests", "launches", "coalescedLaunches", "launchesSaved",
     "dedupedRequests", "failures", "maxBatchSize", "queueWaitMsTotal",
     "queueWaitMsMax", "queued", "dispatcherAlive"}
-SCHEDULER_KEYS = {"scheduler", "admission", "kernelFlight", "queryFlight"}
+SCHEDULER_KEYS = {"scheduler", "admission", "kernelFlight", "queryFlight",
+                  "stallWatch"}
 
 
 @pytest.mark.parametrize("method, keys", [

@@ -178,9 +178,10 @@ class ServerQueryExecutor:
         # last kernel-preflight verdict table run against this executor
         # (tools/preflight.attach_verdicts); surfaced on GET /debug/pallas
         self.preflight_verdicts: Optional[dict] = None
-        # fused-scan launches by accumulate form (GET /debug/pallas
-        # ``launches``)
-        self._pallas_launches = {"single": 0, "two_level": 0}  # guarded-by: _pallas_launches_lock
+        # fused-scan launches by accumulate form, group-range probes under
+        # ``scalar`` (GET /debug/pallas ``launches``)
+        self._pallas_launches = {  # guarded-by: _pallas_launches_lock
+            "single": 0, "two_level": 0, "scalar": 0}
         self._pallas_launches_lock = threading.Lock()
         self._segment_pool = None
         self._segment_pool_lock = threading.Lock()
@@ -259,15 +260,21 @@ class ServerQueryExecutor:
         the caller shared another query's."""
         from pinot_tpu.engine.pallas_kernels import (
             accumulate_kind,
+            builds_one_hot,
             padded_groups,
         )
 
+        _, agg_specs, group_specs, _, _ = plan_spec
         groups = padded_groups(plan_spec)
-        kind = accumulate_kind(groups)
+        kind = accumulate_kind(groups, builds_one_hot(
+            bool(group_specs), (a[0] for a in agg_specs)))
         if count:
-            with self._pallas_launches_lock:
-                self._pallas_launches[kind] += 1
+            self._count_pallas_launch(kind)
         return {"groups": groups, "accumulate": kind}
+
+    def _count_pallas_launch(self, kind: str) -> None:
+        with self._pallas_launches_lock:
+            self._pallas_launches[kind] += 1
 
     def pallas_launches(self) -> Dict[str, int]:
         with self._pallas_launches_lock:
@@ -1069,7 +1076,8 @@ class ServerQueryExecutor:
             with maybe_span(stats, "Dispatch"):
                 served = pallas_kernels.run_segment(
                     plan, staged, self.pallas_kernels, interpret,
-                    on_decline=declined, lut_run_cap=self._pallas_lut_runs)
+                    on_decline=declined, lut_run_cap=self._pallas_lut_runs,
+                    on_probe=self._count_pallas_launch)
             if served is None:
                 return None
             packed, eff = served

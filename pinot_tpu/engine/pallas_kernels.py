@@ -630,9 +630,25 @@ def padded_groups(plan_spec: Tuple) -> int:
     return -(-num_groups // _G_CHUNK) * _G_CHUNK
 
 
-def accumulate_kind(num_groups_padded: int) -> str:
+def builds_one_hot(grouped: bool, agg_bases) -> bool:
+    """Whether the kernel accumulates through the key's one-hot matmul. A
+    scalar key space (no group columns, every key 0) with no sum rows
+    builds none: its count is the tile's mask sum and its min/max rows
+    reduce over the tile (the group-range probe is this case)."""
+    return grouped or any(b in ("sum", "avg") for b in agg_bases)
+
+
+def accumulate_kind(num_groups_padded: int, one_hot: bool) -> str:
     """What a span or counter calls the accumulate a spec takes."""
+    if not one_hot:
+        return "scalar"
     return "single" if num_groups_padded <= _G_CHUNK else "two_level"
+
+
+def spec_accumulate_kind(spec: PallasSpec) -> str:
+    """``accumulate_kind`` of the kernel ``build_kernel(spec)`` builds."""
+    return accumulate_kind(spec.num_groups_padded, builds_one_hot(
+        bool(spec.group_idx), (base for base, _v, _l in spec.aggs)))
 
 
 def build_kernel(spec: PallasSpec):
@@ -643,6 +659,10 @@ def build_kernel(spec: PallasSpec):
     RT = T // 128
     G = spec.num_groups_padded
     H, Hp, rows_per_dot = accumulate_rows(G)
+    # a scalar key space (no group columns, every key 0) reduces min/max
+    # rows over the tile, and with no sum rows builds no one-hot at all
+    scalar = not spec.group_idx
+    one_hot = spec_accumulate_kind(spec) != "scalar"
     n_packed = len(spec.packed_bits)
     n_values = len(spec.value_is_int)
     # per value input: how many refs it occupies (1 plain array, or L
@@ -805,80 +825,97 @@ def build_kernel(spec: PallasSpec):
         m_i = mask.astype(jnp.int32)
         out_seg[0] += sum(m_i[r:r + 8] for r in range(0, RT, 8))
 
-        # -- matmul row stack [nf + 1 + sum(L), T] f32 (docs flattened)
-        rows = []
-        for vexpr, _r in float_sums:
-            rows.append(emit_vexpr(vexpr).astype(jnp.float32) * mask_f)
-        rows.append(mask_f)                        # count row (out_i row 0)
-        for vexpr, (start, L) in int_sums:
-            if vexpr[0] == "v64":
-                # i64-staged column: the limb rows ARE the staged planes
-                # (host-split with the identical shift/mask scheme), so the
-                # accumulation below is bit-for-bit the in-kernel split
-                base_ref = v_start[vexpr[1]]
+        if not one_hot:
+            # -- count row: the tile's exact mask sum, as per-lane
+            # partials the wrapper folds (no one-hot, no matmul)
+            out_i[acc(0)] += m_i.sum(axis=0, keepdims=True)
+        else:
+            # -- matmul row stack [nf + 1 + sum(L), T] f32 (docs flattened)
+            rows = []
+            for vexpr, _r in float_sums:
+                rows.append(emit_vexpr(vexpr).astype(jnp.float32) * mask_f)
+            rows.append(mask_f)                    # count row (out_i row 0)
+            for vexpr, (start, L) in int_sums:
+                if vexpr[0] == "v64":
+                    # i64-staged column: the limb rows ARE the staged planes
+                    # (host-split with the identical shift/mask scheme), so the
+                    # accumulation below is bit-for-bit the in-kernel split
+                    base_ref = v_start[vexpr[1]]
+                    for k in range(L):
+                        plane = values[base_ref + k][0, 0]
+                        rows.append(jnp.where(mask, plane, 0)
+                                    .astype(jnp.float32))
+                    continue
+                v = jnp.where(mask, emit_vexpr(vexpr), 0)
                 for k in range(L):
-                    plane = values[base_ref + k][0, 0]
-                    rows.append(jnp.where(mask, plane, 0)
-                                .astype(jnp.float32))
-                continue
-            v = jnp.where(mask, emit_vexpr(vexpr), 0)
-            for k in range(L):
-                if k < L - 1:
-                    limb = (v >> (k * _LIMB_BITS)) & _LIMB_MASK
-                else:
-                    limb = v >> (k * _LIMB_BITS)   # top limb keeps the sign
-                rows.append(limb.astype(jnp.float32))
-        R = jnp.stack(rows).reshape(len(rows), T)  # [M_mat, T]
+                    if k < L - 1:
+                        limb = (v >> (k * _LIMB_BITS)) & _LIMB_MASK
+                    else:
+                        limb = v >> (k * _LIMB_BITS)  # top limb keeps sign
+                    rows.append(limb.astype(jnp.float32))
+            R = jnp.stack(rows).reshape(len(rows), T)  # [M_mat, T]
 
-        # -- two-level one-hot accumulate: group g = hi * 128 + lo. ONE
-        # [T, 128] one-hot of ``lo`` a tile; ``hi`` expands every matmul
-        # row into Hp rows (row m*Hp + h keeps the docs whose hi == h), so
-        # part[m*Hp + h, l] is row m's partial of group h*128 + l and the
-        # MXU sees a full-height LHS once, not M rows against G/128
-        # one-hots. H == 1 (scalar aggregations, <= 128 groups) needs no
-        # expansion: the rows go in as they are. A plain 2-D matmul over
-        # the tile's flattened docs: Mosaic has no dot_general with two
-        # contracting dims, and takes the (RT, 128) -> T flattening as a
-        # relayout. HIGHEST keeps every MXU pass f32 (the limb/f32-
-        # exactness argument above: an expanded row holds limb values or 0)
-        lo = keys if H == 1 else keys & (_G_CHUNK - 1)
-        oh_lo = (lo[:, :, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (RT, 128, _G_CHUNK), 2)
-        ).astype(jnp.float32).reshape(T, _G_CHUNK)
-        if H > 1:
-            # masked docs outside a narrowed key range: an arithmetic
-            # shift leaves hi negative or >= H, which selects no row (or a
-            # pad row h in [H, Hp) that the wrapper drops; their values
-            # are mask-zeroed anyway)
-            hi = (keys >> 7).reshape(1, T)
-            sel = hi == jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 0)
+            # -- two-level one-hot accumulate: group g = hi * 128 + lo. ONE
+            # [T, 128] one-hot of ``lo`` a tile; ``hi`` expands every matmul
+            # row into Hp rows (row m*Hp + h keeps the docs whose hi == h), so
+            # part[m*Hp + h, l] is row m's partial of group h*128 + l and the
+            # MXU sees a full-height LHS once, not M rows against G/128
+            # one-hots. H == 1 (scalar aggregations, <= 128 groups) needs no
+            # expansion: the rows go in as they are. A plain 2-D matmul over
+            # the tile's flattened docs: Mosaic has no dot_general with two
+            # contracting dims, and takes the (RT, 128) -> T flattening as a
+            # relayout. HIGHEST keeps every MXU pass f32 (the limb/f32-
+            # exactness argument above: an expanded row holds limb values or 0)
+            lo = keys if H == 1 else keys & (_G_CHUNK - 1)
+            oh_lo = (lo[:, :, None] == jax.lax.broadcasted_iota(
+                jnp.int32, (RT, 128, _G_CHUNK), 2)
+            ).astype(jnp.float32).reshape(T, _G_CHUNK)
+            if H > 1:
+                # masked docs outside a narrowed key range: an arithmetic
+                # shift leaves hi negative or >= H, which selects no row (or a
+                # pad row h in [H, Hp) that the wrapper drops; their values
+                # are mask-zeroed anyway)
+                hi = (keys >> 7).reshape(1, T)
+                sel = hi == jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 0)
 
-        for m0 in range(0, len(rows), rows_per_dot):
-            m1 = min(m0 + rows_per_dot, len(rows))
-            lhs = R[m0:m1] if H == 1 else jnp.concatenate(
-                [jnp.where(sel, R[m:m + 1], 0.0) for m in range(m0, m1)],
-                axis=0)
-            part = jnp.dot(lhs, oh_lo,
-                           precision=jax.lax.Precision.HIGHEST,
-                           preferred_element_type=jnp.float32)
-            for m in range(m0, m1):
-                x = part[(m - m0) * Hp:(m - m0 + 1) * Hp]   # [Hp, 128]
-                kind, r = row_target[m]
-                if kind == "f":
-                    # float sums: Neumaier-compensated (sum, comp) pair
-                    a = out_f[acc(r)]
-                    t_ = a + x
-                    err = jnp.where(jnp.abs(a) >= jnp.abs(x),
-                                    (a - t_) + x, (x - t_) + a)
-                    out_f[acc(r)] = t_
-                    out_f[acc(r + 1)] += err
-                else:
-                    # count + int limb partials: f32 -> exact i32 (every
-                    # partial is an integer < 2^24 by the limb-width bound)
-                    out_i[acc(r)] += x.astype(jnp.int32)
+            for m0 in range(0, len(rows), rows_per_dot):
+                m1 = min(m0 + rows_per_dot, len(rows))
+                lhs = R[m0:m1] if H == 1 else jnp.concatenate(
+                    [jnp.where(sel, R[m:m + 1], 0.0) for m in range(m0, m1)],
+                    axis=0)
+                part = jnp.dot(lhs, oh_lo,
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+                for m in range(m0, m1):
+                    x = part[(m - m0) * Hp:(m - m0 + 1) * Hp]   # [Hp, 128]
+                    kind, r = row_target[m]
+                    if kind == "f":
+                        # float sums: Neumaier-compensated (sum, comp) pair
+                        a = out_f[acc(r)]
+                        t_ = a + x
+                        err = jnp.where(jnp.abs(a) >= jnp.abs(x),
+                                        (a - t_) + x, (x - t_) + a)
+                        out_f[acc(r)] = t_
+                        out_f[acc(r + 1)] += err
+                    else:
+                        # count + int limb partials: f32 -> exact i32 (every
+                        # partial is an integer < 2^24 by the limb-width bound)
+                        out_i[acc(r)] += x.astype(jnp.int32)
+
+        if scalar:
+            # -- min/max rows of a scalar key space: per-lane partials
+            # over the tile's sublanes, folded by the wrapper
+            for (vexpr, kind), r in mm_row.items():
+                neutral = _POS if kind == "min" else _NEG
+                vm = jnp.where(mask, emit_vexpr(vexpr).astype(jnp.float32),
+                               neutral)
+                red = vm.min(axis=0) if kind == "min" else vm.max(axis=0)
+                cur = out_mm[r, :]
+                out_mm[r, :] = (jnp.minimum(cur, red) if kind == "min"
+                                else jnp.maximum(cur, red))
 
         # -- min/max rows reduce on the VPU per 128-group chunk
-        for c in range(H if mm_row else 0):
+        for c in range(H if mm_row and not scalar else 0):
             g0 = c * _G_CHUNK
             eq = keys[:, :, None] == g0 + jax.lax.broadcasted_iota(
                 jnp.int32, (RT, 128, _G_CHUNK), 2)
@@ -943,6 +980,10 @@ def build_kernel(spec: PallasSpec):
         interpret=spec.interpret,
     )
 
+    mm_is_min = np.zeros((Mm, 1), dtype=bool)
+    for (_vexpr, kind), r in mm_row.items():
+        mm_is_min[r] = kind == "min"
+
     def pallas_scan(params, *cols):
         """-> (out_f [Mf, G], out_i [Mi, G], out_mm [Mm, G], out_seg
         [S, 128]). Kernel body and index maps trace with 32-bit defaults:
@@ -950,13 +991,21 @@ def build_kernel(spec: PallasSpec):
         a 64-bit literal, Mosaic has no 64 -> 32 conversion and wants i32
         from an index map (every operand is already a 32-bit array). The
         kernel's [rows * Hp, 128] sum/count accumulators are [rows, G]
-        read row-major (less the pad rows h >= H)."""
+        read row-major (less the pad rows h >= H). A scalar key space's
+        per-lane partials fold here to one lane: out_mm [Mm, 1], and
+        out_i [Mi, 1] where no one-hot counted."""
         with jax.enable_x64(False):
             out_f, out_i, out_mm, out_seg = fused(params.reshape(1, -1),
                                                   *cols)
-            return (out_f.reshape(Mf, Hp * _G_CHUNK)[:, :G],
-                    out_i.reshape(Mi, Hp * _G_CHUNK)[:, :G],
-                    out_mm, out_seg.sum(axis=1))
+            out_f = out_f.reshape(Mf, Hp * _G_CHUNK)[:, :G]
+            out_i = out_i.reshape(Mi, Hp * _G_CHUNK)[:, :G]
+            if not one_hot:
+                out_i = out_i.sum(axis=1, keepdims=True)
+            if scalar and mm_row:
+                out_mm = jnp.where(mm_is_min,
+                                   out_mm.min(axis=1, keepdims=True),
+                                   out_mm.max(axis=1, keepdims=True))
+            return out_f, out_i, out_mm, out_seg.sum(axis=1)
 
     return pallas_scan
 
@@ -1096,8 +1145,10 @@ def _segment_params(pp: PallasPlan, staged: StagedSegment):
 
 
 def _run_probe_segment(probe_pp: PallasPlan, staged: StagedSegment,
-                       cache: PallasKernelCache, interpret: bool, decline):
-    """Launch the group-range probe over one staged segment -> out_mm."""
+                       cache: PallasKernelCache, interpret: bool, decline,
+                       on_launch=None):
+    """Launch the group-range probe over one staged segment -> out_mm;
+    ``on_launch`` receives the launch's accumulate kind."""
     got = _stage_packed(probe_pp, staged, decline)
     if got is None:
         return None
@@ -1113,19 +1164,22 @@ def _run_probe_segment(probe_pp: PallasPlan, staged: StagedSegment,
     except Exception:
         cache.pop(spec)
         raise
+    if on_launch is not None:
+        on_launch(spec_accumulate_kind(spec))
     return out_mm
 
 
 def run_segment(plan, staged: StagedSegment, cache: PallasKernelCache,
                 interpret: bool, on_decline=None,
-                lut_run_cap: int = DEFAULT_LUT_RUN_CAP):
+                lut_run_cap: int = DEFAULT_LUT_RUN_CAP, on_probe=None):
     """Run the fused kernel over one staged segment; returns
     ``(packed, effective_plan)`` — the PACKED f64 output vector
     (kernels.pack_outputs layout, single D2H fetch) plus the plan whose
     spec describes it (the original plan, or the probe-narrowed plan for
     large-group shapes; the caller MUST unpack/decode against it) — or
     None when the plan/staging isn't eligible (``on_decline`` receives the
-    reason code, same contract as ``extract_plan``)."""
+    reason code, same contract as ``extract_plan``). ``on_probe`` receives
+    the accumulate kind of each group-range probe launched."""
     from pinot_tpu.engine.kernels import pack_outputs
 
     def decline(reason: str) -> None:
@@ -1143,7 +1197,7 @@ def run_segment(plan, staged: StagedSegment, cache: PallasKernelCache,
 
         def run_probe(probe_pp):
             return _run_probe_segment(probe_pp, staged, cache, interpret,
-                                      decline)
+                                      decline, on_probe)
 
         res = probe_narrowed_plan(plan, staged.segment, run_probe,
                                   lut_run_cap, decline)

@@ -636,6 +636,7 @@ class ShardedQueryExecutor(ServerQueryExecutor):
             _DeferredDecline,
             extract_plan,
             probe_narrowed_plan,
+            spec_accumulate_kind,
         )
         from pinot_tpu.parallel.combine import (
             build_sharded_pallas_kernel,
@@ -699,7 +700,9 @@ class ShardedQueryExecutor(ServerQueryExecutor):
                                      NamedSharding(self.mesh, P()))
             req = self.launcher.submit(probe_kernel, pparams,
                                        self._device_num_docs(batch, S))
-            return np.asarray(req.result())
+            out_mm = np.asarray(req.result())
+            self._count_pallas_launch(spec_accumulate_kind(probe_spec))
+            return out_mm
 
         eff = plan
         defer = _DeferredDecline(declined)

@@ -659,7 +659,8 @@ class ServerInstance:
         preflight-seeded predictions) plus the last preflight verdict
         table run against this executor (tools/preflight.py), and
         ``launches``: fused-scan launches by the accumulate they took
-        (``single``: at most 128 groups; ``two_level``: more). A chip
+        (``single``: at most 128 groups; ``two_level``: more; ``scalar``:
+        no one-hot at all, every group-range probe among them). A chip
         that fell over mid-round keeps its lessons visible here — and,
         with ``pinot.server.query.pallas.blocklist.path`` set, across
         restarts."""

@@ -171,7 +171,11 @@ def _vmem_estimate(spec, model: LoweringModel) -> int:
     accumulate, min-max select buffers). Mirrors
     pallas_kernels.build_kernel's layout via the same ``_row_layout`` and
     ``accumulate_rows``."""
-    from pinot_tpu.engine.pallas_kernels import _row_layout, accumulate_rows
+    from pinot_tpu.engine.pallas_kernels import (
+        _row_layout,
+        accumulate_rows,
+        spec_accumulate_kind,
+    )
 
     T = PALLAS_TILE
     _fsum, isum, mm_row, Mf, Mi, Mm = _row_layout(spec)
@@ -194,20 +198,24 @@ def _vmem_estimate(spec, model: LoweringModel) -> int:
     # count rows as [rows * Hp, 128], min/max rows as [Mm, G]
     total += (Mf + Mi) * Hp * model.lane * 4 + Mm * G * 4
     total += model.sublane_f32 * model.lane * 4  # out_seg block (1, 8, 128)
-    # matmul row stack R [M_mat, T] f32
-    n_limb_rows = sum(L for (_s, L) in isum.values())
-    m_mat = (Mf // 2) + 1 + n_limb_rows
-    total += m_mat * T * 4
-    # the tile's one-hot: lane iota + oh_lo [RT, 128, 128]
-    total += 2 * T * model.lane * 4
-    if H > 1:
-        # the hi-select mask [Hp, T] and one expanded LHS block with its
-        # [rows, 128] partial (at most _EXPAND_ROWS rows, whole R rows)
-        rows = min(m_mat, rows_per_dot) * Hp
-        total += Hp * T * 4 + rows * (T + model.lane) * 4
-    # min/max select buffers (eq + v3) when mm rows exist
-    if mm_row:
+    if spec_accumulate_kind(spec) != "scalar":
+        # matmul row stack R [M_mat, T] f32
+        n_limb_rows = sum(L for (_s, L) in isum.values())
+        m_mat = (Mf // 2) + 1 + n_limb_rows
+        total += m_mat * T * 4
+        # the tile's one-hot: lane iota + oh_lo [RT, 128, 128]
         total += 2 * T * model.lane * 4
+        if H > 1:
+            # the hi-select mask [Hp, T] and one expanded LHS block with
+            # its [rows, 128] partial (at most _EXPAND_ROWS rows, whole R
+            # rows)
+            rows = min(m_mat, rows_per_dot) * Hp
+            total += Hp * T * 4 + rows * (T + model.lane) * 4
+    if mm_row:
+        # min/max select buffers: eq + v3 over a chunk of groups, or one
+        # masked [RT, 128] tile where the key space is scalar
+        total += (T * 4 if not spec.group_idx
+                  else 2 * T * model.lane * 4)
     return total
 
 
@@ -350,13 +358,14 @@ def _mk_spec(num_segs=1, tiles=3, bits=(8,), filter_tree=("true",),
              n_slots=0, groups=128, aggs=(("count", None, None),),
              value_is_int=(), value_limbs=()):
     """A hand-built PallasSpec for the fuzz grid (remainder-tile default:
-    tiles=3 models a capacity % PALLAS_TILE != 0 segment)."""
+    tiles=3 models a capacity % PALLAS_TILE != 0 segment), grouped on
+    packed column 0 so that every shape takes the one-hot accumulate."""
     from pinot_tpu.engine.pallas_kernels import PallasSpec
 
     return PallasSpec(
         num_segs=num_segs, tiles_per_seg=tiles, packed_bits=tuple(bits),
-        filter_tree=filter_tree, n_slots=n_slots, group_idx=(),
-        group_strides=(), group_key_offset=0, num_groups_padded=groups,
+        filter_tree=filter_tree, n_slots=n_slots, group_idx=(0,),
+        group_strides=(1,), group_key_offset=0, num_groups_padded=groups,
         aggs=tuple(aggs), value_is_int=tuple(value_is_int),
         value_limbs=tuple(value_limbs), interpret=True)
 

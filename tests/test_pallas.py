@@ -480,6 +480,7 @@ def test_pallas_launch_counter_loses_no_update_under_threads():
 
     ex = ServerQueryExecutor(use_device=False)
     scalar = (("true",), (("count", False, None),), (), 1, None)
+    summed = (("true",), (("sum", False, ("col", "q")),), (), 1, None)
     grouped = (("true",), (("count", False, None),), (("gdict", "c"),),
                4000, None)
     n_threads, each = 2 * (os.cpu_count() or 4), 500
@@ -487,6 +488,7 @@ def test_pallas_launch_counter_loses_no_update_under_threads():
     def work():
         for _ in range(each):
             ex._note_pallas_launch(scalar)
+            ex._note_pallas_launch(summed)
             ex._note_pallas_launch(grouped)
 
     threads = [threading.Thread(target=work) for _ in range(n_threads)]
@@ -501,4 +503,152 @@ def test_pallas_launch_counter_loses_no_update_under_threads():
         sys.setswitchinterval(old)
     assert not any(t.is_alive() for t in threads)
     assert ex.pallas_launches() == {"single": n_threads * each,
-                                    "two_level": n_threads * each}
+                                    "two_level": n_threads * each,
+                                    "scalar": n_threads * each}
+
+
+# -- a scalar key space: no one-hot, min/max reduced over the tile ---------
+
+# the group-range probe of two SSB flights over synthetic columns whose
+# dictionaries nest as SSB's do (a city id is nation * 10 + r, a brand id
+# category * 40 + r): (bits, cardinality, parent column or None) per packed
+# column, the filter's (column, lo, hi) intervals, the group columns
+_PROBE_SHAPES = {
+    "Q3.2": dict(cols=[(8, 25, None), (8, 25, None), (4, 7, None),
+                       (8, 250, 0), (8, 250, 1)],
+                 filter=[(0, 24, 24), (1, 24, 24), (2, 0, 5)],
+                 groups=(3, 4, 2)),
+    "Q4.3": dict(cols=[(8, 25, None), (4, 7, None), (8, 25, None),
+                       (8, 250, 0), (16, 1000, 2)],
+                 filter=[(0, 24, 24), (1, 5, 6), (2, 3, 3)],
+                 groups=(1, 3, 4)),
+    # no nation 30: nothing matches, every range collapses to (0, 0)
+    "nothing_matches": dict(cols=[(8, 25, None), (8, 25, None),
+                                  (4, 7, None), (8, 250, 0), (8, 250, 1)],
+                            filter=[(0, 30, 30), (1, 24, 24), (2, 0, 5)],
+                            groups=(3, 4, 2)),
+}
+_PROBE_SEG_DOCS = _SEG_DOCS + (2 * PALLAS_TILE,)
+
+
+def _check_probe_case(shape):
+    """The probe kernel over three segments (two with a partial last tile
+    whose invalid docs match the filter and widen every group range):
+    its ranges are numpy's min/max of the group dictIds under the filter,
+    its count and per-segment counts the filter's."""
+    import jax
+
+    from pinot_tpu.engine.pallas_kernels import (
+        PallasSpec,
+        build_kernel,
+        decode_probe_ranges,
+    )
+
+    case = _PROBE_SHAPES[shape]
+    S, TPS, T = len(_PROBE_SEG_DOCS), 2, PALLAS_TILE
+    rng = np.random.default_rng(len(shape) * 7 + 3)
+    doc = np.arange(TPS * T).reshape(TPS, T)
+    valid = np.stack([doc < n for n in _PROBE_SEG_DOCS])
+    ids = []
+    for _bits, card, parent in case["cols"]:
+        if parent is None:
+            ids.append(rng.integers(0, card, (S, TPS, T)))
+        else:
+            fan = card // case["cols"][parent][1]
+            ids.append(ids[parent] * fan + rng.integers(0, fan, (S, TPS, T)))
+    mask = valid.copy()
+    params = []
+    for ci, lo, hi in case["filter"]:
+        # the invalid tail matches the filter with ids that span its
+        # group columns' whole dictionaries
+        ids[ci] = np.where(valid, ids[ci], lo)
+        mask &= (ids[ci] >= lo) & (ids[ci] <= hi)
+        params += [lo, hi]
+    for gi in case["groups"]:
+        card = case["cols"][gi][1]
+        ids[gi] = np.where(valid, ids[gi],
+                           rng.integers(0, card, ids[gi].shape))
+    aggs = []
+    for gi in case["groups"]:
+        aggs += [("min", ("id", gi), None), ("max", ("id", gi), None)]
+    spec = PallasSpec(
+        num_segs=S, tiles_per_seg=TPS,
+        packed_bits=tuple(b for b, _c, _p in case["cols"]),
+        filter_tree=("and", tuple(("iv", ci, k) for k, (ci, _l, _h)
+                                  in enumerate(case["filter"]))),
+        n_slots=len(case["filter"]), group_idx=(), group_strides=(),
+        group_key_offset=0, num_groups_padded=128, aggs=tuple(aggs),
+        value_is_int=(), interpret=True)
+    params = np.asarray(params + list(_PROBE_SEG_DOCS) + [0],
+                        dtype=np.int32)
+    cols = [_pack_planar(x, b) for x, (b, _c, _p) in zip(ids, case["cols"])]
+    _f, out_i, out_mm, out_seg = jax.jit(build_kernel(spec))(params, *cols)
+    want = [(int(ids[gi][mask].min()), int(ids[gi][mask].max()))
+            if mask.any() else (0, 0) for gi in case["groups"]]
+    assert decode_probe_ranges(spec, out_mm, len(want)) == want
+    assert np.asarray(out_i).shape == (1, 1)
+    assert int(np.asarray(out_i)[0, 0]) == int(mask.sum())
+    np.testing.assert_array_equal(np.asarray(out_seg).sum(axis=1),
+                                  mask.reshape(S, -1).sum(axis=1))
+    if shape != "nothing_matches":
+        # the tail would have widened the ranges had it counted
+        assert mask.sum() > 0 and any(
+            ids[gi][~valid].min() < lo or ids[gi][~valid].max() > hi
+            for gi, (lo, hi) in zip(case["groups"], want))
+
+
+# scalar MIN / MAX / COUNT with and without a SUM beside them: the
+# accumulate each takes (no sum: no one-hot at all)
+_SCALAR_SQL = {
+    "min_max_count": (
+        "SELECT min(qty), max(year), count(*) FROM pl_sales "
+        "WHERE region = 'east'", "scalar"),
+    "minmaxrange_min_price": (
+        "SELECT minmaxrange(year), min(price), max(price) FROM pl_sales "
+        "WHERE city BETWEEN 'c010' AND 'c040'", "scalar"),
+    "min_max_count_sum": (
+        "SELECT min(price), max(qty), count(*), sum(qty) FROM pl_sales "
+        "WHERE year >= 2010", "single"),
+    "min_avg": (
+        "SELECT min(year), avg(price) FROM pl_sales "
+        "WHERE region != 'west' AND year < 2020", "single"),
+}
+
+
+@pytest.fixture(scope="module")
+def scalar_sharded_exec():
+    from pinot_tpu.parallel import ShardedQueryExecutor
+
+    return ShardedQueryExecutor(use_pallas=True)
+
+
+def _check_scalar_sql(name, setup, host_exec, sharded):
+    """Per segment and sharded, the fused kernel serves the query with the
+    accumulate it should take and answers as the host engine does."""
+    _, segs = setup
+    sql, kind = _SCALAR_SQL[name]
+    want, _ = host_exec.execute(compile_query(sql), segs)
+    per_seg = ServerQueryExecutor(use_device=True, use_pallas=True)
+    for ex in (per_seg, sharded):
+        before = ex.pallas_launches()
+        got, _ = ex.execute(compile_query(sql), segs)
+        after = ex.pallas_launches()
+        assert {k: after[k] - before[k] for k in after if after[k] > before[k]
+                } == {kind: (len(segs) if ex is per_seg else 1)}, (name, ex)
+        assert len(got.rows) == len(want.rows) == 1
+        for g, w in zip(got.rows[0], want.rows[0]):
+            assert g == pytest.approx(w, rel=1e-6), (name, got.rows, want.rows)
+
+
+@pytest.mark.parametrize("case", [f"probe_{k}" for k in _PROBE_SHAPES]
+                         + [f"sql_{k}" for k in _SCALAR_SQL])
+def test_scalar_key_space_answers(case, setup, host_exec,
+                                  scalar_sharded_exec):
+    """A scalar key space reduces min/max over the tile and counts by the
+    mask sum: the group-range probe's ranges, and scalar MIN / MAX /
+    COUNT queries beside a SUM or not, answer as before."""
+    kind, name = case.split("_", 1)
+    if kind == "probe":
+        _check_probe_case(name)
+    else:
+        _check_scalar_sql(name, setup, host_exec, scalar_sharded_exec)

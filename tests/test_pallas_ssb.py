@@ -181,7 +181,7 @@ def test_sharded_span_and_counter_say_which_accumulate(ssb_segs, qid,
             SimpleNamespace(executor=ex))["launches"]
 
     dev = ShardedQueryExecutor(use_pallas=True)
-    assert launches(dev) == {"single": 0, "two_level": 0}
+    assert launches(dev) == {"single": 0, "two_level": 0, "scalar": 0}
     sql = (ssb.QUERIES[qid].replace("d_year = 1993", "d_year >= 1992")
            + " LIMIT 100000 OPTION(useStarTree=false, trace=true)")
     _got, stats = dev.execute(compile_query(sql), ssb_segs)
@@ -191,7 +191,7 @@ def test_sharded_span_and_counter_say_which_accumulate(ssb_segs, qid,
     assert (spec.num_groups_padded > 128) is (accumulate == "two_level")
     assert (combine["kernel"], combine["groups"], combine["accumulate"]) \
         == ("pallas", spec.num_groups_padded, accumulate)
-    want = {"single": 0, "two_level": 0, accumulate: 1}
+    want = {"single": 0, "two_level": 0, "scalar": 0, accumulate: 1}
     assert launches(dev) == want
 
     jnp_only = ShardedQueryExecutor(use_pallas=False)
@@ -199,7 +199,8 @@ def test_sharded_span_and_counter_say_which_accumulate(ssb_segs, qid,
     combine = {e["operator"]: e
                for e in flatten_spans(stats.spans)}["ShardedCombine"]
     assert combine["kernel"] == "jnp" and "accumulate" not in combine
-    assert launches(jnp_only) == {"single": 0, "two_level": 0}
+    assert launches(jnp_only) == {"single": 0, "two_level": 0,
+                                  "scalar": 0}
 
 
 def test_per_segment_kernel_span_says_which_accumulate(ssb_segs):
@@ -217,7 +218,34 @@ def test_per_segment_kernel_span_says_which_accumulate(ssb_segs):
     assert kernels and all(
         (k["groups"], k["accumulate"]) == (4096, "two_level")
         for k in kernels)
-    assert ex.pallas_launches() == {"single": 0, "two_level": len(kernels)}
+    assert ex.pallas_launches() == {"single": 0, "two_level": len(kernels),
+                                    "scalar": 0}
+
+
+@pytest.mark.parametrize("path", ["sharded", "per_segment"])
+def test_group_range_probe_counts_as_scalar(ssb_segs, path):
+    """Q3.2's group-range probe builds no one-hot: ``/debug/pallas``
+    counts it under ``scalar``, beside the narrowed scan's own accumulate
+    (sharded: one probe and one scan over the batch; per segment: one of
+    each a segment)."""
+    from pinot_tpu.common.tracing import flatten_spans
+    from pinot_tpu.parallel import ShardedQueryExecutor
+
+    sql = (ssb.QUERIES["Q3.2"]
+           + " LIMIT 100000 OPTION(useStarTree=false, trace=true)")
+    if path == "sharded":
+        ex = ShardedQueryExecutor(use_pallas=True)
+        segs, scans = ssb_segs, "ShardedCombine"
+    else:
+        ex = ServerQueryExecutor(use_device=True, use_pallas=True)
+        segs, scans = ssb_segs[:1], "Kernel"
+    _got, stats = ex.execute(compile_query(sql), segs)
+    took = [e["accumulate"] for e in flatten_spans(stats.spans)
+            if e["operator"] == scans and e.get("kernel") == "pallas"]
+    assert len(took) == 1 and took[0] in ("single", "two_level")
+    want = {"single": 0, "two_level": 0, "scalar": 1}
+    want[took[0]] += 1
+    assert ex.pallas_launches() == want
 
 
 def test_narrow_declines_when_probe_cannot_shrink(tmp_path):

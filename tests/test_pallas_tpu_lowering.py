@@ -16,6 +16,7 @@ import os
 from dataclasses import replace
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import pytest
 
@@ -121,16 +122,66 @@ def test_sharded_spec_lowers_for_tpu(staged):
                            tiles_per_seg=8))
 
 
-def test_group_range_probe_lowers_for_tpu(staged):
-    """The min/max-of-dictId probe Q3.2 and Q4.3 run first (no matmul
-    rows, one min/max pair per group column)."""
+def _probe_spec(staged, qid="Q3.2", **grid):
     from pinot_tpu.engine.pallas_kernels import _stage_packed, extract_plan
 
-    ctx = compile_query(ssb.QUERIES["Q3.2"] + " LIMIT 100000")
+    ctx = compile_query(ssb.QUERIES[qid] + " LIMIT 100000")
     full = extract_plan(plan_segment(ctx, staged.segment), staged.segment,
                         unchecked_groups=True)
     probe = probe_plan_of(full)
     _cols, bits = _stage_packed(probe, staged, lambda reason: None)
-    _lower_for_tpu(replace(
-        probe.spec(num_segs=2, tiles_per_seg=3, interpret=False),
-        packed_bits=tuple(bits)))
+    return replace(probe.spec(interpret=False, **grid),
+                   packed_bits=tuple(bits))
+
+
+def test_group_range_probe_lowers_for_tpu(staged):
+    """The min/max-of-dictId probe Q3.2 and Q4.3 run first (no matmul
+    rows, one min/max pair per group column)."""
+    _lower_for_tpu(_probe_spec(staged, num_segs=2, tiles_per_seg=3))
+
+
+def test_group_range_probe_compiles_for_v5e(staged, one_chip):
+    """Mosaic's verdict on the probe's scalar key space at the
+    benchmark's grid (8 segments of 733 tiles)."""
+    spec = _probe_spec(staged, num_segs=8, tiles_per_seg=733)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in _abstract_args(spec)]
+    assert preflight.preflight_spec(spec).ok
+    jax.jit(build_kernel(spec)).lower(*args).compile()
+
+
+def _kernel_eqns(spec):
+    """Every equation of ``build_kernel(spec)``'s jaxpr, nested jaxprs
+    (the pallas_call's kernel body among them) included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if isinstance(sub, jax.extend.core.Jaxpr):
+                        yield from walk(sub)
+
+    closed = jax.make_jaxpr(build_kernel(spec))(*_abstract_args(spec))
+    return list(walk(closed.jaxpr))
+
+
+@pytest.mark.parametrize("shape, one_hot", [
+    ("probe_Q3.2", False), ("probe_Q4.3", False),
+    ("Q1.1", True), ("Q2.1", True)])
+def test_scalar_key_space_builds_no_one_hot(staged, shape, one_hot):
+    """A probe's program has no matmul and no [.., 128, 128] array (no
+    one-hot, no values broadcast across lanes); a scalar spec with a sum
+    (Q1.1) and a grouped one (Q2.1) keep their ``dot_general``."""
+    if shape.startswith("probe_"):
+        spec = _probe_spec(staged, shape[len("probe_"):], num_segs=2,
+                           tiles_per_seg=3)
+    else:
+        spec = _spec_of(shape, staged)
+    eqns = _kernel_eqns(spec)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    wide = [v.aval.shape for e in eqns for v in e.outvars
+            if tuple(getattr(v.aval, "shape", ()))[-2:] == (128, 128)]
+    assert bool(dots) is one_hot, shape
+    if not one_hot:
+        assert not wide, wide

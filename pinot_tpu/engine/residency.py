@@ -1352,8 +1352,12 @@ class ResidencyManager:
                        for i, m in allocator]
             residents = {}
             for name, e in self._entries.items():
-                d: Dict[str, Any] = {"bytes": e.nbytes, "pins": e.pins}
                 r = e.resident
+                # touch: the stamp every hit, stage and register moves; two
+                # readings say which residents no query read in between
+                d: Dict[str, Any] = {"bytes": e.nbytes, "pins": e.pins,
+                                     "touch": e.touch,
+                                     "kind": type(r).__name__}
                 if isinstance(r, StagedSegment):
                     d.update(columns=len(r._columns), packed=len(r._packed),
                              values=len(r._values),
@@ -1362,8 +1366,6 @@ class ResidencyManager:
                              # one must not hide (or drop) its sibling
                              startreeBytes={str(ti): b for ti, b in
                                             r.startree_nbytes().items()})
-                else:
-                    d["kind"] = type(r).__name__
                 residents[name] = d
             host = {name: {"bytes": e.nbytes,
                            "kind": type(e.resident).__name__}

@@ -508,6 +508,57 @@ def test_snapshot_is_bytes_accurate(segs):
     assert snap["budgetBytes"] is None
 
 
+class _Batch:
+    def nbytes(self):
+        return 100
+
+    def release(self):
+        pass
+
+
+def test_snapshot_gives_every_resident_its_touch_and_kind(segs):
+    """``touch`` and ``kind`` on each resident, whatever its kind: two
+    readings say which residents no query read in between (the
+    benchmark's ``staged_unread_share``)."""
+    rm = ResidencyManager(budget_bytes=0)
+    rm.stage(segs[0])
+    rm.stage(segs[1])
+    rm.register("batch(x)", _Batch)
+    first = rm.snapshot()["stagedSegments"]
+    assert {n: e["kind"] for n, e in first.items()} == {
+        segs[0].segment_name: "StagedSegment",
+        segs[1].segment_name: "StagedSegment", "batch(x)": "_Batch"}
+    assert len({e["touch"] for e in first.values()}) == 3
+
+    rm.stage(segs[0])                   # a hit
+    rm.register("batch(x)", _Batch)     # a hit on the batch
+    second = rm.snapshot()["stagedSegments"]
+    moved = {n for n in first if second[n]["touch"] != first[n]["touch"]}
+    assert moved == {segs[0].segment_name, "batch(x)"}
+    assert second[segs[0].segment_name]["touch"] \
+        > first["batch(x)"]["touch"]
+
+
+@pytest.mark.parametrize("how", ["stage", "register"])
+def test_a_resident_staged_anew_takes_a_new_touch(segs, how):
+    rm = ResidencyManager(budget_bytes=0)
+    rm.stage(segs[2])
+    if how == "stage":
+        name = segs[3].segment_name
+        rm.stage(segs[3])
+        before = rm.snapshot()["stagedSegments"][name]["touch"]
+        rm.evict(name)
+        rm.stage(segs[3])
+    else:
+        name = "batch(y)"
+        rm.register(name, _Batch)
+        before = rm.snapshot()["stagedSegments"][name]["touch"]
+        rm.register(name, _Batch, same=lambda r: False)    # stale: rebuilt
+    after = rm.snapshot()["stagedSegments"]
+    assert after[name]["touch"] > before
+    assert after[segs[2].segment_name]["touch"] < before
+
+
 # --------------------------------------------------------------------------
 # budget resolution against the backend
 # --------------------------------------------------------------------------

@@ -179,9 +179,12 @@ class ServerQueryExecutor:
         # (tools/preflight.attach_verdicts); surfaced on GET /debug/pallas
         self.preflight_verdicts: Optional[dict] = None
         # fused-scan launches by accumulate form, group-range probes under
-        # ``scalar`` (GET /debug/pallas ``launches``)
+        # ``scalar`` (GET /debug/pallas ``launches``), and those that built
+        # a one-hot by MXU contraction (``mxu``)
         self._pallas_launches = {  # guarded-by: _pallas_launches_lock
             "single": 0, "two_level": 0, "scalar": 0}
+        self._pallas_mxu = {  # guarded-by: _pallas_launches_lock
+            "bf16": 0, "fp32": 0}
         self._pallas_launches_lock = threading.Lock()
         self._segment_pool = None
         self._segment_pool_lock = threading.Lock()
@@ -252,33 +255,41 @@ class ServerQueryExecutor:
             return None  # pltpu memory spaces cannot lower on GPU
         return backend == "cpu"  # interpret on CPU
 
-    def _note_pallas_launch(self, plan_spec: Tuple,
-                            count: bool = True) -> Dict[str, Any]:
-        """The span attributes that say which accumulate the fused scan of
-        ``plan_spec`` takes (``groups`` is the kernel's
-        ``num_groups_padded``); counts the launch under that name unless
-        the caller shared another query's."""
+    def _note_pallas_launch(self, spec, count: bool = True
+                            ) -> Dict[str, Any]:
+        """The span attributes that say which accumulate and which MXU
+        contraction the fused scan of ``spec`` (its PallasSpec) takes
+        (``groups`` is the kernel's ``num_groups_padded``); counts the
+        launch under those names unless the caller shared another
+        query's."""
         from pinot_tpu.engine.pallas_kernels import (
-            accumulate_kind,
-            builds_one_hot,
-            padded_groups,
+            spec_accumulate_kind,
+            spec_mxu_kind,
         )
 
-        _, agg_specs, group_specs, _, _ = plan_spec
-        groups = padded_groups(plan_spec)
-        kind = accumulate_kind(groups, builds_one_hot(
-            bool(group_specs), (a[0] for a in agg_specs)))
+        kind = spec_accumulate_kind(spec)
+        mxu = spec_mxu_kind(spec)
         if count:
-            self._count_pallas_launch(kind)
-        return {"groups": groups, "accumulate": kind}
+            self._count_pallas_launch(kind, mxu)
+        took = {"groups": spec.num_groups_padded, "accumulate": kind}
+        if mxu is not None:
+            took["mxu"] = mxu
+        return took
 
-    def _count_pallas_launch(self, kind: str) -> None:
+    def _count_pallas_launch(self, kind: str,
+                             mxu: Optional[str] = None) -> None:
         with self._pallas_launches_lock:
             self._pallas_launches[kind] += 1
+            if mxu is not None:
+                self._pallas_mxu[mxu] += 1
 
     def pallas_launches(self) -> Dict[str, int]:
         with self._pallas_launches_lock:
             return dict(self._pallas_launches)
+
+    def pallas_mxu(self) -> Dict[str, int]:
+        with self._pallas_launches_lock:
+            return dict(self._pallas_mxu)
 
     # -- public ------------------------------------------------------------
     def execute_instance(self, ctx: QueryContext,
@@ -1073,16 +1084,18 @@ class ServerQueryExecutor:
                             reason)
 
         def launch():
+            specs = []
             with maybe_span(stats, "Dispatch"):
                 served = pallas_kernels.run_segment(
                     plan, staged, self.pallas_kernels, interpret,
                     on_decline=declined, lut_run_cap=self._pallas_lut_runs,
-                    on_probe=self._count_pallas_launch)
+                    on_probe=self._count_pallas_launch,
+                    on_launch=specs.append)
             if served is None:
                 return None
             packed, eff = served
             return unpack_outputs(fetch_outputs(stats, packed),
-                                  eff.spec), eff
+                                  eff.spec), eff, specs[-1]
 
         try:
             # per-segment coalescing contract: concurrent identical queries
@@ -1099,7 +1112,7 @@ class ServerQueryExecutor:
                             segment=seg.segment_name) as sp:
                 served, shared = self._kernel_flight.do(
                     ("pallas", id(plan), id(staged)), launch)
-                took = (self._note_pallas_launch(served[1].spec,
+                took = (self._note_pallas_launch(served[2],
                                                  count=not shared)
                         if served is not None else {})
                 if sp is not None:
@@ -1121,7 +1134,7 @@ class ServerQueryExecutor:
         if served is None:
             return None  # run_segment recorded its own reason (on_decline)
         self._track_kernel_stats(served[0], seg, stats)
-        return served
+        return served[:2]
 
     # -- shared ------------------------------------------------------------
     def _run_kernel(self, plan: SegmentPlan, seg: ImmutableSegment,

@@ -27,15 +27,20 @@ over a ``(segments, tiles)`` grid:
   one full-height matmul gives every group's partial and a tile's work
   does not multiply rows by 128-group chunks. Exactness scheme:
   - **integer sums** split each value into 12-bit limbs (``L`` limbs for a
-    plan-time ``max_abs`` bound): every per-tile limb partial is at most
-    ``4095 * PALLAS_TILE < 2^24`` — exactly representable in the f32 matmul
-    (run at HIGHEST precision: no bf16 pass rounds a limb).
+    plan-time ``max_abs`` bound), and each limb enters the MXU as two
+    bf16-exact halves, its low byte (0..255) and ``limb >> 8`` (-16..15),
+    beside the 0/1 count row, against a bf16 one-hot, in ONE default-
+    precision bf16 pass: every per-tile half partial is at most
+    ``255 * PALLAS_TILE < 2^24``, exact in the MXU's f32 accumulation;
+    the halves' partials recombine in i32 (``(hi << 8) + lo``).
     Limb partials land in per-limb **i32 accumulators with a carry chain**
     (base-2^12 positional rows, normalized every grid step), so provider-
     wide sums are exact up to ~2^62 with no i64 math inside the kernel;
   - **float sums** accumulate with Neumaier-compensated f32 pairs
     (sum row + compensation row), recovering near-f64 accuracy over
-    hundreds of millions of rows;
+    hundreds of millions of rows; their rows are not bf16-exact, so a spec
+    with a float sum runs them through an fp32 contraction of their own
+    (``Precision.HIGHEST``) beside the integer rows' bf16 pass;
 - min/max/minmaxrange reduce on the VPU per 128-group chunk;
 - scalar (non-group-by) aggregations are the same kernel with a single
   group (all keys 0);
@@ -72,12 +77,17 @@ _EXPAND_ROWS = 256
 # 8192 covers every SSB flight except the Q3.2+/Q4.3 city/brand key spaces
 # (those ride the jnp sparse-group ladder, engine/kernels.py)
 MAX_PALLAS_GROUPS = 8192
-# int values are split into limbs of this many bits so every per-tile limb
-# matmul partial is f32-exact: (2^12 - 1) * PALLAS_TILE < 2^24
+# int values are split into limbs of this many bits: a tile's limb partial,
+# (2^12 - 1) * PALLAS_TILE < 2^24, and the carry chain stay i32-bounded
 # (staging.LIMB_BITS is the same constant — the host-side limb-plane split
 # for i64 columns must mirror the in-kernel split bit-for-bit)
 _LIMB_BITS = LIMB_BITS
 _LIMB_MASK = (1 << _LIMB_BITS) - 1
+# a limb enters the MXU as two halves bf16 holds exactly (every integer of
+# magnitude <= 256): its low byte and ``limb >> 8`` (-16..15 for a signed
+# top limb)
+_HALF_BITS = 8
+_HALF_MASK = (1 << _HALF_BITS) - 1
 # f32 can represent integers exactly below 2^24 (min/max value bound)
 _F32_EXACT = 1 << 24
 _I32_MAX = (1 << 31) - 1
@@ -85,7 +95,8 @@ _I32_MAX = (1 << 31) - 1
 _POS = np.float32(np.inf)
 _NEG = np.float32(-np.inf)
 
-assert _LIMB_MASK * PALLAS_TILE < _F32_EXACT, "limb partials must be f32-exact"
+assert _LIMB_BITS <= 2 * _HALF_BITS, "a limb's halves must be bf16-exact"
+assert _HALF_MASK * PALLAS_TILE < _F32_EXACT, "half partials must be f32-exact"
 
 
 @dataclass(frozen=True)
@@ -651,6 +662,16 @@ def spec_accumulate_kind(spec: PallasSpec) -> str:
         bool(spec.group_idx), (base for base, _v, _l in spec.aggs)))
 
 
+def spec_mxu_kind(spec: PallasSpec) -> Optional[str]:
+    """The MXU contraction of the kernel ``build_kernel(spec)`` builds:
+    ``bf16`` where every matmul row is an integer row (count, limb
+    halves) in one bf16 pass, ``fp32`` where float-sum rows add an fp32
+    contraction of their own beside it, None where no one-hot is built."""
+    if spec_accumulate_kind(spec) == "scalar":
+        return None
+    return "fp32" if _row_layout(spec)[0] else "bf16"
+
+
 def build_kernel(spec: PallasSpec):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -678,13 +699,16 @@ def build_kernel(spec: PallasSpec):
     TPS = spec.tiles_per_seg
 
     fsum_row, isum_row, mm_row, Mf, Mi, Mm = _row_layout(spec)
-    # matmul row plan: [nf float rows][1 count row][per int sum: L limb
-    # rows]; row_target[m] = which accumulator row takes matmul row m
+    # matmul row plans: the float-sum rows (fp32 contraction) in out_f row
+    # order; the integer rows (one bf16 pass) are [1 count row][per int
+    # sum, per limb: its low byte, its high half], and int_target[m] =
+    # (out_i row, left shift) that integer row m's partial adds at
     float_sums = sorted(fsum_row.items(), key=lambda kv: kv[1])
     int_sums = sorted(isum_row.items(), key=lambda kv: kv[1][0])
-    row_target = [("f", r) for _vexpr, r in float_sums] + [("i", 0)]
+    int_target = [(0, 0)]
     for _vexpr, (start, L) in int_sums:
-        row_target += [("i", start + k) for k in range(L)]
+        for k in range(L):
+            int_target += [(start + k, 0), (start + k, _HALF_BITS)]
 
     def acc(r):
         """Accumulator row ``r``'s [Hp, 128] block of out_f / out_i: group
@@ -769,7 +793,6 @@ def build_kernel(spec: PallasSpec):
             return (ids[pi] >= lo) & (ids[pi] <= hi)
 
         mask = emit(spec.filter_tree) & valid
-        mask_f = mask.astype(jnp.float32)
 
         # -- value expressions [RT, 128]: int exprs evaluate exactly in i32
         # (plan-time bound check), float exprs in f32 (the vectorized form
@@ -830,30 +853,28 @@ def build_kernel(spec: PallasSpec):
             # partials the wrapper folds (no one-hot, no matmul)
             out_i[acc(0)] += m_i.sum(axis=0, keepdims=True)
         else:
-            # -- matmul row stack [nf + 1 + sum(L), T] f32 (docs flattened)
-            rows = []
-            for vexpr, _r in float_sums:
-                rows.append(emit_vexpr(vexpr).astype(jnp.float32) * mask_f)
-            rows.append(mask_f)                    # count row (out_i row 0)
+            # -- integer matmul rows [1 + 2 * sum(L), T] (docs flattened):
+            # the count row, then each limb as its two bf16-exact halves
+            int_rows = [m_i]                       # count row (out_i row 0)
             for vexpr, (start, L) in int_sums:
                 if vexpr[0] == "v64":
-                    # i64-staged column: the limb rows ARE the staged planes
-                    # (host-split with the identical shift/mask scheme), so the
-                    # accumulation below is bit-for-bit the in-kernel split
+                    # i64-staged column: the limbs ARE the staged planes
+                    # (host-split with the identical shift/mask scheme), so
+                    # the accumulation below is bit-for-bit the in-kernel
+                    # split
                     base_ref = v_start[vexpr[1]]
-                    for k in range(L):
-                        plane = values[base_ref + k][0, 0]
-                        rows.append(jnp.where(mask, plane, 0)
-                                    .astype(jnp.float32))
-                    continue
-                v = jnp.where(mask, emit_vexpr(vexpr), 0)
-                for k in range(L):
-                    if k < L - 1:
-                        limb = (v >> (k * _LIMB_BITS)) & _LIMB_MASK
-                    else:
-                        limb = v >> (k * _LIMB_BITS)  # top limb keeps sign
-                    rows.append(limb.astype(jnp.float32))
-            R = jnp.stack(rows).reshape(len(rows), T)  # [M_mat, T]
+                    limbs = [jnp.where(mask, values[base_ref + k][0, 0], 0)
+                             for k in range(L)]
+                else:
+                    v = jnp.where(mask, emit_vexpr(vexpr), 0)
+                    # the top limb keeps the sign (arithmetic shift)
+                    limbs = [(v >> (k * _LIMB_BITS)) & _LIMB_MASK
+                             if k < L - 1 else v >> (k * _LIMB_BITS)
+                             for k in range(L)]
+                for limb in limbs:
+                    int_rows += [limb & _HALF_MASK, limb >> _HALF_BITS]
+            R = jnp.stack(int_rows).astype(jnp.bfloat16).reshape(
+                len(int_rows), T)
 
             # -- two-level one-hot accumulate: group g = hi * 128 + lo. ONE
             # [T, 128] one-hot of ``lo`` a tile; ``hi`` expands every matmul
@@ -864,12 +885,15 @@ def build_kernel(spec: PallasSpec):
             # expansion: the rows go in as they are. A plain 2-D matmul over
             # the tile's flattened docs: Mosaic has no dot_general with two
             # contracting dims, and takes the (RT, 128) -> T flattening as a
-            # relayout. HIGHEST keeps every MXU pass f32 (the limb/f32-
-            # exactness argument above: an expanded row holds limb values or 0)
+            # relayout. The one-hot (0/1) and the integer rows (0/1, a limb's
+            # low byte, its high half; an expanded row holds those or 0) are
+            # bf16-exact, so they take ONE default-precision bf16 pass whose
+            # f32 partials are exact integers (the argument above); float-sum
+            # rows take an fp32 contraction against the same one-hot
             lo = keys if H == 1 else keys & (_G_CHUNK - 1)
             oh_lo = (lo[:, :, None] == jax.lax.broadcasted_iota(
                 jnp.int32, (RT, 128, _G_CHUNK), 2)
-            ).astype(jnp.float32).reshape(T, _G_CHUNK)
+            ).astype(jnp.bfloat16).reshape(T, _G_CHUNK)
             if H > 1:
                 # masked docs outside a narrowed key range: an arithmetic
                 # shift leaves hi negative or >= H, which selects no row (or a
@@ -878,29 +902,58 @@ def build_kernel(spec: PallasSpec):
                 hi = (keys >> 7).reshape(1, T)
                 sel = hi == jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 0)
 
-            for m0 in range(0, len(rows), rows_per_dot):
-                m1 = min(m0 + rows_per_dot, len(rows))
-                lhs = R[m0:m1] if H == 1 else jnp.concatenate(
-                    [jnp.where(sel, R[m:m + 1], 0.0) for m in range(m0, m1)],
-                    axis=0)
-                part = jnp.dot(lhs, oh_lo,
-                               precision=jax.lax.Precision.HIGHEST,
-                               preferred_element_type=jnp.float32)
-                for m in range(m0, m1):
-                    x = part[(m - m0) * Hp:(m - m0 + 1) * Hp]   # [Hp, 128]
-                    kind, r = row_target[m]
-                    if kind == "f":
-                        # float sums: Neumaier-compensated (sum, comp) pair
-                        a = out_f[acc(r)]
-                        t_ = a + x
-                        err = jnp.where(jnp.abs(a) >= jnp.abs(x),
-                                        (a - t_) + x, (x - t_) + a)
-                        out_f[acc(r)] = t_
-                        out_f[acc(r + 1)] += err
+            def accumulate(rows, oh, precision, land):
+                """``rows`` [M, T] @ ``oh`` (both of one dtype) in blocks
+                of rows_per_dot matmul rows; ``land(m, x)`` takes row m's
+                [Hp, 128] partial. bf16 rows expand by a 0/1 product
+                (Mosaic cannot relayout a bool mask onto bf16's (16, 128)
+                tiles; an integer row is finite, so the product is the
+                select)."""
+                if H > 1 and rows.dtype != jnp.float32:
+                    sel_r = sel.astype(rows.dtype)
+                for m0 in range(0, rows.shape[0], rows_per_dot):
+                    m1 = min(m0 + rows_per_dot, rows.shape[0])
+                    if H == 1:
+                        lhs = rows[m0:m1]
+                    elif rows.dtype == jnp.float32:
+                        lhs = jnp.concatenate(
+                            [jnp.where(sel, rows[m:m + 1], 0.0)
+                             for m in range(m0, m1)], axis=0)
                     else:
-                        # count + int limb partials: f32 -> exact i32 (every
-                        # partial is an integer < 2^24 by the limb-width bound)
-                        out_i[acc(r)] += x.astype(jnp.int32)
+                        lhs = jnp.concatenate(
+                            [sel_r * rows[m:m + 1] for m in range(m0, m1)],
+                            axis=0)
+                    part = jnp.dot(lhs, oh, precision=precision,
+                                   preferred_element_type=jnp.float32)
+                    for m in range(m0, m1):
+                        land(m, part[(m - m0) * Hp:(m - m0 + 1) * Hp])
+
+            def land_int(m, x):
+                # count + limb-half partials: f32 -> exact i32 (each is an
+                # integer < 2^24), a high half's shifted back into its limb
+                r, shift = int_target[m]
+                xi = x.astype(jnp.int32)
+                out_i[acc(r)] += (xi << shift) if shift else xi
+
+            accumulate(R, oh_lo, None, land_int)
+
+            if float_sums:
+                def land_float(m, x):
+                    # float sums: Neumaier-compensated (sum, comp) pair
+                    r = float_sums[m][1]
+                    a = out_f[acc(r)]
+                    t_ = a + x
+                    err = jnp.where(jnp.abs(a) >= jnp.abs(x),
+                                    (a - t_) + x, (x - t_) + a)
+                    out_f[acc(r)] = t_
+                    out_f[acc(r + 1)] += err
+
+                mask_f = mask.astype(jnp.float32)
+                F = jnp.stack([emit_vexpr(vexpr).astype(jnp.float32) * mask_f
+                               for vexpr, _r in float_sums]).reshape(
+                    len(float_sums), T)
+                accumulate(F, oh_lo.astype(jnp.float32),
+                           jax.lax.Precision.HIGHEST, land_float)
 
         if scalar:
             # -- min/max rows of a scalar key space: per-lane partials
@@ -1171,7 +1224,8 @@ def _run_probe_segment(probe_pp: PallasPlan, staged: StagedSegment,
 
 def run_segment(plan, staged: StagedSegment, cache: PallasKernelCache,
                 interpret: bool, on_decline=None,
-                lut_run_cap: int = DEFAULT_LUT_RUN_CAP, on_probe=None):
+                lut_run_cap: int = DEFAULT_LUT_RUN_CAP, on_probe=None,
+                on_launch=None):
     """Run the fused kernel over one staged segment; returns
     ``(packed, effective_plan)`` — the PACKED f64 output vector
     (kernels.pack_outputs layout, single D2H fetch) plus the plan whose
@@ -1179,7 +1233,8 @@ def run_segment(plan, staged: StagedSegment, cache: PallasKernelCache,
     large-group shapes; the caller MUST unpack/decode against it) — or
     None when the plan/staging isn't eligible (``on_decline`` receives the
     reason code, same contract as ``extract_plan``). ``on_probe`` receives
-    the accumulate kind of each group-range probe launched."""
+    the accumulate kind of each group-range probe launched, ``on_launch``
+    the PallasSpec of the scan launched."""
     from pinot_tpu.engine.kernels import pack_outputs
 
     def decline(reason: str) -> None:
@@ -1224,6 +1279,8 @@ def run_segment(plan, staged: StagedSegment, cache: PallasKernelCache,
     except Exception:
         cache.pop(spec)  # symmetric with the sharded handler's eviction
         raise
+    if on_launch is not None:
+        on_launch(spec)
     tree = assemble_outputs(eff.spec, spec, out_f, out_i, out_mm,
                             seg_matched=None)
     return pack_outputs(tree, eff.spec), eff

@@ -412,9 +412,10 @@ class ShardedQueryExecutor(ServerQueryExecutor):
         finally:
             req = req_out[-1] if req_out else None
             pallas = req is not None and req.kernel.is_pallas
-            # which accumulate the fused scan took: `plan` is the plan the
-            # pallas kernel was bound to (probe-narrowed where it was)
-            took = self._note_pallas_launch(plan.spec) if pallas else {}
+            # which accumulate and MXU contraction the fused scan took:
+            # the spec of the kernel bound (probe-narrowed where it was)
+            took = (self._note_pallas_launch(req.kernel.pallas_spec)
+                    if pallas else {})
             if sp is not None:
                 rec.span_end(
                     sp,
@@ -547,9 +548,11 @@ class ShardedQueryExecutor(ServerQueryExecutor):
             if len(self._param_cache) > self._param_cache_cap:
                 self._param_cache.popitem(last=False)
 
-    def _launch_kernel(self, launch_key: Tuple, make_call, is_pallas: bool):
+    def _launch_kernel(self, launch_key: Tuple, make_call,
+                       pallas_spec=None):
         """Get-or-create the launch-tier entry: the LaunchKernel every
-        same-shape query (any literals) shares."""
+        same-shape query (any literals) shares (``pallas_spec``: the fused
+        kernel's PallasSpec, None for the jnp combine)."""
         from pinot_tpu.parallel.launcher import LaunchKernel
 
         with self._cache_lock:
@@ -562,7 +565,7 @@ class ShardedQueryExecutor(ServerQueryExecutor):
             kernel = self._launch_cache.get(launch_key)
             if kernel is None:
                 kernel = LaunchKernel(launch_key, call,
-                                      is_pallas=is_pallas)
+                                      pallas_spec=pallas_spec)
                 self._launch_cache[launch_key] = kernel
                 if len(self._launch_cache) > self._launch_cache_cap:
                     self._launch_cache.popitem(last=False)
@@ -608,7 +611,7 @@ class ShardedQueryExecutor(ServerQueryExecutor):
             fn = self.sharded_kernels.get(plan.spec, col_layouts)
             return lambda params, num_docs: fn(cols, params, num_docs)
 
-        kernel = self._launch_kernel(launch_key, make_call, is_pallas=False)
+        kernel = self._launch_kernel(launch_key, make_call)
         params = jax.device_put(
             tuple(plan.params), NamedSharding(self.mesh, P()))
         return kernel, params, plan
@@ -695,7 +698,7 @@ class ShardedQueryExecutor(ServerQueryExecutor):
                                                    num_docs)
 
             probe_kernel = self._launch_kernel(launch_key, make_call,
-                                               is_pallas=True)
+                                               pallas_spec=probe_spec)
             pparams = jax.device_put(probe_pp.static_params,
                                      NamedSharding(self.mesh, P()))
             req = self.launcher.submit(probe_kernel, pparams,
@@ -766,7 +769,7 @@ class ShardedQueryExecutor(ServerQueryExecutor):
                                                    value_cols, num_docs)
 
             kernel = self._launch_kernel(launch_key, make_call,
-                                         is_pallas=True)
+                                         pallas_spec=spec)
             params = jax.device_put(pp.static_params,
                                     NamedSharding(self.mesh, P()))
         except Exception:

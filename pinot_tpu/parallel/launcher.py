@@ -50,15 +50,20 @@ class LaunchKernel:
     everything else — staged columns, mesh, output layout — is closed over.
     ``key`` is the literal-normalized identity two requests must share to
     ride one group: same compiled kernel, same staged arrays, same
-    num_docs source.
+    num_docs source. ``pallas_spec`` is the fused Pallas kernel's
+    PallasSpec, None for a jnp program.
     """
 
-    __slots__ = ("key", "call", "is_pallas")
+    __slots__ = ("key", "call", "pallas_spec")
 
-    def __init__(self, key: Tuple, call, is_pallas: bool = False):
+    def __init__(self, key: Tuple, call, pallas_spec=None):
         self.key = key
         self.call = call
-        self.is_pallas = is_pallas
+        self.pallas_spec = pallas_spec
+
+    @property
+    def is_pallas(self) -> bool:
+        return self.pallas_spec is not None
 
     def run_one(self, params, num_docs):
         return self.call(params, num_docs)

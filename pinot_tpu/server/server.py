@@ -657,10 +657,13 @@ class ServerInstance:
         reason each shape declines with — ``pallas_shape_blocked`` for
         runtime lowering failures, ``pallas_preflight_<rule>`` for
         preflight-seeded predictions) plus the last preflight verdict
-        table run against this executor (tools/preflight.py), and
+        table run against this executor (tools/preflight.py),
         ``launches``: fused-scan launches by the accumulate they took
         (``single``: at most 128 groups; ``two_level``: more; ``scalar``:
-        no one-hot at all, every group-range probe among them). A chip
+        no one-hot at all, every group-range probe among them), and
+        ``mxu``: those that built a one-hot, by MXU contraction (``bf16``:
+        integer rows alone, one bf16 pass; ``fp32``: float-sum rows took
+        an fp32 contraction besides). A chip
         that fell over mid-round keeps its lessons visible here — and,
         with ``pinot.server.query.pallas.blocklist.path`` set, across
         restarts."""
@@ -677,6 +680,8 @@ class ServerInstance:
             "run": False}
         launches = getattr(self.executor, "pallas_launches", None)
         out["launches"] = launches() if launches is not None else {}
+        mxu = getattr(self.executor, "pallas_mxu", None)
+        out["mxu"] = mxu() if mxu is not None else {}
         return out
 
     def memory_debug(self) -> Dict[str, Any]:

@@ -166,9 +166,9 @@ class Verdict:
 
 def _vmem_estimate(spec, model: LoweringModel) -> int:
     """Per-grid-step VMEM bytes: every BlockSpec block build_kernel binds
-    plus the kernel's large intermediates (matmul row stack, the one-hot
-    of the key's low 7 bits, one expanded row block of the two-level
-    accumulate, min-max select buffers). Mirrors
+    plus the kernel's large intermediates (matmul row stacks, the bf16
+    one-hot of the key's low 7 bits, one expanded row block of the
+    two-level accumulate, min-max select buffers). Mirrors
     pallas_kernels.build_kernel's layout via the same ``_row_layout`` and
     ``accumulate_rows``."""
     from pinot_tpu.engine.pallas_kernels import (
@@ -199,18 +199,25 @@ def _vmem_estimate(spec, model: LoweringModel) -> int:
     total += (Mf + Mi) * Hp * model.lane * 4 + Mm * G * 4
     total += model.sublane_f32 * model.lane * 4  # out_seg block (1, 8, 128)
     if spec_accumulate_kind(spec) != "scalar":
-        # matmul row stack R [M_mat, T] f32
-        n_limb_rows = sum(L for (_s, L) in isum.values())
-        m_mat = (Mf // 2) + 1 + n_limb_rows
-        total += m_mat * T * 4
-        # the tile's one-hot: lane iota + oh_lo [RT, 128, 128]
-        total += 2 * T * model.lane * 4
+        # matmul row stacks: the integer rows [1 + 2 * limbs, T] bf16 (the
+        # count row and each limb as two halves), the float-sum rows
+        # [nf, T] f32
+        n_int = 1 + 2 * sum(L for (_s, L) in isum.values())
+        n_float = Mf // 2
+        total += n_int * T * 2 + n_float * T * 4
+        # the tile's one-hot: lane iota i32 + oh_lo [RT, 128, 128] bf16,
+        # and its f32 copy where float rows take the fp32 contraction
+        total += T * model.lane * (4 + 2 + (4 if n_float else 0))
         if H > 1:
-            # the hi-select mask [Hp, T] and one expanded LHS block with
-            # its [rows, 128] partial (at most _EXPAND_ROWS rows, whole R
-            # rows)
-            rows = min(m_mat, rows_per_dot) * Hp
-            total += Hp * T * 4 + rows * (T + model.lane) * 4
+            # the hi-select mask [Hp, T] (and its bf16 0/1 copy), and the
+            # larger of the two stacks' expanded LHS blocks (at most
+            # _EXPAND_ROWS rows, whole stack rows) with its [rows, 128]
+            # f32 partial
+            rows_int = min(n_int, rows_per_dot) * Hp
+            rows_float = min(n_float, rows_per_dot) * Hp
+            total += Hp * T * (4 + 2) + max(
+                rows_int * (T * 2 + model.lane * 4),
+                rows_float * (T + model.lane) * 4)
     if mm_row:
         # min/max select buffers: eq + v3 over a chunk of groups, or one
         # masked [RT, 128] tile where the key space is scalar

@@ -471,18 +471,82 @@ def test_build_kernel_accumulate_is_exact(G, case):
         np.testing.assert_allclose(got, want["fsum"], rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("top", [4095, -4096])
+@pytest.mark.parametrize("column", ["int", "v64"])
+@pytest.mark.parametrize("G", [128, 8192])
+def test_bf16_pass_is_exact_at_the_limb_limits(G, column, top):
+    """A full tile in ONE group (the last: its one-hot column and, at
+    8192 groups, its hi row are the extreme ones), every doc carrying 4095
+    in each limb below the top and ``top`` in the signed top limb: each
+    limb half's partial is the largest a tile makes (255 * T, and -16 * T
+    or 15 * T), so one bf16 pass must still give numpy's int64 sum to the
+    unit and the exact count. ``int`` splits a 2-limb i32 value in the
+    kernel; ``v64`` ships four pre-split planes (an i64 column)."""
+    import jax
+
+    from pinot_tpu.engine.pallas_kernels import (
+        PallasSpec,
+        _row_layout,
+        build_kernel,
+    )
+
+    T = PALLAS_TILE
+    L = 2 if column == "int" else 4
+    limbs = [4095] * (L - 1) + [top]
+    v = sum(limb << (12 * k) for k, limb in enumerate(limbs))
+    if column == "int":
+        values, vexpr = [np.full((1, 1, T // 128, 128), v, np.int32)], "v"
+    else:
+        values, vexpr = [np.full((1, 1, T // 128, 128), limb, np.int32)
+                         for limb in limbs], "v64"
+    key = G - 1
+    spec = PallasSpec(
+        num_segs=1, tiles_per_seg=1, packed_bits=(16,),
+        filter_tree=("true",), n_slots=0, group_idx=(0,),
+        group_strides=(1,), group_key_offset=0, num_groups_padded=G,
+        aggs=(("count", None, None), ("sum", (vexpr, 0), L)),
+        value_is_int=(True,), value_limbs=(0 if column == "int" else L,),
+        interpret=True)
+    params = np.asarray([T, 0], dtype=np.int32)
+    cols = [_pack_planar(np.full((1, 1, T), key), 16)] + values
+    _f, out_i, _mm, out_seg = jax.jit(build_kernel(spec))(params, *cols)
+    out_i = np.asarray(out_i).astype(np.int64)
+    _, isum_row, _, _, _, _ = _row_layout(spec)
+    (start, _L), = isum_row.values()
+    want_count = np.zeros(G, np.int64)
+    want_count[key] = T
+    np.testing.assert_array_equal(out_i[0], want_count)
+    assert int(np.asarray(out_seg).sum()) == T
+    got = sum(out_i[start + k] << (12 * k) for k in range(L + 2))
+    want = np.zeros(G, np.int64)
+    want[key] = np.int64(T) * np.int64(v)
+    np.testing.assert_array_equal(got, want)
+
+
+def _counter_spec(aggs, value_is_int=(), groups=0):
+    from pinot_tpu.engine.pallas_kernels import PallasSpec
+
+    return PallasSpec(
+        num_segs=1, tiles_per_seg=1, packed_bits=(8,) if groups else (),
+        filter_tree=("true",), n_slots=0,
+        group_idx=(0,) if groups else (), group_strides=(1,) if groups
+        else (), group_key_offset=0,
+        num_groups_padded=-(-max(groups, 1) // 128) * 128, aggs=aggs,
+        value_is_int=value_is_int)
+
+
 def test_pallas_launch_counter_loses_no_update_under_threads():
-    """``/debug/pallas`` ``launches`` is bumped from every query thread:
-    more threads than cores with a short switch interval must lose none."""
+    """``/debug/pallas`` ``launches`` and ``mxu`` are bumped from every
+    query thread: more threads than cores with a short switch interval
+    must lose none."""
     import os
     import sys
     import threading
 
     ex = ServerQueryExecutor(use_device=False)
-    scalar = (("true",), (("count", False, None),), (), 1, None)
-    summed = (("true",), (("sum", False, ("col", "q")),), (), 1, None)
-    grouped = (("true",), (("count", False, None),), (("gdict", "c"),),
-               4000, None)
+    scalar = _counter_spec((("count", None, None),))
+    summed = _counter_spec((("sum", ("v", 0), None),), (False,))
+    grouped = _counter_spec((("count", None, None),), groups=4000)
     n_threads, each = 2 * (os.cpu_count() or 4), 500
 
     def work():
@@ -505,6 +569,8 @@ def test_pallas_launch_counter_loses_no_update_under_threads():
     assert ex.pallas_launches() == {"single": n_threads * each,
                                     "two_level": n_threads * each,
                                     "scalar": n_threads * each}
+    assert ex.pallas_mxu() == {"bf16": n_threads * each,
+                               "fp32": n_threads * each}
 
 
 # -- a scalar key space: no one-hot, min/max reduced over the tile ---------
@@ -652,3 +718,39 @@ def test_scalar_key_space_answers(case, setup, host_exec,
         _check_probe_case(name)
     else:
         _check_scalar_sql(name, setup, host_exec, scalar_sharded_exec)
+
+
+@pytest.mark.parametrize("path", ["per_segment", "sharded"])
+@pytest.mark.parametrize("agg, mxu", [("sum(qty)", "bf16"),
+                                      ("sum(price)", "fp32")])
+def test_mxu_counter_and_span_say_which_contraction(setup, host_exec,
+                                                    scalar_sharded_exec,
+                                                    path, agg, mxu):
+    """An integer sum takes the one bf16 pass and a float sum an fp32
+    contraction besides: ``/debug/pallas`` ``mxu`` counts each launch
+    under that name, the ``Kernel`` / ``ShardedCombine`` span carries it,
+    and the answer is the host engine's."""
+    from pinot_tpu.common.tracing import flatten_spans
+
+    _, segs = setup
+    sql = (f"SELECT region, {agg} FROM pl_sales GROUP BY region "
+           "ORDER BY region OPTION(trace=true)")
+    if path == "sharded":
+        ex, scans, n = scalar_sharded_exec, "ShardedCombine", 1
+    else:
+        ex = ServerQueryExecutor(use_device=True, use_pallas=True)
+        scans, n = "Kernel", len(segs)
+    before = ex.pallas_mxu()
+    got, stats = ex.execute(compile_query(sql), segs)
+    after = ex.pallas_mxu()
+    assert {k: after[k] - before[k] for k in after} == {
+        "bf16": 0, "fp32": 0, mxu: n}
+    took = [e.get("mxu") for e in flatten_spans(stats.spans)
+            if e["operator"] == scans and e.get("kernel") == "pallas"]
+    assert took == [mxu] * n
+    want, _ = host_exec.execute(compile_query(sql), segs)
+    assert len(got.rows) == len(want.rows)
+    for g, w in zip(got.rows, want.rows):
+        # an integer sum is exact; a float sum within the file's tolerance
+        assert g[0] == w[0] and g[1] == pytest.approx(
+            w[1], rel=0 if mxu == "bf16" else 1e-6)

@@ -189,18 +189,23 @@ def test_sharded_span_and_counter_say_which_accumulate(ssb_segs, qid,
     combine = spans["ShardedCombine"]
     (spec, _plan_spec), = dev._pallas_sharded     # the one kernel it built
     assert (spec.num_groups_padded > 128) is (accumulate == "two_level")
-    assert (combine["kernel"], combine["groups"], combine["accumulate"]) \
-        == ("pallas", spec.num_groups_padded, accumulate)
+    assert (combine["kernel"], combine["groups"], combine["accumulate"],
+            combine["mxu"]) \
+        == ("pallas", spec.num_groups_padded, accumulate, "bf16")
     want = {"single": 0, "two_level": 0, "scalar": 0, accumulate: 1}
     assert launches(dev) == want
+    assert ServerInstance.pallas_debug(SimpleNamespace(executor=dev))[
+        "mxu"] == {"bf16": 1, "fp32": 0}
 
     jnp_only = ShardedQueryExecutor(use_pallas=False)
     _got, stats = jnp_only.execute(compile_query(sql), ssb_segs)
     combine = {e["operator"]: e
                for e in flatten_spans(stats.spans)}["ShardedCombine"]
-    assert combine["kernel"] == "jnp" and "accumulate" not in combine
+    assert combine["kernel"] == "jnp" and "accumulate" not in combine \
+        and "mxu" not in combine
     assert launches(jnp_only) == {"single": 0, "two_level": 0,
                                   "scalar": 0}
+    assert jnp_only.pallas_mxu() == {"bf16": 0, "fp32": 0}
 
 
 def test_per_segment_kernel_span_says_which_accumulate(ssb_segs):
@@ -216,10 +221,12 @@ def test_per_segment_kernel_span_says_which_accumulate(ssb_segs):
     kernels = [e for e in flatten_spans(stats.spans)
                if e["operator"] == "Kernel" and e.get("kernel") == "pallas"]
     assert kernels and all(
-        (k["groups"], k["accumulate"]) == (4096, "two_level")
+        (k["groups"], k["accumulate"], k["mxu"]) == (4096, "two_level",
+                                                     "bf16")
         for k in kernels)
     assert ex.pallas_launches() == {"single": 0, "two_level": len(kernels),
                                     "scalar": 0}
+    assert ex.pallas_mxu() == {"bf16": len(kernels), "fp32": 0}
 
 
 @pytest.mark.parametrize("path", ["sharded", "per_segment"])
@@ -246,6 +253,8 @@ def test_group_range_probe_counts_as_scalar(ssb_segs, path):
     want = {"single": 0, "two_level": 0, "scalar": 1}
     want[took[0]] += 1
     assert ex.pallas_launches() == want
+    # the probe builds no one-hot: only the narrowed scan takes the MXU
+    assert ex.pallas_mxu() == {"bf16": 1, "fp32": 0}
 
 
 def test_narrow_declines_when_probe_cannot_shrink(tmp_path):

@@ -5,9 +5,10 @@
 the executors run it: the check that caught the kernel's two refusals (a
 ``dot_general`` contracting two dims, 64-bit scalars leaking into the
 body) before any chip was asked. The ``compiles_for_v5e`` tests go one
-step on: libtpu compiles the two-level accumulate at its widest shapes
-for a v5e that is described and not attached (VMEM, tiling and slices are
-judged there, not in the lowering). Neither runs anything:
+step on: libtpu compiles the two-level accumulate at its widest shapes,
+and Q2.2's single chunk, for a v5e that is described and not attached
+(VMEM, tiling and slices are judged there, not in the lowering). Neither
+runs anything:
 ``chip_smoke.py`` and the benchmark do.
 """
 
@@ -185,3 +186,77 @@ def test_scalar_key_space_builds_no_one_hot(staged, shape, one_hot):
     assert bool(dots) is one_hot, shape
     if not one_hot:
         assert not wide, wide
+
+
+def _dots(spec):
+    """(operand dtypes, precision) of every ``dot_general`` in the
+    kernel's jaxpr."""
+    return [(tuple(str(v.aval.dtype) for v in e.invars),
+             e.params.get("precision"))
+            for e in _kernel_eqns(spec) if e.primitive.name == "dot_general"]
+
+
+def _default_precision(precision):
+    return precision is None or all(
+        p in (None, jax.lax.Precision.DEFAULT) for p in precision)
+
+
+@pytest.mark.parametrize("shape", sorted(ssb.QUERIES)
+                         + ["Q2.1_g8192", "float_sum", "probe_Q3.2"])
+def test_integer_rows_take_one_bf16_pass(staged, shape):
+    """Every SSB flight's program (and Q2.1's at MAX_PALLAS_GROUPS, whose
+    rows run in two blocks) multiplies bf16 operands at default precision
+    in every ``dot_general``: the one-hot and the integer rows go through
+    the MXU in a single bf16 pass. A float sum keeps exactly one fp32
+    ``dot`` (HIGHEST) beside the bf16 one; the probe has none."""
+    if shape == "probe_Q3.2":
+        assert _dots(_probe_spec(staged, "Q3.2", num_segs=2,
+                                 tiles_per_seg=3)) == []
+        return
+    if shape == "float_sum":
+        # Q1.1's two value inputs: an int sum of one, a float sum of the
+        # other (not bf16-exact)
+        spec = _spec_of("Q1.1", staged)
+        spec = replace(spec, aggs=(("sum", ("v", 0), 3),
+                                   ("sum", ("v", 1), None)),
+                       value_is_int=(True, False))
+        dots = _dots(spec)
+        assert sorted(d for d, _p in dots) == [
+            ("bfloat16", "bfloat16"), ("float32", "float32")]
+        for dtypes, precision in dots:
+            assert _default_precision(precision) is (
+                dtypes == ("bfloat16", "bfloat16")), (dtypes, precision)
+        return
+    qid, _, groups = shape.partition("_g")
+    spec = _spec_of(qid, staged)
+    if groups:
+        spec = replace(spec, num_groups_padded=int(groups))
+    dots = _dots(spec)
+    assert dots and all(d == ("bfloat16", "bfloat16")
+                        and _default_precision(p) for d, p in dots), dots
+
+
+def test_single_chunk_accumulate_compiles_for_v5e(staged, one_chip):
+    """Mosaic's verdict on Q2.2's program (``H = 1``: its integer rows
+    and bf16 one-hot go into one matmul) at the benchmark's grid."""
+    spec = replace(_spec_of("Q2.2", staged), interpret=False, num_segs=8,
+                   tiles_per_seg=733)
+    assert spec.num_groups_padded == 128
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in _abstract_args(spec)]
+    assert preflight.preflight_spec(spec).ok
+    jax.jit(build_kernel(spec)).lower(*args).compile()
+
+
+@pytest.mark.parametrize("shape", sorted(ssb.QUERIES) + [
+    f"Q2.1_g{g}" for g in (256, 384, 4096, 8192)])
+def test_preflight_admits_every_ssb_shape(staged, shape):
+    """The VMEM model reckons the bf16 one-hot and the split limb rows,
+    and every SSB flight, with Q2.1 at each form of the hi axis, still
+    passes it at the benchmark's grid."""
+    qid, _, groups = shape.partition("_g")
+    spec = replace(_spec_of(qid, staged), num_segs=8, tiles_per_seg=733)
+    if groups:
+        spec = replace(spec, num_groups_padded=int(groups))
+    verdict = preflight.preflight_spec(spec)
+    assert verdict.ok, (shape, verdict.rule, verdict.detail)

@@ -148,9 +148,10 @@ def test_fuzz_grid_rules_exact(table):
     ("rows6_groups8192", True), ("wide96_vmem_over", False)])
 def test_vmem_estimate_of_the_two_level_working_set(label, fits):
     """The model's working set follows build_kernel's: one [T, 128]
-    one-hot a tile whatever the group count, plus, above 128 groups, the
-    hi-select mask and ONE expanded row block. The widest plans stay
-    inside the budget at 8192 groups; 96 aggregations there do not."""
+    bf16 one-hot a tile whatever the group count, plus, above 128 groups,
+    the hi-select mask and ONE expanded row block (of bf16 integer rows,
+    two a limb, or of f32 float-sum rows). The widest plans stay inside
+    the budget at 8192 groups; 96 aggregations there do not."""
     from pinot_tpu.engine.pallas_kernels import _row_layout, accumulate_rows
 
     model = preflight.TPU_V5E
@@ -162,12 +163,16 @@ def test_vmem_estimate_of_the_two_level_working_set(label, fits):
     G = spec.num_groups_padded
     H, Hp, rows_per_dot = accumulate_rows(G)
     _f, isum, _mm, Mf, Mi, Mm = _row_layout(spec)
-    m_mat = Mf // 2 + 1 + sum(L for _s, L in isum.values())
+    n_int = 1 + 2 * sum(L for _s, L in isum.values())
+    n_float = Mf // 2
     added = (Mf + Mi) * (Hp - 1) * lane * 4 + Mm * (G - lane) * 4
     if H > 1:
-        block = min(m_mat, rows_per_dot) * Hp
-        assert block <= max(256, Hp)     # two MXU heights, or one R row
-        added += Hp * T * 4 + block * (T + lane) * 4
+        rows_int = min(n_int, rows_per_dot) * Hp
+        rows_float = min(n_float, rows_per_dot) * Hp
+        # two MXU heights, or one stack row
+        assert max(rows_int, rows_float) <= max(256, Hp)
+        added += Hp * T * (4 + 2) + max(rows_int * (T * 2 + lane * 4),
+                                        rows_float * (T + lane) * 4)
     base = preflight._vmem_estimate(
         dataclasses.replace(spec, num_groups_padded=lane), model)
     assert got - base == added

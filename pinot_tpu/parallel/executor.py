@@ -514,7 +514,7 @@ class ShardedQueryExecutor(ServerQueryExecutor):
             req_out.append(req)
             packed = req.result()
         if traced:
-            req.add_spans(rec)  # the dispatcher's Dispatch + DeviceWait
+            req.add_spans(rec)  # Dispatch, DeviceWait, HandOff, Resume
         # coalescing outcome -> per-query stats (merged across shards and
         # servers; see QueryStats.merge for the sum-vs-max key split).
         # Accumulate instead of overwrite: a sliced combine calls this once

@@ -22,6 +22,9 @@ combine pools, ``BaseCombineOperator.java:55``, with one worker):
 - Different-shape queries pipeline through the queue in arrival order
   instead of convoying behind a lock: while query A's caller decodes its
   result, the dispatcher is already launching query B.
+- The dispatcher keeps its own clock: every second of its thread's life is
+  charged to one of :data:`CLOCK_STATES` (``/debug/launches`` ``clock``),
+  from ``time.perf_counter`` alone, whether or not a query is traced.
 """
 
 from __future__ import annotations
@@ -41,6 +44,14 @@ log = logging.getLogger(__name__)
 # stats keys whose QueryStats.launch merge takes MAX (the rest sum); shared
 # with engine/results.py so wire merge and launcher agree on semantics
 LAUNCH_MAX_KEYS = ("batchSize", "queueWaitMs")
+
+# the dispatcher's clock: where each second of its thread's life went.
+# empty: waiting with nothing queued; waking: from the submit that found it
+# waiting until it holds the drained list (the notify, the condition's lock,
+# the interpreter lock); dispatching: a group's jit calls; deviceWait:
+# block_until_ready; handingOff: everything else (counters, futures, the
+# drain and grouping, the step to the next group or the next wait)
+CLOCK_STATES = ("empty", "waking", "dispatching", "deviceWait", "handingOff")
 
 
 class LaunchKernel:
@@ -74,9 +85,10 @@ class _LaunchRequest:
     executor copies into ``QueryStats.launch``)."""
 
     __slots__ = ("kernel", "params", "num_docs", "future", "t_submit",
-                 "batch_size", "queue_wait_ms", "launches_saved", "deduped",
+                 "batch_size", "queue_wait_ms", "launches_saved",
                  "traced", "request_id", "t_dispatch", "t_launched",
-                 "t_ready", "dispatch_cpu_ms", "thread")
+                 "t_ready", "t_handed", "t_resumed", "dispatch_cpu_ms",
+                 "thread")
 
     def __init__(self, kernel: LaunchKernel, params, num_docs,
                  traced: bool = False, request_id: Optional[str] = None):
@@ -88,24 +100,32 @@ class _LaunchRequest:
         self.batch_size = 1
         self.queue_wait_ms = 0.0
         self.launches_saved = 0
-        self.deduped = False
         # a traced query's launch: the dispatcher stamps its group's
         # phases here (beside t_submit), the query's thread attaches them
         self.traced = traced
         self.request_id = request_id
         self.t_dispatch = self.t_launched = self.t_ready = 0.0
+        self.t_handed = self.t_resumed = 0.0
         self.dispatch_cpu_ms = 0.0
         self.thread = ""
 
     def result(self, timeout: Optional[float] = None):
-        return self.future.result(timeout)
+        out = self.future.result(timeout)
+        if self.traced:
+            self.t_resumed = time.perf_counter()
+        return out
 
     def add_spans(self, rec) -> None:
-        """The dispatcher thread's two phases of this launch as children
-        of the recorder's open span: ``Dispatch`` (host side: group, the
-        jit calls until the last returns) and ``DeviceWait``
-        (``block_until_ready``). A group's requests all carry the group's
-        one pair."""
+        """This launch's phases as children of the recorder's open span:
+        the dispatcher thread's ``Dispatch`` (host side: group, the jit
+        calls until the last returns), ``DeviceWait``
+        (``block_until_ready``) and ``HandOff`` (ready until it set this
+        request's future), and the query thread's ``Resume`` (the future
+        set until the thread ran past ``result()``). A group's requests all
+        carry the group's one ``Dispatch`` / ``DeviceWait`` pair.
+        ``HandOff`` and ``Resume`` carry no CPU: the dispatcher's hand-off
+        is the group's, which every rider would count again, and the query
+        thread only waits in ``Resume``."""
         if not self.t_ready:
             return  # never launched (the submit or the group failed)
         rec.add_completed(
@@ -115,6 +135,13 @@ class _LaunchRequest:
         rec.add_completed(
             "DeviceWait", wall_ms=(self.t_ready - self.t_launched) * 1e3,
             start=self.t_launched, thread=self.thread)
+        rec.add_completed(
+            "HandOff", wall_ms=(self.t_handed - self.t_ready) * 1e3,
+            start=self.t_ready, thread=self.thread)
+        if self.t_resumed:
+            rec.add_completed(
+                "Resume", wall_ms=(self.t_resumed - self.t_handed) * 1e3,
+                start=self.t_handed)
 
 
 class LaunchScheduler:
@@ -137,12 +164,22 @@ class LaunchScheduler:
         self.launches = 0  # guarded-by-writes: _stats_lock
         self.coalesced_launches = 0  # guarded-by-writes: _stats_lock
         self.launches_saved = 0  # guarded-by-writes: _stats_lock
-        self.deduped_requests = 0  # guarded-by-writes: _stats_lock
         self.failures = 0  # guarded-by-writes: _stats_lock
         self.max_batch_size = 0  # guarded-by-writes: _stats_lock
         self.queue_wait_ms_total = 0.0  # guarded-by-writes: _stats_lock
         self.queue_wait_ms_max = 0.0  # guarded-by-writes: _stats_lock
         self._registries: List[Any] = []  # guarded-by-writes: _stats_lock
+        # the dispatcher's clock, seconds a state. The thread
+        # charges what it has passed through at each group's _note and at
+        # each wake; while it waits, _idle_since (and the waking submit's
+        # _woke_at) let a snapshot count the wait so far
+        self._clock = dict.fromkeys(CLOCK_STATES, 0.0)  # guarded-by: _stats_lock
+        self._wakes = 0  # guarded-by: _stats_lock
+        self._groups = 0  # guarded-by: _stats_lock
+        self._idle_since: Optional[float] = None  # guarded-by: _stats_lock
+        self._waiting = False  # guarded-by: _cond
+        self._woke_at: Optional[float] = None  # guarded-by: _stats_lock
+        self._charged_to = 0.0  # dispatcher thread only: charged up to here
 
     # -- submission ----------------------------------------------------------
     def submit(self, kernel: LaunchKernel, params, num_docs,
@@ -159,6 +196,12 @@ class LaunchScheduler:
                     target=self._loop, daemon=True, name=self._name)
                 self._thread.start()
             self._queue.append(req)
+            if self._waiting:
+                # this submit wakes a waiting dispatcher: the wake's start
+                # (read inside _stats_lock, as every charge is)
+                self._waiting = False
+                with self._stats_lock:
+                    self._woke_at = time.perf_counter()
             self._cond.notify()
         return req
 
@@ -172,14 +215,24 @@ class LaunchScheduler:
 
     # -- dispatcher ----------------------------------------------------------
     def _loop(self) -> None:
+        self._charged_to = time.perf_counter()
         while True:
             with self._cond:
-                while not self._queue and not self._closed:
-                    self._cond.wait()
+                woke = False
+                if not self._queue and not self._closed:
+                    self._charge_to_wait()
+                    self._waiting = True
+                    while not self._queue and not self._closed:
+                        self._cond.wait()
+                    woke = not self._waiting    # a submit, not close()
+                    self._waiting = False
                 if not self._queue and self._closed:
+                    self._charge_to_exit()
                     return
                 drained = list(self._queue)
                 self._queue.clear()
+            if woke:
+                self._charge_wake()
             # group by compiled-kernel identity, preserving the arrival
             # order of the FIRST request of each group (FIFO fairness across
             # shapes; later same-shape arrivals ride the earlier slot)
@@ -200,6 +253,41 @@ class LaunchScheduler:
                         if not r.future.done():
                             r.future.set_exception(e)
 
+    # -- the dispatcher's clock ----------------------------------------------
+    # (each charge reads the clock inside _stats_lock, so that a snapshot,
+    # which counts a wait under way up to its own reading, never sees a
+    # counter go back)
+    def _charge_to_wait(self) -> None:
+        """Nothing queued: charge the step here as handing off; ``empty``
+        runs from now until a submit wakes the dispatcher."""
+        with self._stats_lock:
+            t = time.perf_counter()
+            self._clock["handingOff"] += t - self._charged_to
+            self._idle_since = t
+
+    def _charge_wake(self) -> None:
+        """The drained list in hand after a wait: ``empty`` up to the
+        submit that woke the dispatcher, ``waking`` from there to now."""
+        with self._stats_lock:
+            held = time.perf_counter()
+            self._clock["empty"] += self._woke_at - self._idle_since
+            self._clock["waking"] += held - self._woke_at
+            self._wakes += 1
+            self._idle_since = self._woke_at = None
+        self._charged_to = held
+
+    def _charge_to_exit(self) -> None:
+        """The dispatcher's exit: what is left since the last charge. A
+        wait that only ``close()`` ended counts as ``empty``."""
+        with self._stats_lock:
+            t = time.perf_counter()
+            if self._idle_since is not None:
+                self._clock["empty"] += t - self._idle_since
+                self._idle_since = None
+            else:
+                self._clock["handingOff"] += t - self._charged_to
+        self._charged_to = t
+
     def _launch_group(self, reqs: List[_LaunchRequest]) -> None:
         import jax
 
@@ -210,8 +298,9 @@ class LaunchScheduler:
             r.queue_wait_ms = (now - r.t_submit) * 1e3
         traced = [r for r in reqs if r.traced]
         cpu0 = time.thread_time() if traced else 0.0
-        # a traced group's two phases also go onto a profiler trace, on
-        # this thread's line (under the first traced request's id)
+        # a traced group's three phases (Dispatch, DeviceWait, HandOff) also
+        # go onto a profiler trace, on this thread's line (under the first
+        # traced request's id)
         ann = (tracing.annotate("Dispatch", traced[0].request_id)
                if traced else None)
         # dedup exact repeats: the executor's param cache hands identical
@@ -248,28 +337,41 @@ class LaunchScheduler:
         ready = time.perf_counter()
         if ann is not None:
             ann.__exit__(None, None, None)
+            ann = tracing.annotate("HandOff", traced[0].request_id)
         for r in traced:
             r.t_dispatch, r.t_launched, r.t_ready = now, launched, ready
             r.dispatch_cpu_ms = cpu_ms
             r.thread = self._name
 
         # counters before futures: a rider that reads /debug/launches after
-        # its answer finds its own launch counted
+        # its answer finds its own launch counted. The clock is charged up
+        # to ready; what follows is handed off at the next charge
         self._note(reqs, launches=len(uniq),
-                   n_failed=sum(e is not None for e in errs))
+                   n_failed=sum(e is not None for e in errs),
+                   clock=(now - self._charged_to, launched - now,
+                          ready - launched))
+        self._charged_to = ready
         n = len(reqs)
         saved = n - len(uniq)
         for r, slot in zip(reqs, req_slot):
             r.batch_size = n
             r.launches_saved = saved
-            r.deduped = req_slot.count(slot) > 1
+            if r.traced:
+                # stamped before the future is set: the waiter reads it
+                r.t_handed = time.perf_counter()
             if errs[slot] is not None:
                 r.future.set_exception(errs[slot])
             else:
                 r.future.set_result(outs[slot])
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     # -- stats / observability ----------------------------------------------
-    def _note(self, reqs, launches: int, n_failed: int) -> None:
+    def _note(self, reqs, launches: int, n_failed: int,
+              clock: Tuple[float, float, float]) -> None:
+        """Counters of one group, and the dispatcher's clock up to its
+        ``ready``: ``clock`` is (handing off before the group, its
+        dispatching, its device wait) in seconds."""
         n = len(reqs)
         wait = [r.queue_wait_ms for r in reqs]
         # windowed dispatcher-queue-wait histogram: the launch tier's
@@ -286,11 +388,14 @@ class LaunchScheduler:
             if n > launches:    # riders that shared an identical one's launch
                 self.coalesced_launches += 1
                 self.launches_saved += n - launches
-                self.deduped_requests += n - launches
             if n > self.max_batch_size:
                 self.max_batch_size = n
             self.queue_wait_ms_total += sum(wait)
             self.queue_wait_ms_max = max(self.queue_wait_ms_max, *wait)
+            self._clock["handingOff"] += clock[0]
+            self._clock["dispatching"] += clock[1]
+            self._clock["deviceWait"] += clock[2]
+            self._groups += 1
         self._mark("LAUNCH_REQUESTS", n)
         self._mark("LAUNCHES", launches)
         if n > launches:
@@ -319,7 +424,7 @@ class LaunchScheduler:
         for reg in list(self._registries):
             reg.meter(metric).mark(n)
 
-    def stats_snapshot(self) -> Dict[str, float]:
+    def stats_snapshot(self) -> Dict[str, Any]:
         """Cumulative counters (callers diff two of these)."""
         with self._stats_lock:
             return {
@@ -327,12 +432,26 @@ class LaunchScheduler:
                 "launches": self.launches,
                 "coalescedLaunches": self.coalesced_launches,
                 "launchesSaved": self.launches_saved,
-                "dedupedRequests": self.deduped_requests,
                 "failures": self.failures,
                 "maxBatchSize": self.max_batch_size,
                 "queueWaitMsTotal": round(self.queue_wait_ms_total, 3),
                 "queueWaitMsMax": round(self.queue_wait_ms_max, 3),
+                "clock": self._clock_locked(),
             }
+
+    def _clock_locked(self) -> Dict[str, float]:
+        """The dispatcher's clock in ms, cumulative. A wait under way is
+        counted up to now: ``empty`` until the submit that woke it,
+        ``waking`` since. Busy, the charges lag by the group in hand."""
+        secs = dict(self._clock)
+        if self._idle_since is not None:
+            now = time.perf_counter()
+            woke = now if self._woke_at is None else self._woke_at
+            secs["empty"] += woke - self._idle_since
+            secs["waking"] += now - woke
+        out = {f"{state}Ms": round(s * 1e3, 3) for state, s in secs.items()}
+        out.update(wakes=self._wakes, groups=self._groups)
+        return out
 
     def snapshot(self) -> Dict[str, Any]:
         """``/debug/launches`` body: counters + live queue state."""

@@ -580,8 +580,9 @@ class ServerInstance:
 
     def launch_debug(self) -> Dict[str, Any]:
         """Launch-coalescing state for ``GET /debug/launches``: requests vs
-        device launches, coalesced/deduped/batched counts, queue waits, and
-        the live dispatcher queue depth (empty for host-only executors)."""
+        device launches, coalesced counts, queue waits, the dispatcher's
+        ``clock`` (where its thread's time went) and the live dispatcher
+        queue depth (empty for host-only executors)."""
         launcher = getattr(self.executor, "launcher", None)
         if launcher is None:
             return {"enabled": False}

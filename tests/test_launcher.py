@@ -116,7 +116,7 @@ def test_dedup_identical_params():
     assert all(r.batch_size == 3 for r in reqs)
     assert all(r.launches_saved == 2 for r in reqs)
     snap = sched.stats_snapshot()
-    assert snap["dedupedRequests"] >= 2
+    assert snap["launchesSaved"] >= 2
     assert snap["coalescedLaunches"] >= 1
 
 
@@ -137,11 +137,11 @@ def test_distinct_params_each_launch_once_in_arrival_order(n):
     reqs = [sched.submit(kern, (f"p{i}",), 5) for i in range(n)]
     gate.set()
     assert b.result(30) == 0
-    assert [r.result(30) for r in reqs] == [("out", (f"p{i}",), 5)
-                                            for i in range(n)]
+    outs = [r.result(30) for r in reqs]
+    assert outs == [("out", (f"p{i}",), 5) for i in range(n)]
     assert calls == [(f"p{i}",) for i in range(n)]
-    assert all(r.batch_size == n and r.launches_saved == 0
-               and not r.deduped for r in reqs)
+    assert all(r.batch_size == n and r.launches_saved == 0 for r in reqs)
+    assert len({id(o) for o in outs}) == n, "one result buffer a launch"
     snap = sched.stats_snapshot()
     assert snap["launches"] - mark["launches"] == 1 + n   # the blocker's
     assert snap["launchesSaved"] == 0 and snap["coalescedLaunches"] == 0
@@ -164,19 +164,21 @@ def test_mixed_group_shares_identical_params_only(order):
     reqs = [sched.submit(kern, objs[c], 0) for c in order]
     gate.set()
     b.result(30)
-    assert [r.result(30) for r in reqs] == [("out", objs[c]) for c in order]
+    outs = [r.result(30) for r in reqs]
+    assert outs == [("out", objs[c]) for c in order]
     assert calls == [("a",), ("b",)], "one launch a distinct param object"
-    assert [r.deduped for r in reqs] == [order.count(c) > 1 for c in order]
+    # riders of one param object share its launch's one result buffer
+    assert [sum(o is p for p in outs) > 1 for o in outs] \
+        == [order.count(c) > 1 for c in order]
     assert all(r.launches_saved == len(order) - 2 for r in reqs)
     snap = sched.stats_snapshot()
     delta = {k: snap[k] - mark[k] for k in ("requests", "launches",
-                                            "launchesSaved",
-                                            "dedupedRequests")}
+                                            "launchesSaved")}
     # the blocker's own launch is counted after the mark
     assert delta["requests"] == 1 + len(order)
     assert delta["launches"] + delta["launchesSaved"] == delta["requests"]
     assert delta["launches"] == 1 + 2
-    assert delta["dedupedRequests"] == len(order) - 2
+    assert delta["launchesSaved"] == len(order) - 2
 
 
 def test_no_two_launches_overlap():
@@ -241,6 +243,157 @@ def test_launch_errors_reach_every_rider():
         with pytest.raises(RuntimeError, match="kernel exploded"):
             r.result(30)
     assert sched.stats_snapshot()["failures"] >= 1
+
+
+# --------------------------------------------------------------------------
+# the dispatcher's clock: every second of its thread in one of five states
+# --------------------------------------------------------------------------
+
+CLOCK_MS = ("emptyMs", "wakingMs", "dispatchingMs", "deviceWaitMs",
+            "handingOffMs")
+
+
+def _clock(sched):
+    return sched.stats_snapshot()["clock"]
+
+
+def _wait_idle(sched, timeout_s=30.0):
+    """Until the dispatcher waits with nothing queued."""
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        with sched._stats_lock:
+            if sched._idle_since is not None:
+                return
+        time.sleep(0.001)
+    raise AssertionError("the dispatcher never went idle")
+
+
+def test_the_clock_sums_to_the_dispatchers_wall_time():
+    """Waits, wakes, launches back to back and a slow kernel: the five
+    states together are the thread's wall time since it started."""
+    sched = LaunchScheduler(name="t-clock-sum")
+    kern = LaunchKernel(("kc",), lambda params, num_docs:
+                        (time.sleep(0.005), params)[1])
+    t0 = time.perf_counter()        # the first submit starts the thread
+    for i in range(4):
+        reqs = [sched.submit(kern, (i, j), 0) for j in range(3)]
+        assert [r.result(30) for r in reqs] == [(i, j) for j in range(3)]
+        time.sleep(0.2)
+    _wait_idle(sched)
+    time.sleep(0.2)
+    clock = _clock(sched)
+    elapsed_ms = (time.perf_counter() - t0) * 1e3
+    assert set(clock) == set(CLOCK_MS) | {"wakes", "groups"}
+    total = sum(clock[k] for k in CLOCK_MS)
+    assert total == pytest.approx(elapsed_ms, rel=0.02), (clock, elapsed_ms)
+    assert min(clock[k] for k in CLOCK_MS) >= 0.0
+    assert clock["groups"] >= 4 and 1 <= clock["wakes"] <= 4
+
+
+def test_an_idle_dispatcher_accrues_empty_and_a_submit_counts_one_wake():
+    sched = LaunchScheduler(name="t-clock-idle")
+    kern = LaunchKernel(("ki",), lambda params, num_docs: params)
+    sched.submit(kern, ("warm",), 0).result(30)
+    _wait_idle(sched)
+    before = _clock(sched)
+    time.sleep(0.2)
+    idle = _clock(sched)
+    assert idle["emptyMs"] - before["emptyMs"] >= 150.0
+    assert {k: idle[k] for k in ("wakes", "groups", "dispatchingMs")} \
+        == {k: before[k] for k in ("wakes", "groups", "dispatchingMs")}
+    req = sched.submit(kern, ("p",), 0, traced=True)
+    assert req.result(30) == ("p",)
+    _wait_idle(sched)
+    after = _clock(sched)
+    assert after["wakes"] == idle["wakes"] + 1
+    assert after["groups"] == idle["groups"] + 1
+    assert after["wakingMs"] > idle["wakingMs"]
+    # the wake lies inside the waker's queue wait: its submit until the
+    # drain, before the group starts
+    assert after["wakingMs"] - idle["wakingMs"] <= req.queue_wait_ms + 0.002
+    # every reading goes forward
+    for a, b in ((before, idle), (idle, after)):
+        assert all(b[k] >= a[k] for k in CLOCK_MS)
+
+
+def test_a_slow_jit_call_shows_in_dispatching():
+    sched = LaunchScheduler(name="t-clock-slow")
+
+    def slow(params, num_docs):
+        time.sleep(0.02)
+        return params
+
+    sched.submit(LaunchKernel(("kw",), lambda p, n: p), 0, 0).result(30)
+    _wait_idle(sched)
+    before = _clock(sched)
+    reqs = [sched.submit(LaunchKernel(("ks",), slow), (i,), 0)
+            for i in range(3)]
+    for r in reqs:
+        r.result(30)
+    _wait_idle(sched)
+    after = _clock(sched)
+    grew = {k: after[k] - before[k] for k in CLOCK_MS}
+    assert grew["dispatchingMs"] >= 3 * 20.0 * 0.95, grew
+    # the calls returned host values: nothing to wait for on a device
+    assert grew["deviceWaitMs"] < grew["dispatchingMs"] / 4, grew
+
+
+def test_the_clock_never_goes_back_under_load():
+    """More submitting threads than cores and a reader snapshotting all
+    the while, on a short switch interval: every reading of every state
+    goes forward, and the states end up summing to the thread's life."""
+    import os
+    import sys
+
+    sched = LaunchScheduler(name="t-clock-load")
+    kern = LaunchKernel(("kl",), lambda params, num_docs: params)
+    readings, errors = [], []
+    stop = threading.Event()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        t0 = time.perf_counter()
+        sched.submit(kern, ("first",), 0).result(30)
+
+        def read():
+            while not stop.is_set():
+                readings.append(_clock(sched))
+
+        def pump(tid):
+            try:
+                for it in range(20):
+                    assert sched.submit(kern, (tid, it), 0).result(30) \
+                        == (tid, it)
+                    if it % 5 == 0:
+                        time.sleep(0.002)
+            except BaseException as e:  # noqa: BLE001 — surfaced below
+                errors.append(e)
+
+        reader = threading.Thread(target=read, daemon=True)
+        reader.start()
+        threads = [threading.Thread(target=pump, args=(t,), daemon=True)
+                   for t in range((os.cpu_count() or 1) + 2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads)
+        _wait_idle(sched)
+        stop.set()
+        reader.join(30)
+        assert not reader.is_alive()
+        end = _clock(sched)
+        elapsed_ms = (time.perf_counter() - t0) * 1e3
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors, errors[:3]
+    readings.append(end)
+    for a, b in zip(readings, readings[1:]):
+        assert all(b[k] >= a[k] for k in CLOCK_MS + ("wakes", "groups")), \
+            (a, b)
+    assert sum(end[k] for k in CLOCK_MS) == pytest.approx(elapsed_ms,
+                                                          rel=0.02)
+    assert end["wakes"] <= end["groups"]
 
 
 def test_dispatcher_crash_completes_waiters_and_recovers(monkeypatch):
@@ -558,8 +711,8 @@ def test_launch_stats_merge_and_wire():
 
 LAUNCHES_KEYS = {
     "enabled", "requests", "launches", "coalescedLaunches", "launchesSaved",
-    "dedupedRequests", "failures", "maxBatchSize", "queueWaitMsTotal",
-    "queueWaitMsMax", "queued", "dispatcherAlive"}
+    "failures", "maxBatchSize", "queueWaitMsTotal", "queueWaitMsMax",
+    "clock", "queued", "dispatcherAlive"}
 SCHEDULER_KEYS = {"scheduler", "admission", "kernelFlight", "queryFlight",
                   "stallWatch"}
 

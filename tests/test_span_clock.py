@@ -287,7 +287,8 @@ STARTREE_SPANS = {"Route", "SegmentQueue", "StarTreeWalk", "Plan", "Stage",
                   "Kernel", "Dispatch", "DeviceWait", "D2H", "Decode",
                   "CombineSegments", "Release"}
 SHARDED_SPANS = {"Route", "Plan", "Stage", "ShardedCombine", "Dispatch",
-                 "DeviceWait", "D2H", "Decode", "Release"}
+                 "DeviceWait", "HandOff", "Resume", "D2H", "Decode",
+                 "Release"}
 
 
 class TestNamedSpans:
@@ -322,7 +323,8 @@ class TestNamedSpans:
         assert kids["Plan"]["cacheHit"] in (True, False)
         combine = kids["ShardedCombine"]
         launch = {c["name"]: c for c in combine["children"]}
-        assert set(launch) == {"Dispatch", "DeviceWait", "D2H"}
+        assert set(launch) == {"Dispatch", "DeviceWait", "HandOff", "Resume",
+                               "D2H"}
         # the launcher's dispatcher thread stamped its two phases
         assert launch["Dispatch"]["thread"].startswith("combine-launch")
         assert launch["DeviceWait"]["thread"] == launch["Dispatch"]["thread"]
@@ -330,6 +332,89 @@ class TestNamedSpans:
         assert launch["DeviceWait"]["startMs"] == pytest.approx(
             launch["Dispatch"]["startMs"] + launch["Dispatch"]["ms"],
             abs=EPS_MS)
+
+    def test_the_launch_hands_off_then_the_query_resumes(self, executor,
+                                                         segs):
+        """Under ``ShardedCombine``: ``DeviceWait``, then the dispatcher's
+        ``HandOff`` until it set the future, then the query thread's
+        ``Resume``, each starting where the one before ended."""
+        root = _traced(executor, segs, SCAN_SQL)
+        combine = next(c for c in root["children"]
+                       if c["name"] == "ShardedCombine")
+        names = [c["name"] for c in combine["children"]]
+        i = names.index("DeviceWait")
+        assert names[i:i + 3] == ["DeviceWait", "HandOff", "Resume"], names
+        wait, hand, resume = combine["children"][i:i + 3]
+        assert hand["thread"] == wait["thread"]
+        assert hand["thread"].startswith("combine-launch")
+        assert resume["thread"] == combine["thread"]
+        assert resume["cpuMs"] == 0.0
+        for a, b in ((wait, hand), (hand, resume)):
+            assert b["startMs"] == pytest.approx(a["startMs"] + a["ms"],
+                                                 abs=2 * EPS_MS)
+
+    def test_a_wake_is_on_the_clock_not_in_the_span_tree(self, executor,
+                                                         segs):
+        """A query whose submit wakes the waiting dispatcher counts one
+        wake on ``/debug/launches`` ``clock``, inside its combine's
+        ``queueMs``, and its launch has the same spans as one that found
+        the dispatcher busy, which counts no wake."""
+        from pinot_tpu.parallel.launcher import LaunchKernel
+
+        launcher = executor.launcher
+
+        def launch_names(root):
+            combine = next(c for c in root["children"]
+                           if c["name"] == "ShardedCombine")
+            return [c["name"] for c in combine["children"]]
+
+        def wait_idle():
+            deadline = time.monotonic() + 30
+            while launcher.snapshot()["queued"] or not launcher._waiting:
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+
+        _traced(executor, segs, SCAN_SQL)       # the thread is there
+        wait_idle()
+        before = launcher.stats_snapshot()["clock"]
+        idle = _traced(executor, segs, SCAN_SQL)
+        wait_idle()
+        woken = launcher.stats_snapshot()["clock"]
+        assert woken["wakes"] == before["wakes"] + 1
+        combine = next(c for c in idle["children"]
+                       if c["name"] == "ShardedCombine")
+        assert woken["wakingMs"] - before["wakingMs"] \
+            <= combine["queueMs"] + EPS_MS
+        names = launch_names(idle)
+        assert names[0] == "Dispatch", names
+        # busy: a launch holds the dispatcher while the query submits
+        gate, entered = threading.Event(), threading.Event()
+
+        def hold(params, num_docs):
+            entered.set()
+            gate.wait(30)
+            return params
+
+        blocker = executor.launcher.submit(LaunchKernel(("hold",), hold),
+                                           0, 0)
+        assert entered.wait(30)
+        got = []
+        t = threading.Thread(target=lambda: got.append(
+            _traced(executor, segs, SCAN_SQL)), daemon=True)
+        t.start()
+        deadline = time.monotonic() + 30
+        while not launcher.snapshot()["queued"]:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        gate.set()
+        blocker.result(30)
+        t.join(60)
+        wait_idle()
+        # the blocker woke the dispatcher; the query that queued behind
+        # it did not
+        assert launcher.stats_snapshot()["clock"]["wakes"] \
+            == woken["wakes"] + 1
+        assert got and launch_names(got[0]) == names
 
     def test_the_walk_span_says_what_the_walk_did(self, executor, segs):
         """``nodes`` read, ``emitted`` records of the leaf ranges reached,
@@ -466,9 +551,11 @@ class TestAnnotationsAndTheOffPath:
         rt, stats = executor.execute(compile_query(
             SCAN_SQL + " OPTION(trace=true, requestId=r7)"), segs)
         spans = list(_walk(stats.spans[0]))
-        # a wait that was over when it was recorded gets none; the
-        # launcher's dispatcher enters its two phases on its own thread
-        recorded = [s["name"] for s in spans if s["name"] != "Admission"]
+        # a wait that was over when it was recorded gets none (Admission,
+        # and the query thread's Resume); the launcher's dispatcher enters
+        # its three phases on its own thread
+        recorded = [s["name"] for s in spans
+                    if s["name"] not in ("Admission", "Resume")]
         assert sorted(n for n, _ in entered) == sorted(recorded)
         assert {r for _, r in entered} == {"r7"}
 
@@ -639,3 +726,99 @@ class TestReaders:
             span["children"] = [c for c in span.get("children", ())
                                 if c["name"] != "SegmentQueue"]
         assert _reader("segment_queue_ms")(ctx) == 0.0
+
+
+# --------------------------------------------------------------------------
+# the launcher's readers: the dispatcher's clock and the query's Resume
+# --------------------------------------------------------------------------
+
+LAUNCHER_CELLS = ["ssb_scan.flights_c2", "ssb_scan.flights_c8v",
+                  "ssb_scan_x4.flights_c2", "ssb_scan_sf12.flights_c2"]
+
+
+def _clock_ctx(before, after):
+    keys = ("emptyMs", "wakingMs", "dispatchingMs", "deviceWaitMs",
+            "handingOffMs")
+    return {side: {"launches": {"requests": 0,
+                                "clock": dict(zip(keys, vals),
+                                              wakes=0, groups=0)}}
+            for side, vals in (("before", before), ("after", after))}
+
+
+# before (100, 1, 50, 300, 9); after (1100, 4, 2050, 5300, 1509): the
+# window grew 1000 + 3 + 2000 + 5000 + 1500 = 9503 ms of the thread
+CLOCK_BY_HAND = {
+    "dispatcher_empty_share": 100.0 * 1000 / 9503,
+    "dispatcher_wake_share": 100.0 * 3 / 9503,
+    "dispatcher_dispatch_share": 100.0 * 2000 / 9503,
+    "dispatcher_device_wait_share": 100.0 * 5000 / 9503,
+    "dispatcher_handoff_share": 100.0 * 1500 / 9503,
+}
+
+
+def _server(resumes):
+    """A broker root over one server's tree with one ``ShardedCombine`` a
+    value of ``resumes`` (``None``: a combine without ``Resume``)."""
+    def leaf(name, start, ms):
+        return {"name": name, "ms": ms, "startMs": start, "cpuMs": 0.0,
+                "thread": "t"}
+
+    combines = []
+    for i, r in enumerate(resumes):
+        kids = [leaf("Dispatch", 1.0 + 10 * i, 2.0),
+                leaf("DeviceWait", 3.0 + 10 * i, 4.0),
+                leaf("HandOff", 7.0 + 10 * i, 0.1)]
+        if r is not None:
+            kids.append(leaf("Resume", 7.1 + 10 * i, r))
+        combines.append(dict(leaf("ShardedCombine", 10 * i, 9.0),
+                             children=kids))
+    server = dict(leaf("ServerQuery", 0.0, 30.0), startEpochMs=1e12 + 1,
+                  children=combines)
+    root = dict(leaf("BrokerQuery", 0.0, 40.0), startEpochMs=1e12,
+                children=[server])
+    return {"ok": True, "raw": {"traceInfo": {"spans": [root]}}}
+
+
+class TestLauncherReaders:
+    def test_each_has_an_entry_in_the_launcher_cells(self):
+        with open(os.path.join(os.path.dirname(HERE),
+                               "BENCHMARK.json")) as f:
+            entries = {m["name"]: m for m in json.load(f)["per_layer"]}
+        for name in list(CLOCK_BY_HAND) + ["resume_ms"]:
+            assert entries[name]["workloads"] == LAUNCHER_CELLS, name
+            assert entries[name]["layer"] == "parallel launcher"
+            assert callable(_reader(name))
+
+    @pytest.mark.parametrize("name", sorted(CLOCK_BY_HAND))
+    def test_a_share_reads_the_number_worked_out_by_hand(self, name):
+        ctx = _clock_ctx((100, 1, 50, 300, 9), (1100, 4, 2050, 5300, 1509))
+        assert _reader(name)(ctx) == pytest.approx(CLOCK_BY_HAND[name])
+
+    def test_the_five_shares_sum_to_a_hundred(self):
+        ctx = _clock_ctx((0, 0, 0, 0, 0), (7, 1, 3, 11, 2))
+        assert sum(_reader(n)(ctx) for n in CLOCK_BY_HAND) \
+            == pytest.approx(100.0)
+
+    @pytest.mark.parametrize("name", sorted(CLOCK_BY_HAND))
+    def test_a_share_reads_nothing_without_the_clock(self, name):
+        ctx = _clock_ctx((0,) * 5, (1,) * 5)
+        for side in ("before", "after"):
+            bare = copy.deepcopy(ctx)
+            del bare[side]["launches"]["clock"]     # a parent's snapshot
+            assert _reader(name)(bare) is None
+        # a window in which the clock did not move
+        assert _reader(name)(_clock_ctx((5,) * 5, (5,) * 5)) is None
+
+    def test_resume_reads_the_number_worked_out_by_hand(self):
+        # query 1: two launches, 0.3 + 0.2; query 2: 0.9; query 3 has
+        # none and is left out: the median of (0.5, 0.9) is the lower
+        records = [_server([0.3, 0.2]), _server([0.9]), _server([None])]
+        assert _reader("resume_ms")({"records": records}) \
+            == pytest.approx(0.5)
+        assert _reader("resume_ms")({"records": records[1:]}) \
+            == pytest.approx(0.9)
+
+    def test_resume_reads_nothing_without_the_span(self, recorded):
+        # the recorded trees are a parent's: no Resume anywhere
+        assert _reader("resume_ms")(recorded) is None
+        assert _reader("resume_ms")({"records": [_server([None])]}) is None

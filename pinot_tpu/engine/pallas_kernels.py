@@ -22,9 +22,10 @@ over a ``(segments, tiles)`` grid:
   partials — the fixed-shape scatter-add replacement for
   ``GroupByResultHolder``. Above 128 groups the key splits in **two
   levels**, ``hi = key >> 7`` and ``lo = key & 127``: a tile builds ONE
-  ``[T, 128]`` one-hot of ``lo`` and expands each row into ``H = G / 128``
-  rows by ``hi`` (row ``m * H + h`` keeps the docs whose ``hi == h``), so
-  one full-height matmul gives every group's partial and a tile's work
+  ``[128, T]`` one-hot of ``lo`` (groups on sublanes, every key left on
+  its lane) and expands each row into ``H = G / 128`` rows by ``hi`` (row
+  ``m * H + h`` keeps the docs whose ``hi == h``), so one full-height
+  matmul gives every group's partial and a tile's work
   does not multiply rows by 128-group chunks. Exactness scheme:
   - **integer sums** split each value into 12-bit limbs (``L`` limbs for a
     plan-time ``max_abs`` bound), and each limb enters the MXU as two
@@ -87,6 +88,8 @@ _LIMB_MASK = (1 << _LIMB_BITS) - 1
 # magnitude <= 256): its low byte and ``limb >> 8`` (-16..15 for a signed
 # top limb)
 _HALF_BITS = 8
+# bf16's bits of 1.0: a one-hot entry, written into a 32-bit word's half
+_BF16_ONE = 0x3F80
 _HALF_MASK = (1 << _HALF_BITS) - 1
 # f32 can represent integers exactly below 2^24 (min/max value bound)
 _F32_EXACT = 1 << 24
@@ -877,23 +880,52 @@ def build_kernel(spec: PallasSpec):
                 len(int_rows), T)
 
             # -- two-level one-hot accumulate: group g = hi * 128 + lo. ONE
-            # [T, 128] one-hot of ``lo`` a tile; ``hi`` expands every matmul
+            # [128, T] one-hot of ``lo`` a tile; ``hi`` expands every matmul
             # row into Hp rows (row m*Hp + h keeps the docs whose hi == h), so
             # part[m*Hp + h, l] is row m's partial of group h*128 + l and the
             # MXU sees a full-height LHS once, not M rows against G/128
             # one-hots. H == 1 (scalar aggregations, <= 128 groups) needs no
             # expansion: the rows go in as they are. A plain 2-D matmul over
             # the tile's flattened docs: Mosaic has no dot_general with two
-            # contracting dims, and takes the (RT, 128) -> T flattening as a
-            # relayout. The one-hot (0/1) and the integer rows (0/1, a limb's
-            # low byte, its high half; an expanded row holds those or 0) are
-            # bf16-exact, so they take ONE default-precision bf16 pass whose
-            # f32 partials are exact integers (the argument above); float-sum
-            # rows take an fp32 contraction against the same one-hot
+            # contracting dims, and takes the (RT, 128) -> T flattening of
+            # the few matmul rows as a relayout. The one-hot (0/1) and the
+            # integer rows (0/1, a limb's low byte, its high half; an
+            # expanded row holds those or 0) are bf16-exact, so they take ONE
+            # default-precision bf16 pass whose f32 partials are exact
+            # integers (the argument above); float-sum rows take an fp32
+            # contraction against the same one-hot.
+            # The one-hot keeps every key on its lane: groups on sublanes,
+            # docs on lanes, one [128, 128] block a key row (that row
+            # broadcast down the sublanes), the blocks side by side along
+            # lanes in the rows' flattened doc order. Each 32-bit word holds
+            # two groups' bf16 entries, the even one's in its low half (the
+            # packed bf16 layout ``pltpu.bitcast`` reads), so a block is 64
+            # compares and selects of whole words; the contraction on the
+            # docs axis of both operands latches the one-hot transposed into
+            # the MXU. Masked docs outside a narrowed key range: a negative
+            # key's pair is negative, and with H == 1 a key >= 128 has a
+            # pair >= 64, so neither matches a group (their rows are
+            # mask-zeroed anyway)
             lo = keys if H == 1 else keys & (_G_CHUNK - 1)
-            oh_lo = (lo[:, :, None] == jax.lax.broadcasted_iota(
-                jnp.int32, (RT, 128, _G_CHUNK), 2)
-            ).astype(jnp.bfloat16).reshape(T, _G_CHUNK)
+            pair = lo >> 1
+            one = jnp.where((lo & 1) == 0, _BF16_ONE, _BF16_ONE << 16)
+            # lax ops, not jnp's indexing and where: Mosaic gets the same
+            # ops, and the 32 blocks trace and lower in about a third less
+            # time (the warm-up lowers every scan program, cached or not)
+            block = (_G_CHUNK // 2, 128)
+            pair_iota = jax.lax.broadcasted_iota(jnp.int32, block, 0)
+            no_word = jnp.zeros(block, jnp.int32)
+
+            def word_block(r):
+                row = (r + 1, 128)
+                hit = jax.lax.slice(pair, (r, 0), row) == pair_iota
+                word = jax.lax.broadcast_in_dim(
+                    jax.lax.slice(one, (r, 0), row), block, (0, 1))
+                return pltpu.bitcast(jax.lax.select(hit, word, no_word),
+                                     jnp.bfloat16)
+
+            oh_lo = jnp.concatenate([word_block(r) for r in range(RT)],
+                                    axis=1)
             if H > 1:
                 # masked docs outside a narrowed key range: an arithmetic
                 # shift leaves hi negative or >= H, which selects no row (or a
@@ -903,8 +935,9 @@ def build_kernel(spec: PallasSpec):
                 sel = hi == jax.lax.broadcasted_iota(jnp.int32, (Hp, T), 0)
 
             def accumulate(rows, oh, precision, land):
-                """``rows`` [M, T] @ ``oh`` (both of one dtype) in blocks
-                of rows_per_dot matmul rows; ``land(m, x)`` takes row m's
+                """``rows`` [M, T] against ``oh`` [128, T] (both of one
+                dtype) on the docs axis of both, in blocks of
+                rows_per_dot matmul rows; ``land(m, x)`` takes row m's
                 [Hp, 128] partial. bf16 rows expand by a 0/1 product
                 (Mosaic cannot relayout a bool mask onto bf16's (16, 128)
                 tiles; an integer row is finite, so the product is the
@@ -923,8 +956,10 @@ def build_kernel(spec: PallasSpec):
                         lhs = jnp.concatenate(
                             [sel_r * rows[m:m + 1] for m in range(m0, m1)],
                             axis=0)
-                    part = jnp.dot(lhs, oh, precision=precision,
-                                   preferred_element_type=jnp.float32)
+                    part = jax.lax.dot_general(
+                        lhs, oh, (((1,), (1,)), ((), ())),
+                        precision=precision,
+                        preferred_element_type=jnp.float32)
                     for m in range(m0, m1):
                         land(m, part[(m - m0) * Hp:(m - m0 + 1) * Hp])
 
@@ -1064,20 +1099,25 @@ def build_kernel(spec: PallasSpec):
 
 
 class PallasKernelCache:
-    def __init__(self):
-        self._cache: Dict[PallasSpec, Any] = {}
+    """The per-segment path's compiled programs, one a (kernel spec,
+    plan spec) pair: see ``segment_program``."""
 
-    def get(self, spec: PallasSpec):
-        k = self._cache.get(spec)
+    def __init__(self):
+        self._cache: Dict[Tuple, Any] = {}
+
+    def get(self, spec: PallasSpec, plan_spec: Optional[Tuple] = None):
+        key = (spec, plan_spec)
+        k = self._cache.get(key)
         if k is None:
-            k = jax.jit(build_kernel(spec))
-            self._cache[spec] = k
+            k = jax.jit(segment_program(spec, plan_spec))
+            self._cache[key] = k
         return k
 
-    def pop(self, spec: PallasSpec) -> None:
+    def pop(self, spec: PallasSpec, plan_spec: Optional[Tuple] = None
+            ) -> None:
         """Evict a kernel whose compile/run failed (the caller blocklists
         the plan shape; keeping the entry would only leak the closure)."""
-        self._cache.pop(spec, None)
+        self._cache.pop((spec, plan_spec), None)
 
     def __len__(self):
         return len(self._cache)
@@ -1151,8 +1191,8 @@ def assemble_outputs(plan_spec: Tuple, spec: PallasSpec, out_f, out_i, out_mm,
 # --------------------------------------------------------------------------
 
 def _stage_packed(pp: PallasPlan, staged: StagedSegment, decline):
-    """(packed device blocks, bits) for the plan's packed columns, or None
-    (reason recorded)."""
+    """(packed device words, bits) for the plan's packed columns, or None
+    (reason recorded); ``segment_program`` cuts the words into blocks."""
     packed_cols = []
     bits = []
     for nm in pp.packed_names:
@@ -1161,16 +1201,15 @@ def _stage_packed(pp: PallasPlan, staged: StagedSegment, decline):
             decline("pallas_column_not_packable")
             return None
         bits.append(pc.bits)
-        W = PALLAS_TILE // pc.vals_per_word
-        packed_cols.append(pc.words.reshape(1, -1, W // 128, 128))
+        packed_cols.append(pc.words)
     return packed_cols, bits
 
 
 def _stage_values(pp: PallasPlan, staged: StagedSegment, decline):
     """Value refs in kernel order: one f32/i32 array per plain input, L
     i32 limb planes per i64-staged input (the value-load layer of the
-    multi-limb accumulation). None (reason recorded) when a column can't
-    serve the fused layout."""
+    multi-limb accumulation), cut into blocks by ``segment_program``.
+    None (reason recorded) when a column can't serve the fused layout."""
     vlimbs = pp.value_limbs or (0,) * len(pp.value_names)
     value_cols = []
     for nm, L in zip(pp.value_names, vlimbs):
@@ -1179,22 +1218,53 @@ def _stage_values(pp: PallasPlan, staged: StagedSegment, decline):
             if planes is None:
                 decline("pallas_value_layout_unsupported")
                 return None
-            value_cols.extend(
-                p.reshape(1, -1, PALLAS_TILE // 128, 128) for p in planes)
+            value_cols.extend(planes)
             continue
         v = staged.value_column(nm)
         if v is None or v.dtype not in (jnp.float32, jnp.int32):
             decline("pallas_value_layout_unsupported")
             return None
-        value_cols.append(v.reshape(1, -1, PALLAS_TILE // 128, 128))
+        value_cols.append(v)
     return value_cols
 
 
-def _segment_params(pp: PallasPlan, staged: StagedSegment):
-    return jnp.concatenate([
-        jnp.asarray(pp.static_params, dtype=jnp.int32).reshape(-1),
-        jnp.asarray([staged.num_docs, 0], dtype=jnp.int32),
-    ])
+def segment_program(spec: PallasSpec, plan_spec: Optional[Tuple]):
+    """fn(static_params, num_docs, packed_cols, value_cols) over ONE
+    staged segment, everything from the runtime params to the answer in
+    one program: the params vector, the blocks' tile shapes, the kernel,
+    and with a ``plan_spec`` the packed f64 output vector
+    (``assemble_outputs`` + ``pack_outputs``), without it the group-range
+    probe's min/max rows. One dispatch a launch: the same steps run
+    eagerly cost a dispatch each, some forty a query, and a request's
+    thread pays each of them again for the interpreter lock when other
+    requests run Python beside it."""
+    from pinot_tpu.engine.kernels import pack_outputs
+
+    call = build_kernel(spec)
+
+    def pallas_scan_segment(static_params, num_docs, packed_cols,
+                            value_cols):
+        params = jnp.concatenate([
+            static_params.astype(jnp.int32).reshape(-1),
+            jnp.stack([num_docs.astype(jnp.int32), jnp.int32(0)])])
+        packed = [c.reshape(1, -1, PALLAS_TILE // (32 // bits) // 128, 128)
+                  for c, bits in zip(packed_cols, spec.packed_bits)]
+        values = [v.reshape(1, -1, PALLAS_TILE // 128, 128)
+                  for v in value_cols]
+        out_f, out_i, out_mm, _seg = call(params, *packed, *values)
+        if plan_spec is None:
+            return out_mm
+        return pack_outputs(assemble_outputs(plan_spec, spec, out_f, out_i,
+                                             out_mm, seg_matched=None),
+                            plan_spec)
+
+    return pallas_scan_segment
+
+
+def _segment_args(pp: PallasPlan, staged: StagedSegment):
+    """The runtime params of a per-segment launch, as host arrays."""
+    return (np.asarray(pp.static_params, dtype=np.int32).reshape(-1),
+            np.int32(staged.num_docs))
 
 
 def _run_probe_segment(probe_pp: PallasPlan, staged: StagedSegment,
@@ -1212,8 +1282,7 @@ def _run_probe_segment(probe_pp: PallasPlan, staged: StagedSegment,
         tuple(bits))
     kernel = cache.get(spec)
     try:
-        _f, _i, out_mm, _s = kernel(_segment_params(probe_pp, staged),
-                                    *packed_cols)
+        out_mm = kernel(*_segment_args(probe_pp, staged), packed_cols, [])
     except Exception:
         cache.pop(spec)
         raise
@@ -1235,8 +1304,6 @@ def run_segment(plan, staged: StagedSegment, cache: PallasKernelCache,
     reason code, same contract as ``extract_plan``). ``on_probe`` receives
     the accumulate kind of each group-range probe launched, ``on_launch``
     the PallasSpec of the scan launched."""
-    from pinot_tpu.engine.kernels import pack_outputs
-
     def decline(reason: str) -> None:
         if on_decline is not None:
             on_decline(reason)
@@ -1271,19 +1338,17 @@ def run_segment(plan, staged: StagedSegment, cache: PallasKernelCache,
     tiles = staged.pallas_capacity() // PALLAS_TILE
     spec = pp.spec(num_segs=1, tiles_per_seg=tiles, interpret=interpret)
     spec = _with_bits(spec, tuple(bits))
-    kernel = cache.get(spec)
+    kernel = cache.get(spec, eff.spec)
 
     try:
-        out_f, out_i, out_mm, out_seg = kernel(
-            _segment_params(pp, staged), *packed_cols, *value_cols)
+        packed = kernel(*_segment_args(pp, staged), packed_cols, value_cols)
     except Exception:
-        cache.pop(spec)  # symmetric with the sharded handler's eviction
+        # symmetric with the sharded handler's eviction
+        cache.pop(spec, eff.spec)
         raise
     if on_launch is not None:
         on_launch(spec)
-    tree = assemble_outputs(eff.spec, spec, out_f, out_i, out_mm,
-                            seg_matched=None)
-    return pack_outputs(tree, eff.spec), eff
+    return packed, eff
 
 
 def _with_bits(spec: PallasSpec, bits: Tuple[int, ...]) -> PallasSpec:

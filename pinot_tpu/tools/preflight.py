@@ -172,6 +172,7 @@ def _vmem_estimate(spec, model: LoweringModel) -> int:
     pallas_kernels.build_kernel's layout via the same ``_row_layout`` and
     ``accumulate_rows``."""
     from pinot_tpu.engine.pallas_kernels import (
+        _G_CHUNK,
         _row_layout,
         accumulate_rows,
         spec_accumulate_kind,
@@ -205,9 +206,18 @@ def _vmem_estimate(spec, model: LoweringModel) -> int:
         n_int = 1 + 2 * sum(L for (_s, L) in isum.values())
         n_float = Mf // 2
         total += n_int * T * 2 + n_float * T * 4
-        # the tile's one-hot: lane iota i32 + oh_lo [RT, 128, 128] bf16,
-        # and its f32 copy where float rows take the fp32 contraction
-        total += T * model.lane * (4 + 2 + (4 if n_float else 0))
+        # the tile's one-hot, groups on sublanes: the key-pair and bf16-one
+        # planes [RT, 128] i32 and the pair iota [_G_CHUNK / 2, 128] i32
+        # it is built from, oh_lo [_G_CHUNK, T] bf16 (its 32-bit words
+        # bitcast in place), and its f32 copy where float rows take the
+        # fp32 contraction. Counted once: the RT selected [64, 128] word
+        # blocks are the one-hot's own vregs, since a concatenate along
+        # lanes at 128-lane boundaries copies nothing (Mosaic's dump of
+        # Q2.2 latches each selected vreg into the MXU as it is made: 256
+        # selects, 256 latches, 16 vector stores in the whole tile body),
+        # so this term bounds the build from above
+        total += 2 * T * 4 + _G_CHUNK // 2 * model.lane * 4
+        total += _G_CHUNK * T * (2 + (4 if n_float else 0))
         if H > 1:
             # the hi-select mask [Hp, T] (and its bf16 0/1 copy), and the
             # larger of the two stacks' expanded LHS blocks (at most

@@ -1450,10 +1450,9 @@ def test_device_i64_inside_kernel_body(tmp_path):
 def test_device_i64_outside_blessed_functions(tmp_path):
     src = _real_src("engine/pallas_kernels.py")
     bad = src.replace(
-        "def _segment_params(pp: PallasPlan, staged: StagedSegment):\n"
-        "    return jnp.concatenate([",
-        "def _segment_params(pp: PallasPlan, staged: StagedSegment):\n"
-        "    _w = jnp.int64(0)\n    return jnp.concatenate([")
+        "def _segment_args(pp: PallasPlan, staged: StagedSegment):\n",
+        "def _segment_args(pp: PallasPlan, staged: StagedSegment):\n"
+        "    _w = jnp.int64(0)\n")
     assert bad != src
     hits = _device_scratch(tmp_path, "pallas_kernels.py", bad)
     assert len(hits) == 1 and "blessed" in hits[0].message, \

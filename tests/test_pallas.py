@@ -380,29 +380,50 @@ def _pack_planar(ids, bits):
     return words.reshape(S, TPS, W // 128, 128)
 
 
-def _accumulate_case(G, case):
+def _edge_keys(G):
+    """Narrowed keys at the one-hot's edges: a chunk's first and last
+    lanes (0, 1, 126, 127, and the last chunk's), the last real group;
+    masked ones just outside: negative (down to dictId 0), the pad groups
+    [Gn, G), G and past it, and hi in the pad rows [H, Hp) and at Hp."""
+    from pinot_tpu.engine.pallas_kernels import accumulate_rows
+
+    H, Hp, _ = accumulate_rows(G)
+    Gn = G - 7
+    top = 128 * (H - 1)
+    real = {0, 1, 126, 127, top, top + 1, Gn - 2, Gn - 1}
+    masked = {-_KEY_OFFSET, 1 - _KEY_OFFSET, -2, -1, Gn, G - 1, G, G + 1,
+              G + 126, G + 127, 128 * Hp - 1, 128 * Hp}
+    return np.asarray(sorted(real | masked))
+
+
+def _accumulate_case(G, case, keys="random"):
     """(spec, params, cols, oracle rows) of one exactness case: a 16-bit
     group column whose dictIds overshoot the narrowed range [50, 50 + Gn)
     on both sides (the filter masks those docs; their keys are negative or
-    >= G), an 8-bit filter column, two segments with partial last tiles."""
+    >= G), an 8-bit filter column, two segments with partial last tiles.
+    ``keys``: ``random`` draws them over the range and past it, ``edges``
+    from ``_edge_keys``."""
     from pinot_tpu.engine.pallas_kernels import PallasSpec
 
     S, TPS, T = 2, 2, PALLAS_TILE
     Gn = G - 7                      # real groups: the pad is not empty
     rng = np.random.default_rng(G * 31 + len(case))
-    gid = rng.integers(0, G + 100, (S, TPS, T))
+    if keys == "random":
+        gid = rng.integers(0, G + 100, (S, TPS, T))
+    else:
+        gid = _KEY_OFFSET + rng.choice(_edge_keys(G), (S, TPS, T))
     fid = rng.integers(0, 256, (S, TPS, T))
     doc = np.arange(TPS * T).reshape(TPS, T)
     valid = np.stack([doc < n for n in _SEG_DOCS])
     mask = (valid & (gid >= _KEY_OFFSET) & (gid <= _KEY_OFFSET + Gn - 1)
             & (fid <= 199))
-    keys = (gid - _KEY_OFFSET)[mask]
+    kept_keys = (gid - _KEY_OFFSET)[mask]
     assert (gid - _KEY_OFFSET)[~mask].min() < 0 \
         and (gid - _KEY_OFFSET)[~mask].max() >= G
 
     def by_group(v):
         out = np.zeros(G, dtype=v.dtype)
-        np.add.at(out, keys, v[mask])
+        np.add.at(out, kept_keys, v[mask])
         return out
 
     if case == "int":
@@ -447,11 +468,26 @@ def test_build_kernel_accumulate_is_exact(G, case):
     Q2.1 shape and at MAX_PALLAS_GROUPS); float sums within the file's
     tolerance. Masked docs whose narrowed key is negative or >= G match
     nothing; the carry chain runs across tiles and segments."""
+    _check_accumulate(G, case, "random")
+
+
+@pytest.mark.parametrize("case", ["int", "float", "v64"])
+@pytest.mark.parametrize("G", [128, 384, 8192])
+def test_one_hot_is_exact_at_the_build_edges(G, case):
+    """The one-hot holds two groups a 32-bit word (an even key in its low
+    half, an odd one in its high half): counts, int-sum limbs and float
+    sums stay exact with every doc on a chunk's edge lanes (0, 1, 126,
+    127) or a masked doc just outside the range (negative keys, the pad
+    groups, ``hi`` in the pad rows [H, Hp) and at Hp)."""
+    _check_accumulate(G, case, "edges")
+
+
+def _check_accumulate(G, case, keys):
     import jax
 
     from pinot_tpu.engine.pallas_kernels import _row_layout, build_kernel
 
-    spec, params, cols, want = _accumulate_case(G, case)
+    spec, params, cols, want = _accumulate_case(G, case, keys)
     out_f, out_i, _mm, out_seg = jax.jit(build_kernel(spec))(params, *cols)
     out_f, out_i = np.asarray(out_f), np.asarray(out_i)
     fsum_row, isum_row, _, Mf, Mi, _ = _row_layout(spec)
@@ -460,6 +496,9 @@ def test_build_kernel_accumulate_is_exact(G, case):
     np.testing.assert_array_equal(np.asarray(out_seg).sum(axis=1),
                                   want["seg"])
     assert want["count"][G - 7:].sum() == 0 and want["count"].sum() > 0
+    if keys == "edges":
+        assert np.count_nonzero(want["count"]) == len(
+            set(_edge_keys(G)) & set(range(G - 7)))
     for _vexpr, (start, L) in isum_row.items():
         rows = out_i[start:start + L + 2].astype(np.int64)
         # normalized carry chain: every limb row back inside 12 bits
@@ -474,9 +513,12 @@ def test_build_kernel_accumulate_is_exact(G, case):
 @pytest.mark.parametrize("top", [4095, -4096])
 @pytest.mark.parametrize("column", ["int", "v64"])
 @pytest.mark.parametrize("G", [128, 8192])
-def test_bf16_pass_is_exact_at_the_limb_limits(G, column, top):
-    """A full tile in ONE group (the last: its one-hot column and, at
-    8192 groups, its hi row are the extreme ones), every doc carrying 4095
+@pytest.mark.parametrize("group", ["first", "last"])
+def test_bf16_pass_is_exact_at_the_limb_limits(group, G, column, top):
+    """A full tile in ONE group (the first or the last: its one-hot
+    entry sits in the low or the high half of a word, on the first or the
+    last sublane, and at 8192 groups its hi row is the first or the
+    last), every doc carrying 4095
     in each limb below the top and ``top`` in the signed top limb: each
     limb half's partial is the largest a tile makes (255 * T, and -16 * T
     or 15 * T), so one bf16 pass must still give numpy's int64 sum to the
@@ -499,7 +541,7 @@ def test_bf16_pass_is_exact_at_the_limb_limits(G, column, top):
     else:
         values, vexpr = [np.full((1, 1, T // 128, 128), limb, np.int32)
                          for limb in limbs], "v64"
-    key = G - 1
+    key = 0 if group == "first" else G - 1
     spec = PallasSpec(
         num_segs=1, tiles_per_seg=1, packed_bits=(16,),
         filter_tree=("true",), n_slots=0, group_idx=(0,),

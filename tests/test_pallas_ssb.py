@@ -138,6 +138,26 @@ def test_ssb13_bit_parity_vs_jnp(ssb_segs, ctxs, pallas_cache, qid):
             assert got.groups == want.groups, qid
 
 
+@pytest.mark.parametrize("qid", sorted(q for q in ssb.QUERIES
+                                 if q not in NARROWED))
+def test_segment_launch_is_one_program(ssb_segs, ctxs, pallas_cache, qid):
+    """A per-segment launch is ONE compiled program from the runtime
+    params to the packed answer: once the segment's columns are staged
+    (the first run decodes and pads them), a traced ``run_segment`` holds
+    no device operation but the one jitted call (run eagerly, the params'
+    concatenate, the blocks' reshapes, the limb reassembly and the packing
+    cost a dispatch each, and a request's thread the interpreter lock
+    again after each)."""
+    import jax
+
+    staged = StagingCache().stage(ssb_segs[0])
+    plan = plan_segment(ctxs[qid], ssb_segs[0])
+    assert run_segment(plan, staged, pallas_cache, interpret=True)
+    jaxpr = jax.make_jaxpr(lambda: run_segment(
+        plan, staged, pallas_cache, interpret=True)[0])()
+    assert [e.primitive.name for e in jaxpr.eqns] == ["jit"], qid
+
+
 def test_sharded_all_13_parity_and_zero_declines(ssb_segs, ctxs):
     """The serving path: every flight through the sharded executor with
     pallas on matches the host engine exactly, the decline histogram

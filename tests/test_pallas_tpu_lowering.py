@@ -6,7 +6,7 @@ the executors run it: the check that caught the kernel's two refusals (a
 ``dot_general`` contracting two dims, 64-bit scalars leaking into the
 body) before any chip was asked. The ``compiles_for_v5e`` tests go one
 step on: libtpu compiles the two-level accumulate at its widest shapes,
-and Q2.2's single chunk, for a v5e that is described and not attached
+Q2.2's single chunk and the float-sum rows, for a v5e that is described and not attached
 (VMEM, tiling and slices are judged there, not in the lowering). Neither
 runs anything:
 ``chip_smoke.py`` and the benchmark do.
@@ -214,13 +214,7 @@ def test_integer_rows_take_one_bf16_pass(staged, shape):
                                  tiles_per_seg=3)) == []
         return
     if shape == "float_sum":
-        # Q1.1's two value inputs: an int sum of one, a float sum of the
-        # other (not bf16-exact)
-        spec = _spec_of("Q1.1", staged)
-        spec = replace(spec, aggs=(("sum", ("v", 0), 3),
-                                   ("sum", ("v", 1), None)),
-                       value_is_int=(True, False))
-        dots = _dots(spec)
+        dots = _dots(_float_sum_spec("Q1.1", staged))
         assert sorted(d for d, _p in dots) == [
             ("bfloat16", "bfloat16"), ("float32", "float32")]
         for dtypes, precision in dots:
@@ -246,6 +240,64 @@ def test_single_chunk_accumulate_compiles_for_v5e(staged, one_chip):
             for a in _abstract_args(spec)]
     assert preflight.preflight_spec(spec).ok
     jax.jit(build_kernel(spec)).lower(*args).compile()
+
+
+@pytest.mark.parametrize("qid", ["Q1.1", "Q2.1"])
+def test_float_sum_accumulate_compiles_for_v5e(staged, one_chip, qid):
+    """Mosaic's verdict on the float-sum rows' fp32 contraction
+    (HIGHEST, against the one-hot cast to f32) at the benchmark's grid:
+    in one chunk beside a bf16 integer pass (Q1.1) and two-level (Q2.1 at
+    its 4096 groups, its sum over a float column)."""
+    spec = replace(_float_sum_spec(qid, staged), interpret=False,
+                   num_segs=8, tiles_per_seg=733)
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in _abstract_args(spec)]
+    assert preflight.preflight_spec(spec).ok
+    jax.jit(build_kernel(spec)).lower(*args).compile()
+
+
+def _float_sum_spec(qid, staged):
+    """The flight's program with a float sum: Q1.1's two value inputs
+    an int sum of one and a float sum of the other (not bf16-exact);
+    Q2.1's one sum over a float column."""
+    spec = _spec_of(qid, staged)
+    if qid == "Q1.1":
+        return replace(spec, aggs=(("sum", ("v", 0), 3),
+                                   ("sum", ("v", 1), None)),
+                       value_is_int=(True, False))
+    return replace(spec, aggs=(("sum", ("v", 0), None),),
+                   value_is_int=(False,))
+
+
+def _assert_keys_stay_on_lanes(spec):
+    """The kernel builds its one-hot as ``[128 groups, T docs]`` blocks
+    from each key row broadcast down the sublanes, and contracts it on
+    the docs axis of both operands: no array of the ``[RT, 128, 128]``
+    shape that broadcasting each key across 128 lanes makes (a lane-to-
+    sublane relayout of every key of a tile), and every ``dot_general``
+    takes the one-hot as a ``[128, T]`` right operand."""
+    RT = PALLAS_TILE // 128
+    eqns = _kernel_eqns(spec)
+    wide = [(e.primitive.name, v.aval.shape) for e in eqns for v in e.outvars
+            if tuple(getattr(v.aval, "shape", ())) == (RT, 128, 128)]
+    assert not wide, wide
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert dots
+    for e in dots:
+        assert e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
+        assert e.invars[1].aval.shape == (128, PALLAS_TILE)
+
+
+@pytest.mark.parametrize("shape", sorted(ssb.QUERIES) + ["Q2.1_g8192"])
+def test_one_hot_keeps_every_key_on_its_lane(staged, shape):
+    """Every SSB flight's program (each builds a one-hot: the grouped ones
+    and Q1's sums), and Q2.1's at MAX_PALLAS_GROUPS, keeps the build that
+    moves no key off its lane."""
+    qid, _, groups = shape.partition("_g")
+    spec = _spec_of(qid, staged)
+    if groups:
+        spec = replace(spec, num_groups_padded=int(groups))
+    _assert_keys_stay_on_lanes(spec)
 
 
 @pytest.mark.parametrize("shape", sorted(ssb.QUERIES) + [

@@ -147,7 +147,7 @@ def test_fuzz_grid_rules_exact(table):
     ("groups128", True), ("groups1024", True), ("groups8192", True),
     ("rows6_groups8192", True), ("wide96_vmem_over", False)])
 def test_vmem_estimate_of_the_two_level_working_set(label, fits):
-    """The model's working set follows build_kernel's: one [T, 128]
+    """The model's working set follows build_kernel's: one [128, T]
     bf16 one-hot a tile whatever the group count, plus, above 128 groups,
     the hi-select mask and ONE expanded row block (of bf16 integer rows,
     two a limb, or of f32 float-sum rows). The widest plans stay inside
@@ -176,6 +176,36 @@ def test_vmem_estimate_of_the_two_level_working_set(label, fits):
     base = preflight._vmem_estimate(
         dataclasses.replace(spec, num_groups_padded=lane), model)
     assert got - base == added
+
+
+def test_vmem_estimate_pins_the_single_chunk_one_hot(segs):
+    """Q2.2's working set at the benchmark's grid, to the byte: its
+    blocks, dictId planes and accumulators, the integer rows (the count
+    and two bf16 halves a limb), and the one-hot as build_kernel holds it
+    with every key on its lane: the key-pair and bf16-one planes, the
+    [64, 128] pair iota and the [128, T] bf16 one-hot, 1,114,112 bytes
+    (the lane-broadcast build held a [T, 128] i32 iota beside it, three
+    times that)."""
+    from pinot_tpu.engine.pallas_kernels import _row_layout
+
+    ctx = compile_query(ssb.QUERIES["Q2.2"] + " LIMIT 100000")
+    staged = StagingCache().stage(segs[0])
+    spec, _eff, reason = preflight.extract_query_spec(
+        plan_segment(ctx, staged.segment), staged)
+    assert spec is not None, reason
+    spec = dataclasses.replace(spec, num_segs=8, tiles_per_seg=733)
+    assert spec.num_groups_padded == 128 and spec.value_is_int == (True,)
+    T, lane = PALLAS_TILE, preflight.TPU_V5E.lane
+    _f, isum, _mm, Mf, Mi, Mm = _row_layout(spec)
+    (L,) = [L for _start, L in isum.values()]
+    blocks = sum(T * bits // 32 * 4 for bits in spec.packed_bits) + T * 4
+    planes = len(spec.packed_bits) * T * 4
+    accumulators = (Mf + Mi) * lane * 4 + Mm * 128 * 4 + 8 * lane * 4
+    int_rows = (1 + 2 * L) * T * 2
+    one_hot = 2 * T * 4 + 64 * lane * 4 + 128 * T * 2
+    assert one_hot == 1_114_112
+    assert preflight._vmem_estimate(spec, preflight.TPU_V5E) == (
+        blocks + planes + accumulators + int_rows + one_hot)
 
 
 def test_fuzz_grid_covers_the_announced_axes():

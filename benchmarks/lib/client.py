@@ -4,7 +4,11 @@
 connection per client; latency is this process's clock from the moment a
 request is sent (open loop: from the moment it was due) to the last byte of
 its response. Every response body is kept and written out when the run is
-over; the parent compares them after the window.
+over; the parent compares them after the window. A job with ``start_wall``
+(a hybrid table's window, which a producer fills from that moment on)
+starts then, and each record also carries ``sent_epoch`` and ``done_epoch``,
+``time.time()`` read beside the two clock readings: the producer's log is
+on that clock.
 
 Runners:
 
@@ -57,17 +61,20 @@ class Run:
         self.drawn = list(job.get("offsets", ()))   # closed: a queue's next
         self.barrier = (threading.Barrier(job["clients"])
                         if job.get("lockstep") else None)
+        self.walls = job.get("start_wall") is not None
 
     def send(self, conn, client: int, step: int, index: int,
              due: float = None) -> None:
         sql = self.sqls[index]
         sent = time.perf_counter()
+        sent_wall = time.time() if self.walls else None
         try:
             status, raw = _post(conn, self.job["path"], sql)
             error = None
         except (OSError, http.client.HTTPException) as e:
             status, raw, error = 0, b"", f"{type(e).__name__}: {e}"
             conn.close()
+        done_wall = time.time() if self.walls else None
         done = time.perf_counter()
         start = sent if due is None else due
         rec = {"client": client, "step": step, "index": index,
@@ -76,6 +83,8 @@ class Run:
                "body": raw.decode("utf-8", "replace"), "error": error}
         if due is not None:
             rec["late_ms"] = (sent - due) * 1e3
+        if self.walls:
+            rec.update(sent_epoch=sent_wall, done_epoch=done_wall)
         with self.lock:
             self.records.append(rec)
 
@@ -124,6 +133,8 @@ class Run:
 
     def run(self) -> Dict[str, Any]:
         job = self.job
+        if job.get("start_wall") is not None:
+            time.sleep(max(0.0, job["start_wall"] - time.time()))
         self.t0_wall = time.time()
         self.t0 = time.perf_counter()
         targets = {"closed": self.closed, "open": self.open,

@@ -27,6 +27,17 @@ strings of a cycle share:
 Run as a script it is the oracle child of a benchmark run: it makes the
 table from the seed, answers the cell's cycle and writes the answers as JSON.
 numpy and the standard library only, so it never touches the chip.
+
+A hybrid table (``hybrid_answers``) is answered in two parts. The static
+part is ``want`` as above over the offline rows at or below the time
+boundary, the largest offline time less one unit (upstream's
+``TimeBoundaryManager``). The stream part is the rows the producer will
+have appended by the end of the run, regenerated from the table module's
+``stream_codes``: those of the members the cycle pins that lie past the
+boundary, with their partition offsets, written beside the JSON as
+``<out>.npz`` (the parent answers a response at the prefix of the stream
+it could have read), and for each window's end each partition's count and
+metric sums over all its rows.
 """
 
 from __future__ import annotations
@@ -239,14 +250,49 @@ def answers(table_mod, cols: Dict[str, np.ndarray],
     return out
 
 
+def hybrid_answers(table_mod, cols: Dict[str, np.ndarray],
+                   cycle: List[Dict[str, Any]], control: bool, seed: int,
+                   hybrid: Dict[str, Any]) -> Dict[str, Any]:
+    """``want`` (and ``control``) over the offline rows at or below the
+    boundary; ``stream``, the arrays of the members' rows past it, sorted
+    by member and offset; ``totals``, a partition's count and metric sums
+    at each window's end."""
+    tc, parts = hybrid["time_column"], hybrid["partitions"]
+    boundary = int(cols[tc].max()) - 1
+    keep = np.flatnonzero(cols[tc] <= boundary)
+    out = answers(table_mod, {k: v[keep] for k, v in cols.items()}, cycle,
+                  control)
+    members = np.asarray(hybrid["members"], dtype=np.int64)
+    chunks, totals = [], []
+    for p in range(parts):
+        end = hybrid["appended"][-1][p]
+        codes = table_mod.stream_codes(p, 0, end, seed, parts)
+        totals.append([[n] + [int(codes[m][:n].sum(dtype=np.int64))
+                              for m in hybrid["metrics"]]
+                       for n in (ends[p] for ends in hybrid["appended"])])
+        rows = np.flatnonzero(np.isin(codes[hybrid["partition_column"]],
+                                      members) & (codes[tc] > boundary))
+        chunk = {k: v[rows] for k, v in codes.items()}
+        chunk["offset"] = rows.astype(np.int64)
+        chunks.append(chunk)
+    stream = {k: np.concatenate([c[k] for c in chunks]) for k in chunks[0]}
+    order = np.lexsort((stream["offset"], stream[hybrid["partition_column"]]))
+    out["stream"] = {k: v[order] for k, v in stream.items()}
+    out["hybrid"] = {"boundary": boundary, "offline_rows": int(len(keep)),
+                     "stream_rows": int(len(order)),
+                     "totals": [list(w) for w in zip(*totals)]}
+    return out
+
+
 def answers_for(table: str, num_segments: int, rows: int, seed: int,
-                cycle: List[Dict[str, Any]], control: bool
-                ) -> Dict[str, Any]:
+                cycle: List[Dict[str, Any]], control: bool,
+                hybrid: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     t0 = time.perf_counter()
     table_mod = importlib.import_module(f"benchmarks.tables.{table}")
     cols = table_mod.table_codes(num_segments, rows, seed)
     made = time.perf_counter() - t0
-    out = answers(table_mod, cols, cycle, control)
+    out = (answers(table_mod, cols, cycle, control) if hybrid is None else
+           hybrid_answers(table_mod, cols, cycle, control, seed, hybrid))
     out["oracle"].update(table_s=made, seconds=time.perf_counter() - t0)
     return out
 
@@ -259,6 +305,10 @@ def main(argv: List[str]) -> int:
     with open(argv[0]) as f:
         job = json.load(f)
     out = answers_for(**job)
+    if "stream" in out:
+        with open(argv[1] + ".npz.tmp", "wb") as f:
+            np.savez(f, **out.pop("stream"))
+        os.replace(argv[1] + ".npz.tmp", argv[1] + ".npz")
     with open(argv[1] + ".tmp", "w") as f:
         json.dump(out, f)
     os.replace(argv[1] + ".tmp", argv[1])

@@ -19,6 +19,9 @@ import urllib.request
 from typing import Any, Dict, List, Tuple
 
 STORE_VERSION = 1
+# a consuming segment with no row yet, the moment after a seal: the host
+# "answers" it with nothing; no device work was declined
+NOTHING_TO_SERVE = frozenset({"mutable_empty_watermark"})
 STORES_CAP_BYTES = 16e9    # of segment stores kept under one data root
 # rows the builders hold between them: 8 x 3M fit the one-chip machine's
 # 40 GiB beside the oracle, 8 x 9M ran it out of memory (PERF.md, call 10)
@@ -149,7 +152,9 @@ class Served:
     """Cluster, REST endpoints and the one server's admin API."""
 
     def __init__(self, config: Dict[str, Any], seg_dirs: List[str],
-                 work_dir: str):
+                 work_dir: str, stream_url: str = ""):
+        """``stream_url``: the producer of a hybrid config (one with a
+        ``realtime`` section), whose REALTIME table consumes from it."""
         from pinot_tpu.common.tracing import LEDGER
         from pinot_tpu.spi import Schema
         from pinot_tpu.spi.table import (IndexingConfig, TableConfig,
@@ -158,24 +163,30 @@ class Served:
         from pinot_tpu.transport import rest
 
         self.ledger_mark = LEDGER.snapshot()
+        self.checks: Dict[str, int] = {}    # the harness's own queries'
         self.table = f"{config['table_name']}_OFFLINE"
+        self.realtime = config.get("realtime")
         self.cluster = EmbeddedCluster(
             num_servers=config["servers"],
             data_dir=os.path.join(work_dir, "cluster"),
             query_timeout_s=900.0)
         self.apis: List[Any] = []
         try:
-            self.cluster.create_table(
-                TableConfig(config["table_name"], TableType.OFFLINE,
-                            indexing_config=IndexingConfig.from_dict(
-                                config["tableIndexConfig"])),
-                Schema.from_dict(config["schema"]))
+            offline = TableConfig(config["table_name"], TableType.OFFLINE,
+                                  indexing_config=IndexingConfig.from_dict(
+                                      config["tableIndexConfig"]))
+            if self.realtime:
+                offline.validation_config = self._time_split()
+            self.cluster.create_table(offline,
+                                      Schema.from_dict(config["schema"]))
             for d in seg_dirs:
                 self.cluster.upload_segment_dir(self.table, d)
             if not self.cluster.wait_for_ev_converged(self.table,
                                                       timeout_s=900.0):
                 raise RuntimeError("external view did not converge")
             self.server = next(iter(self.cluster.servers.values()))
+            if self.realtime:
+                self._add_realtime(config, stream_url)
             self.apis = list(rest.serve_cluster(self.cluster))
             admin = rest.ServerAdminApi(self.server)
             admin.start()
@@ -187,17 +198,111 @@ class Served:
         self.urls = {"server": f"http://127.0.0.1:{admin.port}",
                      "broker": f"http://127.0.0.1:{self.broker_port}"}
 
+    # -- the REALTIME half of a hybrid config ---------------------------------
+    def _time_split(self):
+        """Both halves name the time column: the broker splits a query
+        at the boundary only where the OFFLINE half has one."""
+        from pinot_tpu.spi.table import SegmentsValidationConfig
+
+        return SegmentsValidationConfig(
+            time_column_name=self.realtime["time_column"], time_type="DAYS")
+
+    def _add_realtime(self, config: Dict[str, Any], stream_url: str) -> None:
+        # registers stream.type "socket" with the program's stream SPI
+        from pinot_tpu.ingestion.socketstream import BROKER_URL_PROP
+        from pinot_tpu.spi.table import (IndexingConfig,
+                                         StreamIngestionConfig, TableConfig,
+                                         TableType)
+
+        rt = self.realtime
+        self.realtime_table = f"{config['table_name']}_REALTIME"
+        self.stream_meta = (f"{stream_url}/topics/{config['table_name']}"
+                            f"/metadata")
+        self.cluster.controller.add_table(TableConfig(
+            config["table_name"], TableType.REALTIME,
+            validation_config=self._time_split(),
+            indexing_config=IndexingConfig.from_dict(rt["tableIndexConfig"]),
+            stream_config=StreamIngestionConfig(
+                stream_type="socket", topic=config["table_name"],
+                segment_flush_threshold_rows=rt["flush_threshold_rows"],
+                properties={BROKER_URL_PROP: stream_url})))
+
+    def ingest(self) -> Dict[str, Any]:
+        """The REALTIME half: a partition's rows appended (the stream's
+        own metadata, read first) and consumed (its consuming segment's
+        offset, else its last committed end offset; read in process), the
+        LLC manager's committed segments and how many of them the server
+        serves ONLINE, the consuming segments' docs."""
+        from pinot_tpu.controller.state import ONLINE
+
+        with urllib.request.urlopen(self.stream_meta, timeout=60) as r:
+            appended = json.loads(r.read().decode())["latest"]
+        store = self.cluster.store
+        tdm = self.server.data_manager.get(self.realtime_table)
+        view = store.get_external_view(self.realtime_table)
+        consumed = [0] * self.realtime["partitions"]
+        committed = online = docs = 0
+        for md in store.segment_metadata_list(self.realtime_table):
+            p = md.partition or 0
+            if md.status == ONLINE:
+                committed += 1
+                online += (view.get(md.segment_name, {}).get(
+                    self.server.instance_id) == ONLINE)
+                consumed[p] = max(consumed[p], int(md.end_offset or 0))
+                continue
+            mgr = tdm.consuming_manager(md.segment_name) if tdm else None
+            if mgr is not None:
+                consumed[p] = max(consumed[p], mgr.current_offset.value)
+                docs += mgr.segment.num_docs
+        return {"appended": appended, "consumed": consumed,
+                "committed": committed, "committedOnline": online,
+                "consumingDocs": docs}
+
+    def check_rows(self, sql: str) -> List[list]:
+        """A query of the harness's own (not the window's traffic): its
+        decisions are kept apart from ``ledger_breaches``."""
+        from pinot_tpu.common.tracing import LEDGER
+
+        mark = LEDGER.snapshot()
+        try:
+            return self.cluster.query_rows(sql)
+        finally:
+            for key, n in LEDGER.delta(mark).items():
+                self.checks[key] = self.checks.get(key, 0) + n
+
+    def wait_caught_up(self, rows: List[int], timeout_s: float = 900.0
+                       ) -> Dict[str, Any]:
+        """Until every partition has consumed ``rows[p]`` and the seals
+        those rows caused are ONLINE."""
+        flush = self.realtime["flush_threshold_rows"]
+        seals = sum(n // flush for n in rows)
+        deadline = time.monotonic() + timeout_s
+        while True:
+            got = self.ingest()
+            if (all(c >= n for c, n in zip(got["consumed"], rows))
+                    and got["committedOnline"] >= seals):
+                return got
+            if time.monotonic() > deadline:
+                raise RuntimeError(f"the REALTIME half did not catch up in "
+                                   f"{timeout_s}s: {got}, want {rows} "
+                                   f"consumed and {seals} seals")
+            time.sleep(0.05)
+
     def debug(self, role: str, path: str) -> Dict[str, Any]:
         with urllib.request.urlopen(self.urls[role] + path, timeout=60) as r:
             return json.loads(r.read().decode("utf-8"))
 
     def counters(self) -> Dict[str, Any]:
         """The program's own counts, read before and after a window."""
-        return {"launches": self.debug("server", "/debug/launches"),
-                "memory": self.debug("server", "/debug/memory"),
-                "scheduler": self.debug("server", "/debug/scheduler"),
-                "broker": self.debug("broker", "/debug/scheduler"),
-                "cache_entries": cache_entries()}
+        out = {"launches": self.debug("server", "/debug/launches"),
+               "memory": self.debug("server", "/debug/memory"),
+               "scheduler": self.debug("server", "/debug/scheduler"),
+               "broker": self.debug("broker", "/debug/scheduler"),
+               "cache_entries": cache_entries()}
+        if self.realtime:
+            out["ingest"] = dict(self.ingest(), freshness=self.debug(
+                "server", "/debug/freshness"))
+        return out
 
     @staticmethod
     def compiled_between(before: Dict[str, Any], after: Dict[str, Any]
@@ -228,8 +333,12 @@ class Served:
         if platform == "cpu":     # the toy drive on the CPU records these
             banned -= {"cpu_default_backend", "pallas_disabled_on_backend"}
         bad = []
-        for key in LEDGER.delta(self.ledger_mark):
+        for key, n in LEDGER.delta(self.ledger_mark).items():
+            if n <= self.checks.get(key, 0):
+                continue
             point, chosen, _declined, reason = parse_decision_key(key)
+            if self.realtime and reason in NOTHING_TO_SERVE:
+                continue
             if (reason in banned or reason.startswith("pallas_preflight_")
                     or point == "launch" or chosen == "host_engine"):
                 bad.append(key)

@@ -20,6 +20,14 @@ line says why: ``{"correct": false, "failed_run": <reason>, "phase":
 window are recorded with ``jax.profiler``, and the metrics are the cell's
 per-layer metrics, each read by ``metrics/<name>.py``.
 
+A configuration with a ``realtime`` section is a hybrid table
+(``lib/hybrid.py``): a producer process of the benchmark's own
+(``lib/producer.py``, standard library and numpy) fills its REALTIME half
+on a schedule, each response is judged against the stream prefix it could
+have read, and the ingest's guarantees are numbers beside limits. Every
+branch for it is keyed on that section: any other configuration takes
+the paths it always took.
+
 Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by the name in BENCHMARK.json; nothing here names
 one. The command line always expects a TPU; ``run()`` takes the platform,
@@ -182,6 +190,17 @@ class Child:
             self.proc.kill()
         self.proc.wait()
 
+    def ready(self, timeout_s: float) -> int:
+        """The port a child that serves wrote to ``<out>.ready``."""
+        deadline = time.monotonic() + timeout_s
+        while not os.path.exists(self.out + ".ready"):
+            if self.proc.poll() is not None or time.monotonic() > deadline:
+                raise RunFailed(f"{self.tag} child did not come up "
+                                f"(exit {self.proc.poll()})")
+            time.sleep(0.02)
+        with open(self.out + ".ready") as f:
+            return json.load(f)["port"]
+
 
 def with_trace(sql: str) -> str:
     if sql.endswith(")") and " OPTION(" in sql:
@@ -284,9 +303,13 @@ def run_window(served, setup: Dict[str, Any], seconds: float, trace: bool,
     traffic, cycle = setup["traffic"], setup["cycle"]
     sqls = [with_trace(q["sql"]) if trace else q["sql"] for q in cycle]
     before = served.counters()
-    child = Child("client.py",
-                  own_job(served, cycle, sqls, traffic, seconds),
-                  setup["work"], tag)
+    job = own_job(served, cycle, sqls, traffic, seconds)
+    if setup["config"].get("realtime"):     # the producer goes live with it
+        from benchmarks.lib import hybrid
+
+        job["start_wall"] = time.time() + hybrid.WINDOW_LEAD_S
+        setup["producer"].live(job["start_wall"], seconds)
+    child = Child("client.py", job, setup["work"], tag)
     try:
         profile = (profile_inside(setup["work"], seconds, traffic)
                    if trace else None)
@@ -304,7 +327,14 @@ def judge(served, setup: Dict[str, Any], win: Dict[str, Any],
     """The comparison that decides ``correct``: every response of the
     window against the oracle, and the deployment's guarantees."""
     config, cycle = setup["config"], setup["cycle"]
-    numbers = compare.compare(win["records"], cycle, setup["want"])
+    if config.get("realtime"):
+        from benchmarks.lib import hybrid
+
+        numbers = hybrid.compare_window(
+            win["records"], cycle, setup["want"], setup["stream"],
+            setup["pins"], win["log"], config, setup["table_mod"])
+    else:
+        numbers = compare.compare(win["records"], cycle, setup["want"])
     breaches = served.ledger_breaches(config["forbidden_decision_reasons"],
                                       platform)
     spills = (win["after"]["memory"]["counters"]["spills"]
@@ -317,6 +347,8 @@ def judge(served, setup: Dict[str, Any], win: Dict[str, Any],
     if numbers["first_failed"] or numbers["first_wrong"]:
         log(f"failed: {numbers['first_failed']} "
             f"wrong: {numbers['first_wrong']}")
+    if numbers.get("first_stale"):
+        log(f"stale: {numbers['first_stale']}")
     if breaches:
         log(f"the device did not serve everything: {breaches[:5]}")
     attempted = len(win["records"])
@@ -329,6 +361,9 @@ def judge(served, setup: Dict[str, Any], win: Dict[str, Any],
         "host_served_decisions": {"value": len(breaches), "limit": 0},
         "residency_spills": {"value": spills, "limit": 0},
     }
+    if config.get("realtime"):
+        compared["responses_stale"] = {"value": numbers["responses_stale"],
+                                       "limit": 0}
     ok = attempted > 0 and all(v["value"] <= v["limit"]
                                for v in compared.values())
     compared["responses_compared"] = {"value": numbers["responses_compared"],
@@ -362,8 +397,10 @@ def end_to_end(win: Dict[str, Any], records: List[Dict[str, Any]],
            "latency_p50_ms": stats.percentile(lat, 50.0),
            "latency_p95_ms": stats.percentile(lat, 95.0),
            "setup_s": setup["setup_s"]}
-    if peak_bytes:
-        out["hbm_peak_bytes_per_row"] = peak_bytes / setup["rows"]
+    if peak_bytes:      # a hybrid table's rows: offline and consumed
+        rows = setup["rows"] + (sum(win["ingest_end"]["consumed"])
+                                if setup["config"].get("realtime") else 0)
+        out["hbm_peak_bytes_per_row"] = peak_bytes / rows
     return {k: v for k, v in out.items() if v is not None}
 
 
@@ -386,7 +423,8 @@ def per_layer(win: Dict[str, Any], records: List[Dict[str, Any]],
                         <= device["wall_end"]],
            "config": setup["config"], "traffic": setup["traffic"],
            "cycle": setup["cycle"], "rows": setup["rows"],
-           "peak": setup["peak"], "table_mod": setup["table_mod"]}
+           "peak": setup["peak"], "table_mod": setup["table_mod"],
+           "ingest": win.get("ingest")}
     values = {}
     for name in names:
         value = metric_reader(name)(ctx)
@@ -400,10 +438,11 @@ def per_layer(win: Dict[str, Any], records: List[Dict[str, Any]],
 # --------------------------------------------------------------------------
 
 def set_up(workload: str, seed: int, expect_platform: str,
-           rows: Optional[int], data_root: str, control: bool
-           ) -> Dict[str, Any]:
+           rows: Optional[int], data_root: str, control: bool,
+           seconds: float = 0.0, windows: int = 1) -> Dict[str, Any]:
     """Everything before the window's first request. Returns the served
-    cluster and what the window and the comparison need."""
+    cluster and what the window and the comparison need. ``seconds`` and
+    ``windows`` size a hybrid table's stream for the oracle."""
     setup = load_cell(workload)
     config, traffic = setup["config"], setup["traffic"]
     rows = rows or config["rows"]
@@ -415,11 +454,23 @@ def set_up(workload: str, seed: int, expect_platform: str,
                  table_mod=importlib.import_module(
                      f"benchmarks.tables.{config['table']}"))
     phases: Dict[str, float] = {}
+    job = dict(table=config["table"], num_segments=config["segments"],
+               rows=rows, seed=seed, cycle=cycle, control=control)
+    producer = None
+    if config.get("realtime"):
+        from benchmarks.lib import hybrid
+
+        try:
+            setup["pins"] = hybrid.check(config, cycle)
+        except hybrid.ConfigRefused as e:
+            raise RunFailed(f"hybrid config refused: {e}") from None
+        job["hybrid"] = hybrid.oracle_job(config, setup["pins"], seconds,
+                                          windows)
+        producer = Child("producer.py", hybrid.producer_job(config, seed),
+                         work, "producer")
 
     # the plain reference, beside the build; numpy only, never the chip
-    oracle = Child("oracle.py", dict(
-        table=config["table"], num_segments=config["segments"], rows=rows,
-        seed=seed, cycle=cycle, control=control), work, "oracle")
+    oracle = Child("oracle.py", job, work, "oracle")
     served = None
     try:
         from benchmarks.lib import serve
@@ -433,8 +484,18 @@ def set_up(workload: str, seed: int, expect_platform: str,
         seg_dirs, phases["build_s"] = serve.segment_store(
             setup["cell"]["config"], config, rows, seed, data_root)
         t = time.perf_counter()
-        served = serve.Served(config, seg_dirs, work)
+        if producer is None:
+            served = serve.Served(config, seg_dirs, work)
+        else:
+            setup["producer"] = hybrid.Producer(producer.ready(60.0))
+            served = serve.Served(config, seg_dirs, work,
+                                  setup["producer"].url)
         phases["load_and_stage_s"] = time.perf_counter() - t
+        if producer is not None:    # the catch-up rows and their seals
+            t = time.perf_counter()
+            got = served.wait_caught_up(config["realtime"]["catch_up_rows"])
+            phases["catch_up_s"] = time.perf_counter() - t
+            phases["seals_at_setup"] = got["committed"]
         setup["counters_at_start"] = served.counters()
         t = time.perf_counter()
         with phase("warm"):
@@ -451,16 +512,107 @@ def set_up(workload: str, seed: int, expect_platform: str,
         phases["oracle_s"] = answers["oracle"]["seconds"]
         setup["want"] = answers["want"]
         setup["control"] = answers.get("control")
+        if producer is not None:
+            setup["stream"] = hybrid.Stream.load(
+                oracle.out + ".npz", config["realtime"]["partition_column"])
+            setup["totals"] = answers["hybrid"]["totals"]
+            log(f"oracle: boundary {answers['hybrid']['boundary']}, "
+                f"{answers['hybrid']['stream_rows']} stream rows of the "
+                f"pinned members past it")
         gc.collect()
         gc.freeze()     # set-up's objects are out of the collector's way
     except BaseException:
         oracle.kill()
         if served is not None:
             served.close()
+        if producer is not None:
+            producer.kill()
         raise
     setup["phases"] = phases
     setup["served"] = served
+    setup["producer_child"] = producer
     return setup
+
+
+def ingest_window(setup: Dict[str, Any], win: Dict[str, Any],
+                  seconds: float) -> None:
+    """A hybrid table's window, after the client: the lag at its end,
+    the drain, one count-and-sum query a partition over the REALTIME
+    half, the seals, the producer's log and lateness."""
+    from benchmarks.lib import hybrid
+
+    config, served, producer = (setup["config"], setup["served"],
+                                setup["producer"])
+    rt = config["realtime"]
+    i = setup["windows_done"] = setup.get("windows_done", 0) + 1
+
+    def lag() -> Dict[str, Any]:
+        got = served.ingest()
+        return {"appended": got["appended"], "consumed": got["consumed"],
+                "lag": sum(max(0, a - c) for a, c in zip(got["appended"],
+                                                         got["consumed"]))}
+
+    win["ingest_end"] = lag()
+    deadline = time.monotonic() + 2 * hybrid.WINDOW_LEAD_S + seconds
+    while producer.log(batches=False)["lives_done"] < i:   # its last rows
+        if time.monotonic() > deadline:
+            raise RunFailed("the producer's live phase did not end")
+        time.sleep(0.05)
+    t = time.perf_counter()
+    drained = lag()
+    while drained["lag"] and time.perf_counter() - t < rt["drain_s"]:
+        time.sleep(0.05)
+        drained = lag()
+    drained["drain_s"] = time.perf_counter() - t
+    expected = hybrid.appended_after(
+        config, i * hybrid.live_rows(config, seconds))
+    if drained["appended"] != expected:
+        raise RunFailed(f"the producer appended {drained['appended']}, the "
+                        f"oracle's stream holds {expected}")
+    off = 0
+    for p, want in enumerate(setup["totals"][i - 1]):
+        sql = hybrid.rows_exact_sql(config, served.realtime_table, p)
+        rows = served.check_rows(sql)
+        got = [int(v) for v in rows[0]] if rows else []
+        if got != want:
+            off += 1
+            log(f"rows_exact: partition {p} reads {got} (count, sums), the "
+                f"reference {want}")
+    full = producer.log()
+    seals = (win["after"]["ingest"]["committed"]
+             - win["before"]["ingest"]["committed"])
+    late = full["late_ms"][setup.get("late_seen", 0):]
+    setup["late_seen"] = len(full["late_ms"])
+    win["log"] = hybrid.Log(full["batches"], rt["partitions"])
+    win["guarantees"] = hybrid.guarantees(config, win["ingest_end"],
+                                          drained, seals, off)
+    # for the per-layer readers of a hybrid cell (their ctx["ingest"])
+    win["ingest"] = {"end": win["ingest_end"], "drained": drained,
+                     "seals": seals, "producer_late_ms": late}
+    setup["last_records"] = win["records"]
+    log(f"ingest over the window: appended {sum(drained['appended'])} "
+        f"consumed {sum(win['ingest_end']['consumed'])} at its end "
+        f"(lag {win['ingest_end']['lag']}), seals {seals}, drain "
+        f"{drained['drain_s']:.2f}s (lag {drained['lag']}), "
+        f"producer_late_p50_ms "
+        f"{stats.percentile(late, 50.0) if late else float('nan'):.3f}")
+
+
+def hold_guarantees(setup: Dict[str, Any], win: Dict[str, Any],
+                    verdict: Dict[str, Any]) -> None:
+    """The ingest's guarantees join the numbers compared; a breach is a
+    failed run that names it."""
+    from benchmarks.lib import hybrid
+
+    compared = verdict["compared"]
+    last = compared.pop("responses_compared")
+    compared.update(win["guarantees"], responses_compared=last)
+    broken = hybrid.breached(win["guarantees"])
+    if broken:
+        for name, c in compared.items():
+            print(f"bench: compared {name} = {c['value']} (limit "
+                  f"{c['limit']})", file=sys.stderr)
+        raise RunFailed("ingest guarantee broken: " + "; ".join(broken))
 
 
 def serve_peak() -> int:
@@ -518,8 +670,11 @@ def measure(setup: Dict[str, Any], seconds: float, trace: bool, tag: str,
     from benchmarks.lib import serve
 
     served = setup["served"]
+    hybrid_table = bool(setup["config"].get("realtime"))
     with phase("window"):
         win = run_window(served, setup, seconds, trace, tag)
+        if hybrid_table:
+            ingest_window(setup, win, seconds)
     if "setup_s" not in setup:      # process start to the first request
         setup["setup_s"] = win["t0_wall"] - _T0
         log("set-up " + " ".join(f"{k}={v:.1f}" for k, v in
@@ -530,7 +685,14 @@ def measure(setup: Dict[str, Any], seconds: float, trace: bool, tag: str,
     with phase("compare"):
         verdict = judge(served, setup, win, platform)
         unsound = []
-        if verdict["compiled_in_window"]:
+        if hybrid_table:
+            hold_guarantees(setup, win, verdict)
+        if verdict["compiled_in_window"] and hybrid_table:
+            # segments born in the window bring new shapes: counted
+            log(f"{len(verdict['compiled_in_window'])} programs were "
+                f"compiled inside the window (compiled_in_window): "
+                f"{verdict['compiled_in_window'][:6]}")
+        elif verdict["compiled_in_window"]:
             unsound.append(
                 f"{len(verdict['compiled_in_window'])} programs were "
                 f"compiled inside the measured window: a shape was not "
@@ -589,13 +751,23 @@ def result_line(setup: Dict[str, Any], win: Dict[str, Any],
         "failed": verdict["failed"], "metrics": metrics, "device": device}
     if breakdown:
         line["breakdown"] = breakdown
+    if setup["config"].get("realtime"):
+        line["compiled_in_window"] = len(verdict["compiled_in_window"])
     line["compared"] = verdict["compared"]      # last, as the contract asks
     return json.dumps(line)
 
 
 def control_reading(setup: Dict[str, Any]) -> Dict[str, Any]:
     """The control, judged as a run is: the reference's answers summed in
-    float32 put in the program's place."""
+    float32 put in the program's place (a hybrid table's: at the newest
+    prefix each response of the last window could have read)."""
+    if setup["config"].get("realtime"):
+        from benchmarks.lib import hybrid
+
+        return hybrid.control_window(
+            setup["last_records"], setup["cycle"], setup["want"],
+            setup["control"], setup["stream"], setup["pins"],
+            setup["config"], setup["table_mod"])
     records = [{"index": q["id"], "status": 200, "body": json.dumps({
         "exceptions": [], "numServersQueried": 1, "numServersResponded": 1,
         "partialResult": False,
@@ -614,7 +786,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     run; a noise study of several windows only says so and goes on."""
     strict = windows == 1 if strict is None else strict
     data_root = data_root or os.path.join(HERE, ".data")
-    setup = set_up(workload, seed, expect_platform, rows, data_root, control)
+    setup = set_up(workload, seed, expect_platform, rows, data_root, control,
+                   seconds, windows)
     lines = []
     try:
         for i in range(windows):
@@ -639,6 +812,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         setup["served"].close()
         shutil.rmtree(os.path.join(setup["work"], "profile"),
                       ignore_errors=True)
+        if setup["producer_child"] is not None:
+            try:
+                setup["producer"].stop()
+                setup["producer_child"].join(60.0)
+            finally:
+                setup["producer_child"].kill()
     return lines
 
 

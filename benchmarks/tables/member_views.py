@@ -22,14 +22,16 @@ time slice at full size), capped so that no member holds over
 The contract of ``tables/ssb_flat.py``: ``segment_sizes``, ``segment_codes``
 seeded a segment, ``decode``, ``table_codes``, ``STRING_DOMAINS``,
 ``CARDINALITY``; codes first, strings only in the builders. numpy only.
-Nothing of the program is imported here.
+Nothing of the program is imported here. For a hybrid table besides
+(``lib/hybrid.py``): ``stream_codes``, a stream partition's rows by offset,
+and ``STREAM_BLOCK``.
 """
 
 from __future__ import annotations
 
 import functools
 
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import numpy as np
 
@@ -46,6 +48,12 @@ DOMAIN_SIZE = 65_536
 ZIPF_EXPONENT = 1.0
 WINDOW_SPANS = (6, 29, 89)      # b - a of ``day BETWEEN a AND b``
 WINDOWS_A_SPAN = 128
+# the stream of a hybrid table: the day before the time boundary (which
+# the broker hides: the offline half serves it), the offline half's last
+# day (the realtime half serves it) and the day after it, today
+STREAM_DAYS = (DAY0 + DAYS - 2, DAY0 + DAYS)
+STREAM_SEED = 35_003
+STREAM_BLOCK = 4096
 
 INDUSTRIES = [f"industry_{k:03d}" for k in range(148)]
 REGIONS = [f"region_{k:02d}" for k in range(64)]
@@ -139,6 +147,13 @@ def segment_codes(i: int, num_segments: int, n: int,
     return {
         "member_id": np.repeat(members, counts).astype(np.int32),
         "day": rng.integers(days.start, days.stop, n).astype(np.int16),
+        **_view_columns(rng, n),
+    }
+
+
+def _view_columns(rng, n: int) -> Dict[str, np.ndarray]:
+    """A view's other columns: the viewer's attributes and the measures."""
+    return {
         "viewer_industry": _skewed(rng, 148, n).astype(np.int16),
         "viewer_region": _skewed(rng, 64, n).astype(np.int8),
         "viewer_seniority": rng.integers(0, 10, n).astype(np.int8),
@@ -150,6 +165,58 @@ def segment_codes(i: int, num_segments: int, n: int,
                            ).astype(np.int64).clip(*DWELL_MS
                                                    ).astype(np.int32),
     }
+
+
+@functools.lru_cache(maxsize=64)
+def _stream_members(partition: int, partitions: int
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+    """Stream partition ``partition``'s members in popularity order, and
+    the cumulative Zipf weight of each (exponent ``ZIPF_EXPONENT`` over
+    the global rank, as ``member_domain`` draws the traffic's keys),
+    capped as a segment's are: no member over ``MEMBER_SHARE_CAP`` of the
+    rows a seal makes a segment of."""
+    order = rank_order()
+    ranks = np.flatnonzero(order % partitions == partition)
+    weight = 1.0 / (ranks + 1.0) ** ZIPF_EXPONENT
+    for _ in range(16):
+        weight = np.minimum(weight, 0.6 * MEMBER_SHARE_CAP * weight.sum())
+    cum = np.cumsum(weight)
+    return order[ranks], cum / cum[-1]
+
+
+def stream_codes(partition: int, start: int, n: int, seed: int,
+                 partitions: int) -> Dict[str, np.ndarray]:
+    """Stream partition ``partition``'s rows at offsets ``[start, start +
+    n)``, as ``segment_codes`` gives a segment's: the realtime half of a
+    hybrid ``member_views``. A row's viewee is drawn by Zipf over the
+    members whose key falls in the partition (``member_id % partitions``:
+    upstream's ``Modulo`` keying of the producer), so the members the
+    traffic asks for most are viewed most; its day is one of
+    ``STREAM_DAYS``. Seeded a block of ``STREAM_BLOCK`` offsets, so the
+    producer and the oracle regenerate the same rows however they cut
+    the offsets."""
+    if not 0 <= partition < partitions or start < 0 or n < 0:
+        raise ValueError(f"no rows {start}+{n} in partition {partition} "
+                         f"of {partitions}")
+    members, cum = _stream_members(partition, partitions)
+    first = start // STREAM_BLOCK
+    last = max(first, (start + n - 1) // STREAM_BLOCK)
+    parts = []
+    for block in range(first, last + 1):
+        rng = np.random.default_rng([seed, STREAM_SEED, partitions,
+                                     partition, block])
+        m = STREAM_BLOCK
+        pick = np.minimum(np.searchsorted(cum, rng.random(m), "right"),
+                          len(members) - 1)
+        parts.append({
+            "member_id": members[pick].astype(np.int32),
+            "day": rng.integers(STREAM_DAYS[0], STREAM_DAYS[1] + 1,
+                                m).astype(np.int16),
+            **_view_columns(rng, m),
+        })
+    lo = start - first * STREAM_BLOCK
+    return {k: np.concatenate([p[k] for p in parts])[lo:lo + n]
+            for k in parts[0]}
 
 
 def decode(codes: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
